@@ -36,6 +36,7 @@ from typing import Optional, Sequence
 
 from . import config as _config
 from . import metrics as _metrics
+from .compile_cache import ensure_compile_cache as _ensure_compile_cache
 from .exceptions import NotInitializedError
 
 _lock = threading.Lock()
@@ -127,6 +128,32 @@ def _identity_from_comm(comm, coordinator_address):
             addr = f"{_routable_host()}:{port}"
         coordinator_address = comm.bcast(addr, root=0)
     return coordinator_address, size, rank
+
+
+def _check_chip_binding(local_size: int) -> None:
+    """Refuse to start as one of several unbound ranks on a TPU host.
+
+    A chip belongs to one process. The launcher binds each local slot to
+    its own chip where it knows the host's chip grid
+    (``runner.exec_run.chip_binding_env``); without that binding every
+    rank opens every chip and all but the first fail or hang inside the
+    runtime. The chip count comes from the PCI bus, so nothing here
+    touches the chips."""
+    if local_size <= 1 or os.environ.get("TPU_VISIBLE_CHIPS"):
+        return
+    platforms = _jax().config.jax_platforms
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    from jax._src.hardware_utils import num_available_tpu_chips_and_device_id
+    chips, _ = num_available_tpu_chips_and_device_id()
+    if chips:
+        raise RuntimeError(
+            f"{local_size} ranks were started on this host, which has "
+            f"{chips} TPU chip(s), without a chip binding: the launcher "
+            f"gives each rank its own chip only for a single-host job with "
+            f"as many slots as a known host shape (-H localhost:4 on a "
+            f"four-chip host). Start one rank per host instead (-H "
+            f"host:1): one process drives all of a host's chips.")
 
 
 def _routable_host() -> str:
@@ -227,6 +254,8 @@ def init(process_sets: Optional[Sequence[Sequence[int]]] = None,
         pid = process_id if process_id is not None else cfg.get(_config.RANK)
 
         jax = _jax()
+        _ensure_compile_cache()
+        _check_chip_binding(w.local_size())
         if addr and n and n > 1:
             # Controlled failure-detection latency: under an elastic launch
             # a dead peer must surface quickly so the driver's recovery
@@ -237,48 +266,19 @@ def init(process_sets: Optional[Sequence[Sequence[int]]] = None,
             if heartbeat < 0:
                 heartbeat = 10.0 if cfg.get(_config.ELASTIC) else 100.0
             # Multi-process eager collectives on the CPU backend need a
-            # cross-process implementation; jax versions that default the
-            # flag to "none" fail at the FIRST collective ("Multiprocess
-            # computations aren't implemented on the CPU backend"), not
-            # at init. Select gloo only when the flag is still at that
-            # default, so an explicit user/env choice always wins.
-            missing = object()
-            try:
-                current = jax.config.read(
-                    "jax_cpu_collectives_implementation")
-            except (AttributeError, KeyError):
-                current = missing  # this jax has no such flag to select
-            if current in (None, "none"):
-                try:
-                    jax.config.update(
-                        "jax_cpu_collectives_implementation", "gloo")
-                except Exception:
-                    import logging
-                    logging.getLogger("horovod_tpu").warning(
-                        "could not select gloo CPU collectives; "
-                        "multi-process CPU collectives may fail",
-                        exc_info=True)
-            kwargs = {
-                "coordinator_address": addr,
-                "num_processes": n,
-                "process_id": pid,
-                "initialization_timeout": int(
+            # cross-process implementation; without one the FIRST
+            # collective fails ("Multiprocess computations aren't
+            # implemented on the CPU backend"), not init.
+            if jax.config.jax_cpu_collectives_implementation == "none":
+                jax.config.update(
+                    "jax_cpu_collectives_implementation", "gloo")
+            jax.distributed.initialize(
+                coordinator_address=addr, num_processes=n, process_id=pid,
+                initialization_timeout=int(
                     cfg.get(_config.INIT_TIMEOUT_SECONDS)),
-                "heartbeat_timeout_seconds": int(heartbeat),
-                "shutdown_timeout_seconds": int(
-                    cfg.get(_config.SHUTDOWN_TIMEOUT_SECONDS)),
-            }
-            # the timeout kwargs arrived across jax releases; passing one
-            # an older runtime doesn't know is a TypeError, so offer only
-            # what this jax accepts (older versions fall back to their
-            # built-in heartbeat/shutdown defaults)
-            import inspect
-            accepted = inspect.signature(
-                jax.distributed.initialize).parameters
-            if not any(p.kind is inspect.Parameter.VAR_KEYWORD
-                       for p in accepted.values()):
-                kwargs = {k: v for k, v in kwargs.items() if k in accepted}
-            jax.distributed.initialize(**kwargs)
+                heartbeat_timeout_seconds=int(heartbeat),
+                shutdown_timeout_seconds=int(
+                    cfg.get(_config.SHUTDOWN_TIMEOUT_SECONDS)))
             w.coordinator_addr = addr
         w.process_id = jax.process_index()
         w.num_processes = jax.process_count()
@@ -408,48 +408,11 @@ def mapped_axis_sizes() -> dict:
     under plain jit with no mapped axis — the signal the in-jit
     collective fast path (collectives.py, docs/injit.md) keys on.
 
-    The axis environment moved between jax releases, so resolution is a
-    fallback chain: public ``jax.core.get_axis_env`` where it exists,
-    the private ``jax._src.core`` equivalent otherwise, and finally
-    ``unsafe_get_axis_names`` + per-axis ``axis_frame`` (which returns
-    the frame's size) for very old trees.
+    Read from jax's axis environment (``jax._src.core.get_axis_env``; jax
+    0.9 exposes no public accessor for the set of mapped axes).
     """
-    jax = _jax()
-    get_env = getattr(jax.core, "get_axis_env", None)
-    if get_env is None:
-        try:
-            from jax._src import core as _src_core
-            get_env = getattr(_src_core, "get_axis_env", None)
-        except ImportError:  # pragma: no cover - jax always has _src.core
-            get_env = None
-    if get_env is not None:
-        try:
-            return dict(get_env().axis_sizes)
-        except Exception:
-            pass
-    try:
-        from jax._src.core import unsafe_get_axis_names
-        names = list(unsafe_get_axis_names())
-    except Exception as e:
-        # No resolution path left on this jax. Returning {} here would
-        # make the in-jit fast path lower every collective with size-1
-        # (no-op) semantics — silently unreduced gradients. Fail loudly
-        # instead: HVD_TPU_INJIT_FASTPATH=0 routes callers back to the
-        # eager dispatcher until the axis-env resolution is re-taught.
-        raise RuntimeError(
-            "cannot introspect the jax axis environment on this jax "
-            "version (get_axis_env / unsafe_get_axis_names both "
-            "unavailable), so mapped axes are indistinguishable from "
-            "plain jit. Set HVD_TPU_INJIT_FASTPATH=0 to use the eager "
-            "dispatcher, or extend mapped_axis_sizes() for this jax "
-            "(docs/injit.md).") from e
-    out = {}
-    for n in names:
-        try:
-            out[n] = int(jax.core.axis_frame(n))
-        except Exception:
-            out[n] = 1
-    return out
+    from jax._src.core import get_axis_env
+    return dict(get_axis_env().axis_sizes)
 
 
 def mapped_axes() -> "tuple":

@@ -44,10 +44,6 @@ _TPU_PEAK_BF16_FLOPS = (
     ("v2", 45e12),
 )
 
-# Analytic fallback when XLA cost analysis is unavailable: ResNet-50 forward
-# at 224x224 is ~4.1 GMACs = ~8.2 GFLOPs/image; fwd+bwd+update ~= 3x forward.
-_RESNET50_TRAIN_FLOPS_PER_IMAGE = 3 * 8.2e9
-
 
 def _resolve_stem(model_name: str, stem: Optional[str]) -> Optional[str]:
     """The stem knob exists only on the ResNet family; resolution order
@@ -61,27 +57,19 @@ def _resolve_stem(model_name: str, stem: Optional[str]) -> Optional[str]:
 
 
 def peak_flops_per_chip(device_kind: str) -> Optional[float]:
+    """Peak dense bf16 FLOP/s of one chip; None for a device that is not a
+    TPU (no MFU is reported there). A TPU kind missing from the table is an
+    error, not a default: an MFU against a guessed peak is a wrong number."""
     k = (device_kind or "").lower()
     for name, peak in _TPU_PEAK_BF16_FLOPS:
         if name in k:
             return peak
+    if "tpu" in k:
+        raise ValueError(
+            f"no peak FLOP/s on record for TPU device_kind "
+            f"{device_kind!r}; add it to _TPU_PEAK_BF16_FLOPS with its "
+            f"source")
     return None
-
-
-def _compiled_flops(jitted, *example_args) -> Optional[float]:
-    """FLOPs per call from XLA's cost analysis (shape-only lowering, so it
-    does not disturb the jit cache or donated buffers)."""
-    import jax
-    try:
-        shapes = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), example_args)
-        ca = jitted.lower(*shapes).compile().cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        f = float(ca.get("flops", 0.0) or 0.0)
-        return f if f > 0 else None
-    except Exception:
-        return None
 
 
 class _Rig:
@@ -187,8 +175,18 @@ class _Rig:
             p = optax.apply_updates(p, updates)
             return p, bs, s, loss
 
-        # donate params/batch_stats/opt_state so XLA updates them in place
-        self.train_step = jax.jit(_step, donate_argnums=(0, 1, 2))
+        # donate params/batch_stats/opt_state so XLA updates them in place.
+        # Compiled once, ahead of time: the executable that runs the steps
+        # is the one whose cost analysis supplies the FLOP count.
+        self.train_step = jax.jit(_step, donate_argnums=(0, 1, 2)).lower(
+            self.params, self.batch_stats, self.opt_state, self.images,
+            self.labels).compile()
+        flops = self.train_step.cost_analysis().get("flops")
+        if not flops:
+            raise RuntimeError(
+                "XLA cost analysis reports no FLOP count for the train "
+                "step, so no MFU can be derived from this run")
+        self.flops_per_step = float(flops)
 
         # Scanned k-step program: the whole timed iteration is ONE XLA
         # call (lax.fori_loop over steps), eliminating per-step host
@@ -209,17 +207,6 @@ class _Rig:
 
         self._multi_step_cache = {}
         self._make_multi = _multi
-
-        self.flops_per_step = _compiled_flops(
-            self.train_step, self.params, self.batch_stats, self.opt_state,
-            self.images, self.labels)
-        if self.flops_per_step is None and model_name == "resnet50" \
-                and image_size == 224:
-            # the analytic constant is for ResNet-50 @ 224 only; other
-            # models without XLA cost analysis report no flops (and so
-            # no MFU) rather than a number borrowed from the wrong model
-            self.flops_per_step = (
-                _RESNET50_TRAIN_FLOPS_PER_IMAGE * global_batch)
 
         self._warmed_up = 0
 
@@ -281,7 +268,7 @@ class _Rig:
 
         peak = peak_flops_per_chip(self.device_kind)
         mfu = None
-        if peak and self.flops_per_step:
+        if peak:
             steps_per_sec = ips_total / self.global_batch
             mfu = (self.flops_per_step * steps_per_sec) / (self.n * peak)
 

@@ -26,6 +26,7 @@ Results are written to ``MICROBENCH.json`` by the root script and cited
 in ``docs/tensor-fusion.md``.
 """
 
+import functools
 import time
 from typing import List, Optional, Sequence
 
@@ -218,28 +219,6 @@ def bucketed_optimizer_sweep(iters: int = 5,
     }
 
 
-def _shard_map():
-    """Version-tolerant shard_map with replication checking disabled
-    (all_gather-based lowerings — broadcast, int8 — fail the static
-    replication inference on some jax versions). Public ``jax.shard_map``
-    landed after the jax this container ships (the experimental path is
-    the same function), and ``check_rep`` was renamed ``check_vma`` in
-    newer jax — tolerate both, or the sweep's variants all die and the
-    ``injit`` MICROBENCH section silently goes empty."""
-    import jax
-    try:
-        smap = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as smap
-
-    def wrap(f, **kw):
-        try:
-            return smap(f, check_rep=False, **kw)
-        except TypeError:  # renamed in newer jax
-            return smap(f, check_vma=False, **kw)
-    return wrap
-
-
 def injit_optimizer_sweep(iters: int = 5) -> dict:
     """The compiled-plane fast path on the ResNet-50 161-gradient
     scenario (docs/injit.md): per-leaf vs packed vs packed+bf16 vs
@@ -267,7 +246,9 @@ def injit_optimizer_sweep(iters: int = 5) -> dict:
     from .fusion import packed_plan
     from .optimizer import _packed_threshold
 
-    shard_map = _shard_map()
+    # check_vma off: all_gather-based lowerings (broadcast, int8) fail
+    # shard_map's static replication inference
+    shard_map = functools.partial(jax.shard_map, check_vma=False)
     devices = jax.devices()
     n = len(devices)
     mesh = Mesh(np.array(devices), ("dp",))

@@ -27,34 +27,27 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# Bound lazily so this module imports on machines without pallas support.
-pl = None
-pltpu = None
-
-
-def _ensure_pallas():
-    global pl, pltpu
-    if pl is None:
-        from jax.experimental import pallas as _pl
-        from jax.experimental.pallas import tpu as _pltpu
-        pl, pltpu = _pl, _pltpu
+# Mosaic's default scoped-VMEM budget, and the most this kernel asks for
+# (a v5e core has 128 MiB; the rest is left to the compiler).
+_VMEM_DEFAULT_BYTES = 16 << 20
+_VMEM_MAX_BYTES = 100 << 20
 
 
 def use_pallas_default() -> bool:
-    """Pallas kernels compile only for TPU; elsewhere the interpreter (or
-    the XLA reference path) runs — mirrors how the reference picks NCCL on
-    GPU and Gloo on CPU (operations.cc:142-233 ordered dispatch)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """Whether the default backend compiles Pallas TPU kernels. The
+    context-parallel wrappers (ring / Ulysses attention) pick the flash
+    kernel from this; a backend that fails to initialize raises here
+    rather than reading as "not a TPU"."""
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
-# Reference implementation (test oracle + non-TPU fallback)
+# Reference implementation (test oracle)
 # ---------------------------------------------------------------------------
 
 def mha_reference(q, k, v, causal: bool = True,
@@ -143,20 +136,68 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
     l = jnp.maximum(l, 1e-30)                            # fully-masked rows
     o_ref[0] = (o / l).astype(o_ref.dtype)
-    lse_ref[0, 0, pl.ds(iq * block_q, block_q)] = m[:, 0] + jnp.log(l[:, 0])
+    lse = m[:, 0] + jnp.log(l[:, 0])
+    if lse_ref.shape[-1] == block_q:
+        lse_ref[0, 0, :] = lse                           # single q block
+    else:
+        # Mosaic needs a lane offset it can prove is a multiple of 128;
+        # _flash_fwd only lets block_q % 128 == 0 reach this branch
+        start = pl.multiple_of(iq * block_q, 128) if block_q % 128 == 0 \
+            else iq * block_q
+        lse_ref[0, 0, pl.ds(start, block_q)] = lse
+
+
+def _compiler_params(dtype, SQ, SK, D, block_q, block_k):
+    """Mosaic parameters for the compiled kernel, or ValueError for a
+    tiling it cannot take — there is no other implementation to route
+    such a shape to."""
+    sublanes = 32 // jnp.dtype(dtype).itemsize   # rows per packed vreg tile
+    if SQ > block_q and block_q % 128:
+        raise ValueError(
+            f"flash_attention: block_q={block_q} splits S_q={SQ} into "
+            f"several q blocks, and the per-row lse store then needs "
+            f"block_q to be a multiple of 128; pass block_q >= S_q or a "
+            f"multiple of 128")
+    if block_k % sublanes:
+        raise ValueError(
+            f"flash_attention: block_k={block_k} must be a multiple of "
+            f"{sublanes} rows for {jnp.dtype(dtype).name} (the kernel "
+            f"slices K and V at block_k offsets)")
+    # Resident per program, double-buffered by the pipeline: the q and o
+    # blocks, the whole local K and V, the lse row; plus the fp32 o/s/p
+    # working set. Lanes pad to 128.
+    item = jnp.dtype(dtype).itemsize
+    lanes = -(-D // 128) * 128
+    need = 2 * ((2 * block_q + 2 * SK) * lanes * item + SQ * 4) \
+        + 4 * block_q * (lanes + 2 * max(block_k, 128)) * 4
+    if need > _VMEM_MAX_BYTES:
+        raise ValueError(
+            f"flash_attention keeps the local K and V resident in VMEM: "
+            f"{SK} rows x D={D} {jnp.dtype(dtype).name} needs "
+            f"~{need >> 20} MiB, over the {_VMEM_MAX_BYTES >> 20} MiB this "
+            f"kernel asks for; shard the sequence further (ring attention "
+            f"over a larger 'sp' axis)")
+    return pltpu.CompilerParams(
+        # bh programs are independent; q-block programs share the
+        # resident lse row block, so that dimension stays sequential
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=need if need > _VMEM_DEFAULT_BYTES else None)
 
 
 @functools.partial(
     jax.jit, static_argnames=("causal", "sm_scale", "block_q", "block_k",
                               "sk_real", "interpret", "vma"))
 def _flash_fwd(q, k, v, q_offset, k_offset, *, causal, sm_scale,
-               block_q, block_k, sk_real, interpret, vma=None):
+               block_q, block_k, sk_real, interpret, vma=()):
     """(BH, S_q, D) x (BH, S_k_padded, D) -> out (BH, S_q, D),
     lse (BH, S_q). S_q % block_q == 0, S_k_padded % block_k == 0."""
-    _ensure_pallas()
     BH, SQ, D = q.shape
     SK = k.shape[1]
     grid = (BH, SQ // block_q)
+    compiler_params = None
+    if not interpret:
+        compiler_params = _compiler_params(q.dtype, SQ, SK, D, block_q,
+                                           block_k)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_k=block_k,
         sk_real=sk_real, block_q=block_q)
@@ -179,17 +220,11 @@ def _flash_fwd(q, k, v, q_offset, k_offset, *, causal, sm_scale,
             pl.BlockSpec((1, 1, SQ), lambda b, i: (b, 0, 0)),
         ],
         out_shape=[
-            # vma: under shard_map the outputs vary over the caller's mesh
-            # axes (ring attention's 'sp'); None outside shard_map
-            jax.ShapeDtypeStruct((BH, SQ, D), q.dtype,
-                                 vma=frozenset(vma) if vma else None),
+            jax.ShapeDtypeStruct((BH, SQ, D), q.dtype, vma=frozenset(vma)),
             jax.ShapeDtypeStruct((BH, 1, SQ), jnp.float32,
-                                 vma=frozenset(vma) if vma else None),
+                                 vma=frozenset(vma)),
         ],
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            # bh programs are independent; q-block programs share the
-            # resident lse row block, so that dimension stays sequential
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=compiler_params,
         interpret=interpret,
     )(qoff, koff, q, k, v)
     return out, lse[:, 0, :]
@@ -221,7 +256,7 @@ def _flash(q, k, v, qoff, koff, causal, sm_scale, block_q, block_k,
 
 
 def _flash_fwd_padded(q, k, v, qoff, koff, causal, sm_scale, block_q,
-                      block_k, interpret, vma=None):
+                      block_k, interpret, vma=()):
     sq = q.shape[1]
     sk = k.shape[1]
     out, lse = _flash_fwd(
@@ -281,9 +316,9 @@ def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, vma, res,
 
     dq0 = jnp.zeros((BH, SQ, D), jnp.float32)
     if vma:
-        # under shard_map the carry must be marked varying over the caller's
-        # mesh axes to match the body output's vma
-        dq0 = jax.lax.pcast(dq0, tuple(vma), to="varying")
+        # under shard_map the carry must be marked varying over the same
+        # mesh axes as the body's output
+        dq0 = jax.lax.pcast(dq0, vma, to="varying")
     dq, (dk_blocks, dv_blocks) = jax.lax.scan(kblock, dq0, jnp.arange(nkb))
     dk = jnp.moveaxis(dk_blocks, 0, 1).reshape(BH, nkb * block_k, D)[:, :SK]
     dv = jnp.moveaxis(dv_blocks, 0, 1).reshape(BH, nkb * block_k, D)[:, :SK]
@@ -300,15 +335,17 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
                              sm_scale: Optional[float] = None,
                              q_offset=0, k_offset=0,
                              block_q: int = 512, block_k: int = 128,
-                             interpret: Optional[bool] = None,
-                             out_dtype=None, vma=None):
+                             interpret: bool = False,
+                             out_dtype=None):
     """Flash attention over (B, S, H, D) tensors; also returns the per-row
     log-sum-exp ``lse`` with shape (B, S, H) — differentiable — so callers
     can merge partial attention over distributed K/V blocks (ring
     attention's per-step combine).
 
-    On TPU this runs the Pallas kernel; elsewhere (or with
-    ``interpret=True`` for testing) the kernel runs interpreted.
+    The Pallas kernel is compiled by Mosaic, which exists only for TPU:
+    on any other backend, or for a tiling the kernel cannot take, the
+    call raises. ``interpret=True`` runs the same kernel body through the
+    Pallas interpreter instead — what the CPU tests pass.
     ``q_offset``/``k_offset`` are the global positions of local row 0 for
     causal masking across sharded sequences; they may be traced values
     (ring attention derives them from ``jax.lax.axis_index``).
@@ -318,18 +355,21 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
     SK = k.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(D))
-    if interpret is None:
-        interpret = not use_pallas_default()
     block_q = min(block_q, SQ)
-    block_k = min(block_k, SK)
+    # a short K is one block, padded (and masked) up to whole vreg tiles
+    block_k = min(block_k, -(-SK // 16) * 16)
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
-    out, lse = _flash(to_bh(q), to_bh(k), to_bh(v),
-                      jnp.asarray(q_offset, jnp.int32),
-                      jnp.asarray(k_offset, jnp.int32),
+    q_offset = jnp.asarray(q_offset, jnp.int32)
+    k_offset = jnp.asarray(k_offset, jnp.int32)
+    # under shard_map the kernel's outputs vary over every manual mesh axis
+    # its inputs vary over (empty outside shard_map)
+    vma = frozenset().union(*(jax.typeof(x).vma
+                              for x in (q, k, v, q_offset, k_offset)))
+    out, lse = _flash(to_bh(q), to_bh(k), to_bh(v), q_offset, k_offset,
                       causal, float(sm_scale), int(block_q), int(block_k),
-                      bool(interpret), tuple(vma) if vma else None)
+                      bool(interpret), tuple(sorted(vma)))
     out = out.reshape(B, H, SQ, D).transpose(0, 2, 1, 3)
     lse = lse.reshape(B, H, SQ).transpose(0, 2, 1)
     return out.astype(out_dtype), lse
@@ -339,11 +379,11 @@ def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     q_offset=0, k_offset=0,
                     block_q: int = 512, block_k: int = 128,
-                    interpret: Optional[bool] = None,
-                    out_dtype=None, vma=None):
+                    interpret: bool = False,
+                    out_dtype=None):
     """Flash attention over (B, S, H, D); see flash_attention_with_lse."""
     out, _ = flash_attention_with_lse(
         q, k, v, causal=causal, sm_scale=sm_scale, q_offset=q_offset,
         k_offset=k_offset, block_q=block_q, block_k=block_k,
-        interpret=interpret, out_dtype=out_dtype, vma=vma)
+        interpret=interpret, out_dtype=out_dtype)
     return out

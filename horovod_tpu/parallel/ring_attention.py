@@ -24,28 +24,41 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..ops.flash_attention import flash_attention_with_lse, use_pallas_default
 
 NEG_INF = -1e30
 
 
+def _vary_like(q, axis_name):
+    """Mark a fresh accumulator device-varying over the ring axis and every
+    other manual mesh axis ``q`` varies over, so it type-checks against the
+    per-step results under shard_map's VMA tracking."""
+    axes = tuple(sorted(jax.typeof(q).vma | {axis_name}))
+    return lambda x: jax.lax.pcast(x, axes, to="varying")
+
+
 def ring_attention(q, k, v, axis_name: str, causal: bool = True,
-                   out_dtype=None, impl: str = "auto"):
+                   out_dtype=None, impl: str = "auto",
+                   interpret: bool = False):
     """Exact attention over sequence blocks distributed on ``axis_name``.
 
     Args:
       q, k, v: (B, S_local, H, D) per-device blocks (sequence axis sharded).
       axis_name: mesh axis carrying the sequence shards (the ring).
       causal: apply a causal mask using global positions.
-      impl: "flash" = Pallas flash kernel per ring step (TPU hot path),
-        "xla" = blockwise einsum recurrence, "auto" = flash on TPU.
+      impl: "flash" = Pallas flash kernel per ring step, "xla" = blockwise
+        einsum recurrence, "auto" = flash where the backend is a TPU (the
+        kernel compiles or the call raises) and xla on backends that have
+        no Mosaic compiler.
+      interpret: run the flash kernel through the Pallas interpreter
+        (CPU tests); selects the flash implementation.
     Returns (B, S_local, H, D) attention output for the local Q block.
     """
     if impl == "auto":
-        from ..ops.flash_attention import use_pallas_default
-        impl = "flash" if use_pallas_default() else "xla"
+        impl = "flash" if interpret or use_pallas_default() else "xla"
     if impl == "flash":
         return ring_attention_flash(q, k, v, axis_name, causal=causal,
-                                    out_dtype=out_dtype)
+                                    out_dtype=out_dtype, interpret=interpret)
     out_dtype = out_dtype or q.dtype
     n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
@@ -81,10 +94,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True,
         v_next = jax.lax.ppermute(v_blk, axis_name, perm)
         return (o_new, m_new, l_new, k_next, v_next), None
 
-    # initial accumulators must be marked device-varying over the ring axis
-    # for the scan carry to type-check under shard_map's VMA tracking
-    def vary(x):
-        return jax.lax.pcast(x, (axis_name,), to="varying")
+    vary = _vary_like(q, axis_name)
     o0 = vary(jnp.zeros((B, S, H, D), jnp.float32))
     m0 = vary(jnp.full((B, H, S), NEG_INF, jnp.float32))
     l0 = vary(jnp.zeros((B, H, S), jnp.float32))
@@ -98,7 +108,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True,
 
 
 def ring_attention_flash(q, k, v, axis_name: str, causal: bool = True,
-                         out_dtype=None, interpret=None,
+                         out_dtype=None, interpret: bool = False,
                          block_q: int = 512, block_k: int = 128):
     """Ring attention with the Pallas flash kernel as the per-step block
     engine (ops/flash_attention.py).
@@ -119,10 +129,6 @@ def ring_attention_flash(q, k, v, axis_name: str, causal: bool = True,
     attention trade.
     """
     out_dtype = out_dtype or q.dtype
-    from ..ops.flash_attention import (flash_attention_with_lse,
-                                       use_pallas_default)
-    if interpret is None:
-        interpret = not use_pallas_default()
     n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     B, S, H, D = q.shape
@@ -134,7 +140,7 @@ def ring_attention_flash(q, k, v, axis_name: str, causal: bool = True,
             q, k_blk, v_blk, causal=causal,
             q_offset=my * S, k_offset=src * S,
             block_q=block_q, block_k=block_k, interpret=interpret,
-            out_dtype=jnp.float32, vma=(axis_name,))
+            out_dtype=jnp.float32)
         lse_new = jnp.logaddexp(lse, lse_i)
         w_old = jnp.exp(lse - lse_new)[..., None]        # (B, S, H, 1)
         w_new = jnp.exp(lse_i - lse_new)[..., None]
@@ -144,13 +150,11 @@ def ring_attention_flash(q, k, v, axis_name: str, causal: bool = True,
             v_blk = jax.lax.ppermute(v_blk, axis_name, perm)
         return o, lse_new, k_blk, v_blk
 
-    # remat each step on the compiled path: the backward re-runs the kernel
-    # instead of storing every rotated K/V block, keeping memory O(S_local)
-    if not interpret:
-        step = jax.checkpoint(step, static_argnums=(0,))
+    # remat each step: the backward re-runs the kernel instead of storing
+    # every rotated K/V block, keeping memory O(S_local)
+    step = jax.checkpoint(step, static_argnums=(0,))
 
-    def vary(x):
-        return jax.lax.pcast(x, (axis_name,), to="varying")
+    vary = _vary_like(q, axis_name)
     o = vary(jnp.zeros((B, S, H, D), jnp.float32))
     lse = vary(jnp.full((B, S, H), NEG_INF, jnp.float32))
     k_blk, v_blk = k, v

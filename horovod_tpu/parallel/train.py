@@ -5,11 +5,14 @@ program over a (dp, fsdp, pp, ep, sp, tp) mesh where
 
 * parameters shard by their logical axes (tp/fsdp) — pjit auto mode;
 * the batch shards over (dp, fsdp), the sequence over sp;
-* attention runs ring (or Ulysses) context-parallel via a *nested* manual
-  shard_map over just the 'sp' axis (axis_names={'sp'}), while dp/fsdp/tp
-  stay in XLA's automatic sharding propagation — so the gradient allreduce,
-  tensor-parallel collectives, and the ring ppermutes all come out of one
-  compilation;
+* attention runs ring (or Ulysses) context-parallel inside a *nested*
+  manual shard_map: batch over (dp, fsdp), sequence over sp, heads over tp —
+  every mesh axis, because a Mosaic kernel cannot sit under an axis XLA
+  still partitions automatically. Attention is independent per batch row
+  and head, so the only collective inside is the 'sp' ring; everything
+  around it stays in XLA's automatic sharding propagation, and the gradient
+  allreduce, tensor-parallel collectives and ring ppermutes all come out
+  of one compilation;
 * gradients need no explicit reduction (auto mode supplies them globally
   correct; DistributedOptimizer mode 2).
 """
@@ -32,9 +35,11 @@ from .. import faults as _faults
 _FP_MESH = _faults.FaultPoint("worker.mesh")
 
 
-def sharded_attention(mesh, kind: str = "ring", causal: bool = True):
+def sharded_attention(mesh, kind: str = "ring", causal: bool = True,
+                      interpret: bool = False):
     """Build a TransformerConfig.attention_fn running context-parallel over
-    the mesh's 'sp' axis, nested inside auto dp/fsdp/tp sharding."""
+    the mesh's 'sp' axis. ``interpret`` runs ring attention's flash kernel
+    through the Pallas interpreter (CPU tests of the compiled path)."""
     import jax
     from jax.sharding import PartitionSpec as P
 
@@ -42,7 +47,9 @@ def sharded_attention(mesh, kind: str = "ring", causal: bool = True):
     from .ulysses import ulysses_attention
 
     if mesh.shape.get("sp", 1) == 1:
-        return None  # fall back to the model's default full attention
+        return None  # the model's default full attention
+
+    spec = P(("dp", "fsdp"), "sp", "tp")   # (batch, seq, heads, head_dim)
 
     def fn(q, k, v, mask, dtype):
         del mask  # global causal masking computed from ring positions
@@ -50,13 +57,14 @@ def sharded_attention(mesh, kind: str = "ring", causal: bool = True):
         def inner(ql, kl, vl):
             if kind == "ring":
                 return ring_attention(ql, kl, vl, "sp", causal=causal,
-                                      out_dtype=dtype)
+                                      out_dtype=dtype, interpret=interpret)
             return ulysses_attention(ql, kl, vl, "sp", causal=causal,
                                      out_dtype=dtype)
 
-        return jax.shard_map(
-            inner, mesh=mesh, in_specs=P(None, "sp"),
-            out_specs=P(None, "sp"), axis_names={"sp"})(q, k, v)
+        # check_vma off under the interpreter only: it traces the kernel
+        # body, whose dynamic slices trip the varying-axes checker
+        return jax.shard_map(inner, mesh=mesh, in_specs=spec, out_specs=spec,
+                             check_vma=not interpret)(q, k, v)
     return fn
 
 
@@ -72,11 +80,13 @@ class TrainStepBundle:
 
 def make_transformer_train_step(cfg, mesh, optimizer=None,
                                 attention_kind: str = "ring",
-                                rules=None) -> TrainStepBundle:
+                                rules=None,
+                                interpret: bool = False) -> TrainStepBundle:
     """Build model + sharded params + jitted train step over ``mesh``.
 
     ``cfg``: models.transformer.TransformerConfig (attention_fn is replaced
-    with the sp-parallel one when the mesh has sp > 1).
+    with the sp-parallel one when the mesh has sp > 1). ``interpret``: see
+    :func:`sharded_attention`.
     """
     import jax
     import jax.numpy as jnp
@@ -88,7 +98,7 @@ def make_transformer_train_step(cfg, mesh, optimizer=None,
     from .mesh_utils import TRANSFORMER_RULES, param_shardings
 
     rules = rules or TRANSFORMER_RULES
-    attn = sharded_attention(mesh, kind=attention_kind)
+    attn = sharded_attention(mesh, kind=attention_kind, interpret=interpret)
     cfg = dataclasses.replace(cfg, attention_fn=attn)
     model = Transformer(cfg)
 
@@ -99,7 +109,9 @@ def make_transformer_train_step(cfg, mesh, optimizer=None,
     S = cfg.max_seq_len
     if S % max(sp, 1) != 0:
         raise ValueError(f"seq len {S} not divisible by sp={sp}")
-    tok0 = jnp.zeros((1, S), jnp.int32)
+    # one row per data shard: the init trace runs the attention shard_map,
+    # whose batch axis must divide over (dp, fsdp)
+    tok0 = jnp.zeros((mesh.shape["dp"] * mesh.shape["fsdp"], S), jnp.int32)
 
     abstract = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), tok0))
@@ -108,7 +120,16 @@ def make_transformer_train_step(cfg, mesh, optimizer=None,
         lambda: model.init(jax.random.PRNGKey(0), tok0),
         out_shardings=shardings)()
     params = variables["params"]
-    opt_state = opt.init(params)
+    # The step hands back params and optimizer state in the shardings it
+    # took them in. Left to XLA, an output may come back laid out otherwise
+    # (the position table over 'sp'): the next call then compiles a second
+    # program, and donation has nothing to alias.
+    replicated = NamedSharding(mesh, P())
+    state = (params, opt.init(params))
+    state_shardings = jax.tree_util.tree_map(
+        lambda x: x.sharding if isinstance(x.sharding, NamedSharding)
+        else replicated, state)
+    params, opt_state = jax.device_put(state, state_shardings)
 
     batch_sharding = NamedSharding(mesh, P(("dp", "fsdp"), "sp"))
 
@@ -122,7 +143,8 @@ def make_transformer_train_step(cfg, mesh, optimizer=None,
         updates, s = opt.update(grads, s, p)
         return optax.apply_updates(p, updates), s, loss
 
-    step = jax.jit(_step, donate_argnums=(0, 1))
+    step = jax.jit(_step, donate_argnums=(0, 1),
+                   out_shardings=(*state_shardings, replicated))
     return TrainStepBundle(step=step, params=params, opt_state=opt_state,
                            batch_sharding=batch_sharding, mesh=mesh)
 

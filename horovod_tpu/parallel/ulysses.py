@@ -54,7 +54,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = True,
             def attention_fn(qh, kh, vh, mask, dtype):
                 del mask  # causal handled inside the kernel
                 return flash_attention(qh, kh, vh, causal=causal,
-                                       out_dtype=dtype, vma=(axis_name,))
+                                       out_dtype=dtype)
         else:
             from horovod_tpu.models.transformer import _default_attention
             attention_fn = _default_attention

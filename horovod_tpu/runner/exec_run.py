@@ -27,6 +27,19 @@ SSH_COMMAND_PREFIX = ["ssh", "-o", "PasswordAuthentication=no",
 
 _LOCAL_NAMES = {"localhost", "127.0.0.1", "::1"}
 
+# One process per chip. A TPU chip belongs to one process, so when a host
+# carries several slots each is told which chip is its own and where its
+# peers' libtpu runtimes listen — the variables jax's own multi-process TPU
+# harness sets for this libtpu. TPU_PROCESS_BOUNDS is the physical chip
+# grid, so only host shapes seen on hardware are listed: a v5e host of four
+# chips is 2x2. Ignored by every other backend.
+_TPU_PROCESS_BOUNDS = {4: "2,2,1"}
+_TPU_PROCESS_PORT = 8476
+CHIP_BINDING_KEYS = (
+    "TPU_VISIBLE_CHIPS", "TPU_CHIPS_PER_PROCESS_BOUNDS", "TPU_PROCESS_BOUNDS",
+    "TPU_PROCESS_ADDRESSES", "TPU_PROCESS_PORT", "CLOUD_TPU_TASK_ID",
+    "ALLOW_MULTIPLE_LIBTPU_LOAD")
+
 
 def is_local_host(hostname: str) -> bool:
     # The whole 127/8 block is loopback, not just 127.0.0.1 — multi-"host"
@@ -40,6 +53,30 @@ def is_local_host(hostname: str) -> bool:
         return hostname in (socket.gethostname(), socket.getfqdn())
     except OSError:
         return False
+
+
+def chip_binding_env(slot: SlotInfo) -> Dict[str, str]:
+    """Environment that gives ``slot`` exactly one of its host's chips.
+
+    Empty for one slot per host (that process drives every chip) and for
+    layouts with no known chip grid — a multi-host job, or a slot count
+    other than a listed host shape; ``hvd.init()`` then refuses to start
+    on a TPU host rather than let every rank open every chip.
+    """
+    bounds = _TPU_PROCESS_BOUNDS.get(slot.local_size)
+    if bounds is None or slot.cross_size != 1:
+        return {}
+    return {
+        "TPU_VISIBLE_CHIPS": str(slot.local_rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            f"localhost:{_TPU_PROCESS_PORT + i}"
+            for i in range(slot.local_size)),
+        "TPU_PROCESS_PORT": str(_TPU_PROCESS_PORT + slot.local_rank),
+        "CLOUD_TPU_TASK_ID": str(slot.local_rank),
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
 
 
 def slot_env(slot: SlotInfo, coordinator_addr: str,
@@ -64,17 +101,19 @@ def slot_env(slot: SlotInfo, coordinator_addr: str,
         env["HVD_TPU_RENDEZVOUS_PORT"] = str(rendezvous_port)
     if elastic:
         env["HVD_TPU_ELASTIC"] = "1"
+    env.update(chip_binding_env(slot))
     return env
 
 
 def _remote_command(command: Sequence[str], env: Dict[str, str],
                     hostname: str, forward_keys: Sequence[str]) -> List[str]:
     """Wrap a command for ssh execution, exporting the worker env contract
-    plus ``forward_keys`` (reference gloo_run.py exports via `env` on the
-    remote shell)."""
+    (chip binding included) plus ``forward_keys`` (reference gloo_run.py
+    exports via `env` on the remote shell)."""
     exports = []
     for k, v in env.items():
-        if k.startswith(("HVD_TPU_", "HOROVOD_")) or k in forward_keys:
+        if k.startswith(("HVD_TPU_", "HOROVOD_")) or k in forward_keys \
+                or k in CHIP_BINDING_KEYS:
             exports.append(f"{k}={shlex.quote(v)}")
     remote = "env " + " ".join(exports) + " " + " ".join(
         shlex.quote(c) for c in command)

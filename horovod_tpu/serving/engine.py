@@ -43,6 +43,7 @@ from .. import _locks
 from .. import config as _config
 from .. import faults as _faults
 from .. import metrics as _metrics
+from ..compile_cache import ensure_compile_cache
 from .batcher import BucketedForward, MicroBatcher, parse_buckets
 
 log = logging.getLogger("horovod_tpu.serving")
@@ -104,6 +105,8 @@ class ParamsLifecycle:
         if (params is None) == (checkpoint_dir is None):
             raise ValueError(
                 "provide exactly one of params= or checkpoint_dir=")
+        # both engines build this first, before any program compiles
+        ensure_compile_cache()
         cfg = _config.live_config()
         self.checkpoint_dir = checkpoint_dir
         self.plane = plane

@@ -28,7 +28,7 @@ def main() -> int:
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_x64", True)
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     import horovod_tpu as hvd
@@ -56,7 +56,7 @@ def main() -> int:
     # --- compiled plane: the same verb, called under jit/shard_map —
     # must lower in-trace with zero dispatcher submissions
     @partial(shard_map, mesh=mesh, in_specs=P("world", None),
-             out_specs=P("world", None), check_rep=False)
+             out_specs=P("world", None), check_vma=False)
     def step(x):
         return hvd.allreduce(x[0], op=hvd.Sum, name="pw_injit")[None]
 
@@ -84,7 +84,7 @@ def main() -> int:
         [jax.device_put(flat[None], jax.local_devices()[0])])
 
     @partial(shard_map, mesh=mesh, in_specs=P("world", None),
-             out_specs=P("world", None), check_rep=False)
+             out_specs=P("world", None), check_vma=False)
     def grouped(x):
         a = x[0, :3].reshape(3)
         b = x[0, 3:].reshape(2, 2)

@@ -45,7 +45,7 @@ def main() -> int:
 
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from horovod_tpu.parallel.hierarchical import hierarchical_allreduce
 

@@ -185,3 +185,48 @@ def test_jax_profiler_helpers(tmp_path):
     hvd.stop_jax_profiler()
     files = list(tmp_path.rglob("*"))
     assert files, "profiler produced no trace files"
+
+
+# -- one process per chip: the worker-side refusal ---------------------------
+def _on_a_tpu_host(monkeypatch, chips=4):
+    from types import SimpleNamespace
+
+    from jax._src import hardware_utils
+
+    from horovod_tpu import basics
+    monkeypatch.setattr(hardware_utils,
+                        "num_available_tpu_chips_and_device_id",
+                        lambda: (chips, None))
+    # the suite pins jax to the CPU; pose as a process that may use the TPU
+    monkeypatch.setattr(basics, "_jax", lambda: SimpleNamespace(
+        config=SimpleNamespace(jax_platforms=None)))
+    return basics
+
+
+def test_unbound_ranks_sharing_a_tpu_host_are_refused(monkeypatch):
+    basics = _on_a_tpu_host(monkeypatch)
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    with pytest.raises(RuntimeError, match="without a chip binding"):
+        basics._check_chip_binding(3)
+
+
+def test_chip_binding_check_lets_the_supported_layouts_through(monkeypatch):
+    basics = _on_a_tpu_host(monkeypatch)
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    basics._check_chip_binding(1)           # one rank drives every chip
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "2")
+    basics._check_chip_binding(4)           # bound by the launcher
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS")
+    _on_a_tpu_host(monkeypatch, chips=0)._check_chip_binding(3)   # no TPU
+
+
+def test_chip_binding_check_ignores_a_cpu_pinned_process(monkeypatch):
+    """Under JAX_PLATFORMS=cpu (this suite) the chips are never opened."""
+    from jax._src import hardware_utils
+
+    from horovod_tpu import basics
+    monkeypatch.setattr(hardware_utils,
+                        "num_available_tpu_chips_and_device_id",
+                        lambda: (4, None))
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    basics._check_chip_binding(3)

@@ -18,7 +18,7 @@ from gen_pipeline import (  # noqa: E402
 def test_compose_services_parsed():
     svcs = parse_compose_services()
     assert "test-cpu-base" not in svcs
-    assert "test-cpu-jaxonly-py3_12" in svcs
+    assert "test-cpu-jax_only-py3_12" in svcs
     assert "test-cpu-openmpi-py3_12" in svcs
     assert "test-cpu-mpich-py3_12" in svcs
     assert "test-cpu-mxnet-py3_11" in svcs

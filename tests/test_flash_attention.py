@@ -149,3 +149,34 @@ def test_ring_flash_gradient_matches_global_reference():
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# the compiled path: no silent fallback, clear refusals
+# ---------------------------------------------------------------------------
+
+def test_default_arguments_never_fall_back_off_the_tpu():
+    """Without interpret=True the kernel goes to Mosaic, which exists only
+    for TPU: on this CPU backend the call raises instead of quietly running
+    the interpreter or the reference."""
+    q = _rand((1, 128, 2, 64))
+    with pytest.raises(ValueError, match="interpret mode"):
+        flash_attention(q, q, q)
+
+
+def test_compiled_kernel_refuses_tilings_mosaic_cannot_take():
+    from horovod_tpu.ops.flash_attention import (_VMEM_DEFAULT_BYTES,
+                                                 _compiler_params)
+    bf16 = jnp.bfloat16
+    with pytest.raises(ValueError, match="multiple of 128"):
+        _compiler_params(bf16, 256, 256, 64, block_q=64, block_k=128)
+    with pytest.raises(ValueError, match="multiple of 16"):
+        _compiler_params(bf16, 256, 264, 64, block_q=256, block_k=24)
+    with pytest.raises(ValueError, match="shard the sequence further"):
+        _compiler_params(bf16, 131072, 131072, 128, 512, 128)
+    # the full-width train shape fits Mosaic's default budget; a long local
+    # context asks for more, explicitly
+    assert _compiler_params(bf16, 1024, 1024, 64, 512,
+                            128).vmem_limit_bytes is None
+    assert _compiler_params(bf16, 32768, 32768, 128, 512,
+                            128).vmem_limit_bytes > _VMEM_DEFAULT_BYTES

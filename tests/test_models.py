@@ -104,6 +104,14 @@ def test_graft_entry_dryrun():
     assert callable(fn) and len(args) == 2
 
 
+def test_peak_flops_table_rejects_an_unknown_tpu():
+    from horovod_tpu.benchmark import peak_flops_per_chip
+    assert peak_flops_per_chip("TPU v5 lite") == 197e12
+    assert peak_flops_per_chip("cpu") is None       # no MFU off the chip
+    with pytest.raises(ValueError, match="TPU v9 mystery"):
+        peak_flops_per_chip("TPU v9 mystery")
+
+
 def test_benchmark_scanned_stage(hvd_world):
     """The scanned k-step program (one XLA call per timed iteration)
     produces a valid measurement and shares the rig with plain stages."""

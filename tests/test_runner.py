@@ -19,7 +19,8 @@ import pytest
 from horovod_tpu.runner import (HostInfo, get_host_assignments, parse_hostfile,
                                 parse_hosts)
 from horovod_tpu.runner import config_parser, launch
-from horovod_tpu.runner.exec_run import is_local_host, slot_env
+from horovod_tpu.runner.exec_run import (CHIP_BINDING_KEYS, _remote_command,
+                                         is_local_host, slot_env)
 from horovod_tpu.runner.rendezvous import (KVStoreClient, KVStoreServer,
                                            RendezvousServer)
 from horovod_tpu.runner.safe_exec import safe_exec
@@ -87,6 +88,52 @@ def test_slot_env_contract():
     assert env["HVD_TPU_LOCAL_RANK"] == "1"
     assert env["HVD_TPU_COORDINATOR_ADDR"] == "127.0.0.1:7777"
     assert env["HVD_TPU_RENDEZVOUS_PORT"] == "8888"
+
+
+# -- one process per chip ----------------------------------------------------
+def test_four_local_slots_get_four_disjoint_chips():
+    slots, _ = get_host_assignments([HostInfo("localhost", 4)], 4)
+    envs = [slot_env(s, "127.0.0.1:7777", base_env={}) for s in slots]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    for e in envs:
+        # one chip each, out of the host's 2x2 grid; every rank knows
+        # where all four runtimes listen, its own port among them
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "2,2,1"
+        addresses = e["TPU_PROCESS_ADDRESSES"].split(",")
+        assert len(set(addresses)) == 4
+        assert f"localhost:{e['TPU_PROCESS_PORT']}" in addresses
+
+
+def test_one_slot_per_host_is_left_every_chip():
+    for hosts in ([HostInfo("localhost", 1)],
+                  [HostInfo("a", 1), HostInfo("b", 1)]):
+        slots, _ = get_host_assignments(hosts, len(hosts))
+        for s in slots:
+            env = slot_env(s, "127.0.0.1:7777", base_env={})
+            assert not set(env) & set(CHIP_BINDING_KEYS)
+
+
+def test_layouts_with_no_known_chip_grid_are_left_unbound():
+    """Two or three slots, or several multi-slot hosts: no binding here —
+    hvd.init() refuses those on a TPU host (test_basics)."""
+    for hosts, n in (([HostInfo("localhost", 3)], 3),
+                     ([HostInfo("a", 4), HostInfo("b", 4)], 8)):
+        slots, _ = get_host_assignments(hosts, n)
+        env = slot_env(slots[-1], "127.0.0.1:7777", base_env={})
+        assert not set(env) & set(CHIP_BINDING_KEYS)
+
+
+def test_remote_command_forwards_the_chip_binding():
+    slots, _ = get_host_assignments([HostInfo("tpu-host", 4)], 4)
+    env = slot_env(slots[2], "tpu-host:7777", base_env={"HOME": "/root"})
+    remote = _remote_command(["python", "train.py"], env, "tpu-host",
+                             ("PATH",))[-1]
+    for key in CHIP_BINDING_KEYS:
+        assert f"{key}={env[key]}" in remote.replace("'", "")
+    assert "HVD_TPU_RANK=2" in remote and "HOME=" not in remote
 
 
 def test_is_local_host():
