@@ -132,25 +132,32 @@ class Attention(nn.Module):
             return jnp.einsum("bshd,hde->bse", out, wo.astype(dt))
         # -- paged incremental path ---------------------------------------
         # layer_cache: this layer's (num_blocks, block_size, H, D) pools
-        # plus the batch's tables/positions; see PagedCache.
+        # plus the batch's tables/positions; see PagedCache. The named
+        # scopes cost nothing at run time; under flax's own module
+        # scopes they name a captured profile's operations
+        # ``layer_<i>/attn/kv_write`` and so on (the MLP is ``layer_<i>/mlp``).
         k_slab, v_slab, block_tables, positions, live = layer_cache
         B, C = x.shape[0], x.shape[1]
         block_size = k_slab.shape[1]
-        # scatter the chunk's K/V through the block tables; pad tokens
-        # (and dead lanes) route to the null block 0
-        blk_idx = positions // block_size                       # (B, C)
-        offsets = positions % block_size                        # (B, C)
-        blocks = jnp.take_along_axis(
-            block_tables, blk_idx.astype(jnp.int32), axis=1)    # (B, C)
-        valid = jnp.arange(C)[None, :] < live[:, None]
-        blocks = jnp.where(valid, blocks, 0)
-        k_slab = k_slab.at[blocks, offsets].set(k)
-        v_slab = v_slab.at[blocks, offsets].set(v)
-        # gather every table slot back as one contiguous (B, T, H, D)
-        # view — T = max_blocks * block_size, position t lives at index t
-        kc = k_slab[block_tables].reshape(B, -1, H, D)
-        vc = v_slab[block_tables].reshape(B, -1, H, D)
-        out = _default_attention(q, kc, vc, mask, dt)
+        with jax.named_scope("kv_write"):
+            # scatter the chunk's K/V through the block tables; pad
+            # tokens (and dead lanes) route to the null block 0
+            blk_idx = positions // block_size                   # (B, C)
+            offsets = positions % block_size                    # (B, C)
+            blocks = jnp.take_along_axis(
+                block_tables, blk_idx.astype(jnp.int32), axis=1)  # (B, C)
+            valid = jnp.arange(C)[None, :] < live[:, None]
+            blocks = jnp.where(valid, blocks, 0)
+            k_slab = k_slab.at[blocks, offsets].set(k)
+            v_slab = v_slab.at[blocks, offsets].set(v)
+        with jax.named_scope("kv_gather"):
+            # gather every table slot back as one contiguous (B, T, H, D)
+            # view — T = max_blocks * block_size, position t lives at
+            # index t
+            kc = k_slab[block_tables].reshape(B, -1, H, D)
+            vc = v_slab[block_tables].reshape(B, -1, H, D)
+        with jax.named_scope("attention"):
+            out = _default_attention(q, kc, vc, mask, dt)
         return (jnp.einsum("bshd,hde->bse", out, wo.astype(dt)),
                 (k_slab, v_slab))
 
@@ -255,8 +262,9 @@ class Transformer(nn.Module):
             x = jnp.take_along_axis(
                 x, logits_at.astype(jnp.int32)[:, None, None], axis=1)
         # logits in fp32, weight-tied to the embedding
-        logits = jnp.einsum("bse,ve->bsv", x.astype(jnp.float32),
-                            emb.astype(jnp.float32))
+        with jax.named_scope("head"):
+            logits = jnp.einsum("bse,ve->bsv", x.astype(jnp.float32),
+                                emb.astype(jnp.float32))
         if cache is None:
             return logits
         if logits_at is not None:

@@ -136,6 +136,9 @@ class DistributedGradientTransform:
         return Int8ErrorFeedbackState(residual=residual, inner=inner)
 
     def update(self, grads, state, params=None, **extra):
+        # the two named scopes cost nothing at run time: they name the
+        # step's operations in a captured profile
+        import jax
         if self._stateful_compression:
             if not isinstance(state, Int8ErrorFeedbackState):
                 raise TypeError(
@@ -143,12 +146,17 @@ class DistributedGradientTransform:
                     "as optax state; pass the state returned by this "
                     "transform's init() (got "
                     f"{type(state).__name__}).")
-            reduced, new_residual = self._packed_reduce(grads, state.residual)
-            updates, inner = self._base.update(
-                reduced, state.inner, params, **extra)
+            with jax.named_scope("grad_reduce"):
+                reduced, new_residual = self._packed_reduce(
+                    grads, state.residual)
+            with jax.named_scope("optimizer_update"):
+                updates, inner = self._base.update(
+                    reduced, state.inner, params, **extra)
             return updates, Int8ErrorFeedbackState(new_residual, inner)
-        reduced = self.reduce_gradients(grads)
-        return self._base.update(reduced, state, params, **extra)
+        with jax.named_scope("grad_reduce"):
+            reduced = self.reduce_gradients(grads)
+        with jax.named_scope("optimizer_update"):
+            return self._base.update(reduced, state, params, **extra)
 
     # reduction --------------------------------------------------------------
     def reduce_gradients(self, grads):

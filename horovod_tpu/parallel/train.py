@@ -139,9 +139,14 @@ def make_transformer_train_step(cfg, mesh, optimizer=None,
             logits, tgts).mean()
 
     def _step(p, s, toks, tgts):
-        loss, grads = jax.value_and_grad(loss_fn)(p, toks, tgts)
-        updates, s = opt.update(grads, s, p)
-        return optax.apply_updates(p, updates), s, loss
+        with jax.named_scope("loss_and_grad"):
+            loss, grads = jax.value_and_grad(loss_fn)(p, toks, tgts)
+        with jax.named_scope("optimizer"):
+            # a DistributedOptimizer scopes its grad_reduce and its
+            # optimizer_update inside
+            updates, s = opt.update(grads, s, p)
+        with jax.named_scope("apply_updates"):
+            return optax.apply_updates(p, updates), s, loss
 
     step = jax.jit(_step, donate_argnums=(0, 1),
                    out_shardings=(*state_shardings, replicated))

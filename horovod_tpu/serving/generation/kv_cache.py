@@ -577,6 +577,13 @@ def sample_tokens(logits, sample: SampleParams):
     actual confidence, not the truncated one.
     """
     import jax
+
+    with jax.named_scope("sample_tokens"):
+        return _sample_tokens(logits, sample)
+
+
+def _sample_tokens(logits, sample: SampleParams):
+    import jax
     import jax.numpy as jnp
 
     vocab = logits.shape[-1]

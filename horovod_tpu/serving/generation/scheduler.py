@@ -187,10 +187,12 @@ _M_OCCUPANCY = _metrics.histogram(
     buckets=(1, 2, 4, 8, 16, 32, 64))
 _M_STEP = _metrics.histogram(
     "hvd_tpu_gen_step_seconds",
-    "Per scheduler iteration, the wall time split between waiting on "
-    "the device ('device': blocked in token-vector/prefill transfers) "
-    "and everything else ('host': admission, stream delivery, state "
-    "bookkeeping, enqueue). With HVD_TPU_GEN_ASYNC_DEPTH=1 the host "
+    "Per busy scheduler iteration, the wall time split between waiting "
+    "on the device ('device': blocked in token-vector/prefill "
+    "transfers, the iteration's gen.wait loop spans) and everything "
+    "else ('host': admission, stream delivery, state bookkeeping, "
+    "enqueue; the rest of its gen.iter span, split by phase in "
+    "hvd_tpu_gen_phase_seconds). With HVD_TPU_GEN_ASYNC_DEPTH=1 the host "
     "share overlaps the in-flight device step; a host share rivaling "
     "the device share at depth 0 is the signal that async stepping "
     "pays. With speculative decoding on, 'verify' is the wait on the "
@@ -201,6 +203,44 @@ _M_STEP = _metrics.histogram(
     labels=("component",),
     buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
              0.25, 1.0))
+_M_PHASE = _metrics.histogram(
+    "hvd_tpu_gen_phase_seconds",
+    "Per busy scheduler iteration, the self time of each phase of the "
+    "loop (tracing.py's loop spans, duration minus what child spans "
+    "cover): 'admit' (queue drain, cancellations, expiry, admission), "
+    "'prefill.prepare' / 'prefill.dispatch', 'decode.prepare' / "
+    "'decode.dispatch' (host arrays and uploads, then the program's "
+    "call), 'wait' (blocked on a device result), 'deliver' (tokens "
+    "mirrored, streamed, blocks registered, sequences retired) and "
+    "'iter' (the iteration's own remainder). One observation a phase "
+    "an iteration in which it ran; over any interval the sums add up "
+    "to hvd_tpu_gen_step_seconds' host plus device sums.",
+    labels=("phase",),
+    buckets=(0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005, 0.001,
+             0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 1.0))
+#: request-scope waits reach a minute: a caller of a backlogged engine
+#: waits ten seconds and more for a lane
+_WAIT_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+                 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
+_M_QUEUE_WAIT = _metrics.histogram(
+    "hvd_tpu_gen_queue_wait_seconds",
+    "Per request, from its arrival at submit to the dispatch of its "
+    "first prefill chunk: the wait for a batch slot, for KV blocks and "
+    "for the prefills ahead of it. One observation a request; a "
+    "re-admission after a preemption is not a second one.",
+    buckets=_WAIT_BUCKETS)
+_M_PREFILL_SPAN = _metrics.histogram(
+    "hvd_tpu_gen_prefill_span_seconds",
+    "Per request, from the dispatch of its first prefill chunk to its "
+    "first token put on the stream: chunked prefill, one chunk an "
+    "iteration beside the decode steps of the running batch.",
+    buckets=_WAIT_BUCKETS)
+_M_TTFT = _metrics.histogram(
+    "hvd_tpu_gen_ttft_seconds",
+    "Per request, time to first token as the scheduler sees it: "
+    "hvd_tpu_gen_queue_wait_seconds plus "
+    "hvd_tpu_gen_prefill_span_seconds of the same request.",
+    buckets=_WAIT_BUCKETS)
 _M_SPEC_DRAFTED = _metrics.counter(
     "hvd_tpu_gen_spec_drafted_total",
     "Tokens proposed by the speculative-decoding drafter "
@@ -325,7 +365,7 @@ class GenSequence:
                  "done_event", "arrived_at", "temperature", "top_k",
                  "top_p", "seed", "key", "sample_offset", "prefix_hashes",
                  "block_hashes", "cache_gen", "request_id", "trace",
-                 "num_beams")
+                 "num_beams", "first_dispatch_at", "first_token_at")
 
     def __init__(self, seq_id: int, prompt: List[int], max_tokens: int,
                  eos_id: Optional[int], deadline_s: float,
@@ -399,6 +439,11 @@ class GenSequence:
         self.stream_q: "queue.Queue" = queue.Queue()
         self.done_event = threading.Event()
         self.arrived_at = time.monotonic()
+        #: monotonic instants of the first prefill chunk's dispatch and
+        #: of the first token put on the stream: set once, so a
+        #: re-admission after a preemption observes no second wait
+        self.first_dispatch_at: Optional[float] = None
+        self.first_token_at: Optional[float] = None
         #: serving request id, stamped into preemption/deadline
         #: diagnostics whether or not the request is traced
         self.request_id = request_id
@@ -538,7 +583,10 @@ class ContinuousBatcher:
         #: decode steps enqueued but not yet consumed:
         #: (token_dev, logprob_dev, lane snapshot)
         self._inflight: "collections.deque" = collections.deque()
-        self._blocked_s = 0.0
+        #: the loop's spans (tracing.py): ring, profiler annotation and
+        #: the self times that hvd_tpu_gen_phase_seconds and
+        #: hvd_tpu_gen_step_seconds are derived from
+        self._spans = _tracing.LoopTrace("gen.iter", histogram=_M_PHASE)
         self._lock = _locks.lock(
             "serving.generation.ContinuousBatcher._lock")
         self._thread: Optional[threading.Thread] = None
@@ -903,6 +951,8 @@ class ContinuousBatcher:
 
     def _loop(self) -> None:
         err = RuntimeError("generation scheduler stopped")
+        step_host = _M_STEP.labels(component="host")
+        step_device = _M_STEP.labels(component="device")
         while True:
             # block only when fully idle; otherwise drain without waiting
             if not self._running and not self._waiting \
@@ -916,45 +966,67 @@ class ContinuousBatcher:
                     item.run()
                 else:
                     self._waiting.append(item)
-            while True:
-                try:
-                    item = self._q.get_nowait()
-                except queue.Empty:
-                    break
-                if item is _STOP:
-                    self._shutdown(err)
-                    return
-                if isinstance(item, _ControlOp):
-                    item.run()
-                    continue
-                self._waiting.append(item)
-            if self._stopped:
-                self._shutdown(err)
-                return
-            # one wall clock per iteration: admission, expiry, and
-            # emission deadlines all read the same instant
-            now = time.monotonic()
-            if self._prefix_cache:
-                # notice a params hot-swap BEFORE admission: matching
-                # must never attach blocks computed under the previous
-                # checkpoint (the device calls below would re-check, but
-                # only after this iteration's match already committed)
-                self._params()
+            # an iteration is measured when it began with work in the
+            # running set or on the device (an engine coming out of idle
+            # is traced, not observed); nothing the queue drain below
+            # does moves either
             busy = bool(self._running or self._inflight)
-            t0 = time.perf_counter()
-            self._blocked_s = 0.0
-            self._apply_cancels(now)
-            self._admit(now)
-            self._prefill_step(now)
-            self._decode_step(now)
+            spans = self._spans
+            with spans.iteration(observe=busy, busy=busy,
+                                 running=len(self._running),
+                                 waiting=len(self._waiting)
+                                 + self._q.qsize(),
+                                 inflight=len(self._inflight)):
+                with spans.span("gen.admit"):
+                    now = self._drain_and_admit(err)
+                if now is not None:
+                    self._prefill_step(now)
+                    self._decode_step(now)
             if busy:
-                wall = time.perf_counter() - t0
-                dev = min(self._blocked_s, wall)
-                _M_STEP.labels(component="device").observe(dev)
-                _M_STEP.labels(component="host").observe(
-                    max(0.0, wall - dev))
+                # derived from the spans' own stamps: device is what the
+                # gen.wait spans cover, host the rest of gen.iter
+                phases = spans.self_ns
+                dev = phases.get("gen.wait", 0)
+                step_device.observe(dev * 1e-9)
+                step_host.observe((sum(phases.values()) - dev) * 1e-9)
+            if now is None:
+                return          # stopped: everything was failed
             self._publish_gauges()
         self._shutdown(err)
+
+    def _drain_and_admit(self, err: BaseException) -> Optional[float]:
+        """The ``gen.admit`` phase: drain the submission queue without
+        waiting, apply cancellations, admit, shed what expired. Returns
+        the iteration's wall clock, or None after a stop (everything
+        failed, the loop must return)."""
+        while True:
+            try:
+                item = self._q.get_nowait()
+            except queue.Empty:
+                break
+            if item is _STOP:
+                self._shutdown(err)
+                return None
+            if isinstance(item, _ControlOp):
+                item.run()
+                continue
+            self._waiting.append(item)
+        if self._stopped:
+            self._shutdown(err)
+            return None
+        # one wall clock per iteration: admission, expiry, and
+        # emission deadlines all read the same instant
+        now = time.monotonic()
+        if self._prefix_cache:
+            # notice a params hot-swap BEFORE admission: matching
+            # must never attach blocks computed under the previous
+            # checkpoint (the device calls below would re-check, but
+            # only after this iteration's match already committed)
+            self._params()
+        self._apply_cancels(now)
+        self._admit(now)
+        self._expire_running(now)
+        return now
 
     def _shutdown(self, err: BaseException) -> None:
         # tokens still in flight belong to sequences this shutdown is
@@ -1102,7 +1174,6 @@ class ContinuousBatcher:
                     stage="prefill" if s.state == "prefill" else "decode"))
 
     def _prefill_step(self, now: float) -> None:
-        self._expire_running(now)
         s = next((x for x in self._running if x.state == "prefill"), None)
         if s is None:
             return
@@ -1112,38 +1183,67 @@ class ContinuousBatcher:
         self._flush_inflight()
         if s.state != "prefill":
             return                # a device failure during the drain
+        spans = self._spans
         total = len(s.prefill_tokens)
         chunk = s.prefill_tokens[s.prefilled:s.prefilled + self.prefill_chunk]
         live = len(chunk)
-        need = self._alloc.blocks_for(s.prefilled + live) - len(s.blocks)
-        if need > 0 and not self._grow(s, need):
-            return          # s itself was preempted; nothing to run
-        tokens = np.zeros((1, self.prefill_chunk), np.int32)
-        tokens[0, :live] = chunk
-        sample = SampleParams(
-            # the resume path discards the sampled token (it was emitted
-            # before the eviction): force the cheap greedy branch
-            temperature=jnp.asarray(
-                [0.0 if s.resume_decode else s.temperature], jnp.float32),
-            top_k=jnp.asarray([s.top_k], jnp.int32),
-            top_p=jnp.asarray([s.top_p], jnp.float32),
-            key=jnp.asarray(s.key[None, :]),
-            emitted=jnp.asarray([s.sample_offset], jnp.int32))
+        with spans.span("gen.prefill.prepare"):
+            need = self._alloc.blocks_for(s.prefilled + live) - len(s.blocks)
+            if need > 0 and not self._grow(s, need):
+                return          # s itself was preempted; nothing to run
+            tokens = np.zeros((1, self.prefill_chunk), np.int32)
+            tokens[0, :live] = chunk
+            row = np.zeros((1, self.max_blocks), np.int32)
+            row[0, :len(s.blocks)] = s.blocks
+            args = (
+                PagedCache(self._k, self._v, jnp.asarray(row),
+                           jnp.asarray(np.asarray([s.prefilled], np.int32)),
+                           jnp.asarray(np.asarray([live], np.int32))),
+                jnp.asarray(tokens),
+                SampleParams(
+                    # the resume path discards the sampled token (it was
+                    # emitted before the eviction): force the cheap
+                    # greedy branch
+                    temperature=jnp.asarray(
+                        [0.0 if s.resume_decode else s.temperature],
+                        jnp.float32),
+                    top_k=jnp.asarray([s.top_k], jnp.int32),
+                    top_p=jnp.asarray([s.top_p], jnp.float32),
+                    key=jnp.asarray(s.key[None, :]),
+                    emitted=jnp.asarray([s.sample_offset], jnp.int32)))
         if s.request_id:
             _tracing.note_request(s.request_id)
         try:
-            # the span installs the request's context on the scheduler
-            # thread, so collectives submitted inside the prefill program
-            # bind under this chunk
-            with _tracing.span_for(s.trace, "gen.prefill",
-                                   args={"seq": s.id, "chunk": live,
-                                         "prefilled": s.prefilled,
-                                         "total": total}):
+            # the request span installs the request's context on the
+            # scheduler thread, so collectives submitted inside the
+            # prefill program bind under this chunk
+            with spans.span("gen.prefill.dispatch", seq=s.id,
+                            request=s.request_id or "", chunk=live,
+                            prefilled=s.prefilled, total=total), \
+                    _tracing.span_for(s.trace, "gen.prefill",
+                                      args={"seq": s.id, "chunk": live,
+                                            "prefilled": s.prefilled,
+                                            "total": total}):
                 _FP_PREFILL.fire()
-                tok, logp = self._run_prefill(s, tokens, live, sample)
+                if s.first_dispatch_at is None:
+                    self._first_dispatch(s)
+                tok, logp = self._run_prefill(*args)
         except Exception as e:  # noqa: BLE001 — fails only this sequence
             self._deliver_error(s, e)
             return
+        with spans.span("gen.deliver"):
+            self._deliver_prefill(s, live, total, tok, logp, now)
+
+    def _first_dispatch(self, s: GenSequence) -> None:
+        """The request's wait for its first prefill chunk ends here:
+        observed once a request, whatever preemptions follow."""
+        s.first_dispatch_at = t = time.monotonic()
+        _M_QUEUE_WAIT.observe(t - s.arrived_at)
+        _tracing.emit_span(s.trace, "gen.queue", s.arrived_at, t,
+                           args={"seq": s.id})
+
+    def _deliver_prefill(self, s: GenSequence, live: int, total: int,
+                         tok, logp, now: float) -> None:
         _M_TOKENS.labels(phase="prefill").inc(live)
         s.prefilled += live
         s.cache_len = s.prefilled
@@ -1185,9 +1285,8 @@ class ContinuousBatcher:
                 # chunks never reach this sync: their sampled token is
                 # simply not consumed.)
                 _M_TOKENS.labels(phase="decode").inc()
-                t0 = time.perf_counter()
-                tok_v, logp_v = np.asarray(tok), np.asarray(logp)
-                self._blocked_s += time.perf_counter() - t0
+                with self._spans.span("gen.wait", program="prefill"):
+                    tok_v, logp_v = np.asarray(tok), np.asarray(logp)
                 logp_v = _corrupt_logprobs(logp_v, [s])
                 if not np.isfinite(logp_v[0]):
                     self._deliver_error(s, RuntimeError(
@@ -1198,15 +1297,10 @@ class ContinuousBatcher:
         if self.on_step is not None:
             self.on_step("prefill", [s.id])
 
-    def _run_prefill(self, s: GenSequence, tokens, live: int, sample):
-        row = np.zeros((1, self.max_blocks), np.int32)
-        row[0, :len(s.blocks)] = s.blocks
-        cache = PagedCache(self._k, self._v, jnp.asarray(row),
-                           jnp.asarray(np.asarray([s.prefilled], np.int32)),
-                           jnp.asarray(np.asarray([live], np.int32)))
+    def _run_prefill(self, cache: PagedCache, tokens, sample):
         try:
             tok, logp, cache = self._prefill_prog(
-                self._params(), cache, jnp.asarray(tokens), sample)
+                self._params(), cache, tokens, sample)
         except Exception:
             # the pools were donated into the failed call and may be
             # deleted — without recovery every later step would die on
@@ -1237,14 +1331,10 @@ class ContinuousBatcher:
         # state was built: drain the pipeline before touching it
         if self._dstate is None or self._state_epoch != self._epoch:
             self._flush_inflight()
-        while True:
-            batch = self._ensure_decode_blocks()
-            if batch is not None:
-                break
-        batch = [x for x in batch if x.state == "decode"]
+        with self._spans.span("gen.decode.prepare",
+                              program="decode") as span:
+            batch = self._prepare_decode(span)
         if batch:
-            if self._dstate is None or self._state_epoch != self._epoch:
-                self._build_dstate(batch)
             try:
                 _FP_DECODE.fire()
             except Exception as e:  # noqa: BLE001 — fails only this batch
@@ -1256,12 +1346,12 @@ class ContinuousBatcher:
                     if s.state == "decode":
                         self._deliver_error(s, e)
                 return
-            if self._tables_dirty:
-                self._upload_tables()
             try:
-                out = self._decode_prog(self._params(), self._k,
-                                        self._v, self._dtables,
-                                        self._dstate)
+                with self._spans.span("gen.decode.dispatch",
+                                      program="decode", lanes=len(batch)):
+                    out = self._decode_prog(self._params(), self._k,
+                                            self._v, self._dtables,
+                                            self._dstate)
             except Exception:  # noqa: BLE001
                 self._reset_device()
                 return
@@ -1272,6 +1362,26 @@ class ContinuousBatcher:
         limit = self.async_depth if batch else 0
         while len(self._inflight) > limit:
             self._process_flight(now)
+
+    def _prepare_decode(self, span) -> List[GenSequence]:
+        """What the plain and the speculative step prepare alike, under
+        their ``gen.decode.prepare`` span: blocks for every lane's next
+        write, the decode state rebuilt if membership moved, the block
+        tables uploaded if one changed. Returns the sequences to step
+        (none: nothing to dispatch)."""
+        while True:
+            batch = self._ensure_decode_blocks()
+            if batch is not None:
+                break
+        batch = [x for x in batch if x.state == "decode"]
+        rebuilt = bool(batch) and (self._dstate is None
+                                   or self._state_epoch != self._epoch)
+        if rebuilt:
+            self._build_dstate(batch)
+        if batch and self._tables_dirty:
+            self._upload_tables()
+        span.annotate(rebuilt=rebuilt)
+        return batch
 
     def _ensure_decode_blocks(self):
         """Guarantee every decoding sequence owns blocks covering its
@@ -1365,14 +1475,17 @@ class ContinuousBatcher:
 
     def _process_flight(self, now: float) -> None:
         tok_d, logp_d, lanes = self._inflight.popleft()
-        t0 = time.perf_counter()
         try:
-            tok = np.asarray(tok_d)
-            logp = np.asarray(logp_d)
+            with self._spans.span("gen.wait", program="decode"):
+                tok = np.asarray(tok_d)
+                logp = np.asarray(logp_d)
         except Exception:  # noqa: BLE001 — the device step itself died
             self._reset_device()
             return
-        self._blocked_s += time.perf_counter() - t0
+        with self._spans.span("gen.deliver"):
+            self._deliver_flight(tok, logp, lanes, now)
+
+    def _deliver_flight(self, tok, logp, lanes, now: float) -> None:
         logp = _corrupt_logprobs(logp, lanes)   # serving.logprob drill
         emitted = []
         for i, s in enumerate(lanes):
@@ -1414,35 +1527,33 @@ class ContinuousBatcher:
         if not any(x.state == "decode" and x.num_beams == 1
                    for x in self._running):
             return
-        while True:
-            batch = self._ensure_decode_blocks()
-            if batch is not None:
-                break
-        batch = [x for x in batch if x.state == "decode"]
-        if not batch:
-            return
-        if self._dstate is None or self._state_epoch != self._epoch:
-            self._build_dstate(batch)
-        S = self.spec_tokens
-        B = self.max_seqs
-        draft = np.zeros((B, S), np.int32)
-        dlen = np.zeros((B,), np.int32)
-        drafted = 0
-        for i, s in enumerate(self._lanes):
-            if s is None or s.state != "decode":
-                continue
-            # never draft into the final position: the verifier's own
-            # sample always takes the last slot, so a full-length
-            # accept still retires exactly where plain decode would
-            cap = min(S, s.max_tokens - len(s.generated) - 1)
-            if cap <= 0:
-                continue
-            d = self._proposer.propose(s.prompt + s.generated, cap)[:cap]
-            draft[i, :len(d)] = d
-            dlen[i] = len(d)
-            drafted += len(d)
-        if drafted:
-            _M_SPEC_DRAFTED.inc(drafted)
+        spans = self._spans
+        with spans.span("gen.decode.prepare", program="verify") as span:
+            batch = self._prepare_decode(span)
+            if not batch:
+                return
+            S = self.spec_tokens
+            B = self.max_seqs
+            draft = np.zeros((B, S), np.int32)
+            dlen = np.zeros((B,), np.int32)
+            drafted = 0
+            for i, s in enumerate(self._lanes):
+                if s is None or s.state != "decode":
+                    continue
+                # never draft into the final position: the verifier's own
+                # sample always takes the last slot, so a full-length
+                # accept still retires exactly where plain decode would
+                cap = min(S, s.max_tokens - len(s.generated) - 1)
+                if cap <= 0:
+                    continue
+                d = self._proposer.propose(s.prompt + s.generated,
+                                           cap)[:cap]
+                draft[i, :len(d)] = d
+                dlen[i] = len(d)
+                drafted += len(d)
+            if drafted:
+                _M_SPEC_DRAFTED.inc(drafted)
+            draft_d, dlen_d = jnp.asarray(draft), jnp.asarray(dlen)
         try:
             _FP_VERIFY.fire()
         except Exception as e:  # noqa: BLE001 — fails only this batch
@@ -1450,31 +1561,33 @@ class ContinuousBatcher:
                 if s.state == "decode":
                     self._deliver_error(s, e)
             return
-        if self._tables_dirty:
-            self._upload_tables()
         try:
-            out = self._verify_prog(self._params(), self._k, self._v,
-                                    self._dtables, self._dstate,
-                                    jnp.asarray(draft), jnp.asarray(dlen))
+            with spans.span("gen.decode.dispatch", program="verify",
+                            lanes=len(batch)):
+                out = self._verify_prog(self._params(), self._k, self._v,
+                                        self._dtables, self._dstate,
+                                        draft_d, dlen_d)
         except Exception:  # noqa: BLE001
             self._reset_device()
             return
         self._k, self._v, self._dstate, pred_d, logp_d, n_emit_d = out
-        t0 = time.perf_counter()
         try:
-            pred = np.asarray(pred_d)
-            logp = np.asarray(logp_d)
-            n_emit = np.asarray(n_emit_d)
+            with spans.span("gen.wait", program="verify") as wait:
+                pred = np.asarray(pred_d)
+                logp = np.asarray(logp_d)
+                n_emit = np.asarray(n_emit_d)
         except Exception:  # noqa: BLE001 — the device step itself died
             self._reset_device()
             return
-        dt = time.perf_counter() - t0
-        self._blocked_s += dt
         # the verify transfer wait is the spec loop's device-blocked
-        # share of the step — published both as the aggregate device
-        # component (above) and under its own label for accept-rate
-        # tuning
-        _M_STEP.labels(component="verify").observe(dt)
+        # share of the step — published both in the aggregate device
+        # component (every gen.wait span) and under its own label for
+        # accept-rate tuning
+        _M_STEP.labels(component="verify").observe(wait.dur_ns * 1e-9)
+        with spans.span("gen.deliver"):
+            self._deliver_verify(pred, logp, n_emit, now)
+
+    def _deliver_verify(self, pred, logp, n_emit, now: float) -> None:
         logp = _corrupt_logprobs(logp, self._lanes)  # serving.logprob
         emitted = []
         for i, s in enumerate(list(self._lanes)):
@@ -1518,6 +1631,7 @@ class ContinuousBatcher:
         self._flush_inflight()
         if s.state != "decode":
             return
+        spans = self._spans
         W = s.num_beams
         bs = self._alloc.block_size
         root = {"tokens": [], "logprobs": [], "score": 0.0,
@@ -1559,29 +1673,33 @@ class ContinuousBatcher:
                     + (f" (request {s.request_id})" if s.request_id
                        else ""), stage="decode"))
                 return
-            for h in active:
-                need = self._alloc.blocks_for(h["cache_len"] + 1) \
-                    - len(h["blocks"])
-                if need > 0:
-                    got = _take(need)
-                    if got is None:
-                        _free_hyps(active)
-                        self._deliver_error(s, BlocksExhaustedError(
-                            f"beam search (width {W}) for sequence "
-                            f"{s.id} exhausted the KV block pool with "
-                            f"no younger sequence left to preempt"))
-                        return
-                    h["blocks"].extend(got)
-            B = self.max_seqs
-            tables = np.zeros((B, self.max_blocks), np.int32)
-            tokens = np.zeros((B,), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            live = np.zeros((B,), np.int32)
-            for i, h in enumerate(active):
-                tables[i, :len(h["blocks"])] = h["blocks"]
-                tokens[i] = h["next_input"]
-                lengths[i] = h["cache_len"]
-                live[i] = 1
+            with spans.span("gen.decode.prepare", program="beam"):
+                for h in active:
+                    need = self._alloc.blocks_for(h["cache_len"] + 1) \
+                        - len(h["blocks"])
+                    if need > 0:
+                        got = _take(need)
+                        if got is None:
+                            _free_hyps(active)
+                            self._deliver_error(s, BlocksExhaustedError(
+                                f"beam search (width {W}) for sequence "
+                                f"{s.id} exhausted the KV block pool "
+                                f"with no younger sequence left to "
+                                f"preempt"))
+                            return
+                        h["blocks"].extend(got)
+                B = self.max_seqs
+                tables = np.zeros((B, self.max_blocks), np.int32)
+                tokens = np.zeros((B,), np.int32)
+                lengths = np.zeros((B,), np.int32)
+                live = np.zeros((B,), np.int32)
+                for i, h in enumerate(active):
+                    tables[i, :len(h["blocks"])] = h["blocks"]
+                    tokens[i] = h["next_input"]
+                    lengths[i] = h["cache_len"]
+                    live[i] = 1
+                args = (jnp.asarray(tables), jnp.asarray(tokens),
+                        jnp.asarray(lengths), jnp.asarray(live))
             try:
                 _FP_DECODE.fire()
             except Exception as e:  # noqa: BLE001 — fails only s
@@ -1589,10 +1707,10 @@ class ContinuousBatcher:
                 self._deliver_error(s, e)
                 return
             try:
-                out = self._beam_prog(
-                    self._params(), self._k, self._v,
-                    jnp.asarray(tables), jnp.asarray(tokens),
-                    jnp.asarray(lengths), jnp.asarray(live))
+                with spans.span("gen.decode.dispatch", program="beam",
+                                lanes=len(active)):
+                    out = self._beam_prog(self._params(), self._k,
+                                          self._v, *args)
             except Exception:  # noqa: BLE001
                 # beam blocks are invisible to _reset_device (s.blocks
                 # is empty): free them first or they leak forever
@@ -1600,110 +1718,112 @@ class ContinuousBatcher:
                 self._reset_device()
                 return
             self._k, self._v, top_tok_d, top_lp_d = out
-            t0 = time.perf_counter()
             try:
-                top_tok = np.asarray(top_tok_d)
-                top_lp = np.asarray(top_lp_d)
+                with spans.span("gen.wait", program="beam"):
+                    top_tok = np.asarray(top_tok_d)
+                    top_lp = np.asarray(top_lp_d)
             except Exception:  # noqa: BLE001
                 _free_hyps(active)
                 self._reset_device()
                 return
-            self._blocked_s += time.perf_counter() - t0
-            # candidate selection, best cumulative logprob first. Ties
-            # break toward the older hypothesis and the lower-ranked
-            # candidate — for W=1 that is exactly argmax, which is what
-            # makes width-1 bit-identical to greedy decode.
-            cands = []
-            for i in range(len(active)):
-                for j in range(top_tok.shape[1]):
-                    cands.append(
-                        (active[i]["score"] + float(top_lp[i, j]), i, j))
-            cands.sort(key=lambda c: (-c[0], c[1], c[2]))
-            sel = []        # (parent_idx, token, logprob, score)
-            for score, i, j in cands:
-                if len(sel) >= W:
+            # the rest of the pass (selection, forks, on_step) is delivery
+            with spans.span("gen.deliver"):
+                # candidate selection, best cumulative logprob first. Ties
+                # break toward the older hypothesis and the lower-ranked
+                # candidate — for W=1 that is exactly argmax, which is what
+                # makes width-1 bit-identical to greedy decode.
+                cands = []
+                for i in range(len(active)):
+                    for j in range(top_tok.shape[1]):
+                        cands.append(
+                            (active[i]["score"] + float(top_lp[i, j]), i, j))
+                cands.sort(key=lambda c: (-c[0], c[1], c[2]))
+                sel = []        # (parent_idx, token, logprob, score)
+                for score, i, j in cands:
+                    if len(sel) >= W:
+                        break
+                    t = int(top_tok[i, j])
+                    lp = float(top_lp[i, j])
+                    h = active[i]
+                    done_now = ((s.eos_id is not None and t == s.eos_id)
+                                or len(h["tokens"]) + 1 >= s.max_tokens)
+                    if done_now:
+                        if len(finished) < W:
+                            finished.append(
+                                {"tokens": h["tokens"] + [t],
+                                 "logprobs": h["logprobs"] + [lp],
+                                 "score": score, "blocks": []})
+                        continue
+                    sel.append((i, t, lp, score))
+                # fork: the first child of each parent inherits its block
+                # list wholesale; siblings share() the full blocks and
+                # device-copy the partial tail at the divergence point
+                snapshots = [list(h["blocks"]) for h in active]
+                claimed = set()
+                new_active: List[dict] = []
+                failed = False
+                for i, t, lp, score in sel:
+                    L = active[i]["cache_len"] + 1   # resident after write
+                    if i not in claimed:
+                        claimed.add(i)
+                        blocks = active[i]["blocks"]
+                        active[i]["blocks"] = []
+                    else:
+                        pblocks = snapshots[i]
+                        full = L // bs
+                        blocks = []
+                        if full:
+                            self._alloc.share(pblocks[:full])
+                            blocks.extend(pblocks[:full])
+                        if L % bs:
+                            got = _take(1)
+                            if got is None:
+                                self._alloc.free(blocks)
+                                failed = True
+                                break
+                            blocks.extend(got)
+                            src = pblocks[full]
+                            self._k = self._k.at[:, got[0]].set(
+                                self._k[:, src])
+                            self._v = self._v.at[:, got[0]].set(
+                                self._v[:, src])
+                    new_active.append(
+                        {"tokens": active[i]["tokens"] + [t],
+                         "logprobs": active[i]["logprobs"] + [lp],
+                         "score": score, "next_input": t,
+                         "cache_len": L, "blocks": blocks})
+                if failed:
+                    _free_hyps(new_active)
+                    _free_hyps(active)
+                    self._deliver_error(s, BlocksExhaustedError(
+                        f"beam search (width {W}) for sequence {s.id} "
+                        f"could not fork a hypothesis: KV block pool "
+                        f"exhausted with no younger sequence to preempt"))
+                    return
+                _free_hyps([h for i, h in enumerate(active)
+                            if i not in claimed])
+                active = new_active
+                if self.on_step is not None:
+                    self.on_step("decode", [s.id])
+                if finished:
+                    best_fin = max(f["score"] for f in finished)
+                    # scores only fall as beams extend (logprobs <= 0), so
+                    # a finished hypothesis at least as good as every
+                    # survivor can never be overtaken
+                    if len(finished) >= W or not active or best_fin >= max(
+                            h["score"] for h in active):
+                        break
+        with spans.span("gen.deliver"):
+            pool = finished if finished else active
+            win = max(pool, key=lambda h: h["score"])
+            _free_hyps(active)
+            _M_TOKENS.labels(phase="decode").inc(len(win["tokens"]))
+            for t, lp in zip(win["tokens"], win["logprobs"]):
+                if s.state != "decode":
                     break
-                t = int(top_tok[i, j])
-                lp = float(top_lp[i, j])
-                h = active[i]
-                done_now = ((s.eos_id is not None and t == s.eos_id)
-                            or len(h["tokens"]) + 1 >= s.max_tokens)
-                if done_now:
-                    if len(finished) < W:
-                        finished.append(
-                            {"tokens": h["tokens"] + [t],
-                             "logprobs": h["logprobs"] + [lp],
-                             "score": score, "blocks": []})
-                    continue
-                sel.append((i, t, lp, score))
-            # fork: the first child of each parent inherits its block
-            # list wholesale; siblings share() the full blocks and
-            # device-copy the partial tail at the divergence point
-            snapshots = [list(h["blocks"]) for h in active]
-            claimed = set()
-            new_active: List[dict] = []
-            failed = False
-            for i, t, lp, score in sel:
-                L = active[i]["cache_len"] + 1   # resident after write
-                if i not in claimed:
-                    claimed.add(i)
-                    blocks = active[i]["blocks"]
-                    active[i]["blocks"] = []
-                else:
-                    pblocks = snapshots[i]
-                    full = L // bs
-                    blocks = []
-                    if full:
-                        self._alloc.share(pblocks[:full])
-                        blocks.extend(pblocks[:full])
-                    if L % bs:
-                        got = _take(1)
-                        if got is None:
-                            self._alloc.free(blocks)
-                            failed = True
-                            break
-                        blocks.extend(got)
-                        src = pblocks[full]
-                        self._k = self._k.at[:, got[0]].set(
-                            self._k[:, src])
-                        self._v = self._v.at[:, got[0]].set(
-                            self._v[:, src])
-                new_active.append(
-                    {"tokens": active[i]["tokens"] + [t],
-                     "logprobs": active[i]["logprobs"] + [lp],
-                     "score": score, "next_input": t,
-                     "cache_len": L, "blocks": blocks})
-            if failed:
-                _free_hyps(new_active)
-                _free_hyps(active)
-                self._deliver_error(s, BlocksExhaustedError(
-                    f"beam search (width {W}) for sequence {s.id} "
-                    f"could not fork a hypothesis: KV block pool "
-                    f"exhausted with no younger sequence to preempt"))
-                return
-            _free_hyps([h for i, h in enumerate(active)
-                        if i not in claimed])
-            active = new_active
-            if self.on_step is not None:
-                self.on_step("decode", [s.id])
-            if finished:
-                best_fin = max(f["score"] for f in finished)
-                # scores only fall as beams extend (logprobs <= 0), so
-                # a finished hypothesis at least as good as every
-                # survivor can never be overtaken
-                if len(finished) >= W or not active or best_fin >= max(
-                        h["score"] for h in active):
-                    break
-        pool = finished if finished else active
-        win = max(pool, key=lambda h: h["score"])
-        _free_hyps(active)
-        _M_TOKENS.labels(phase="decode").inc(len(win["tokens"]))
-        for t, lp in zip(win["tokens"], win["logprobs"]):
-            if s.state != "decode":
-                break
-            self._emit(s, int(t), float(lp), now)
-        if s.state != "done":
-            self._retire(s, device_synced=True)
+                self._emit(s, int(t), float(lp), now)
+            if s.state != "done":
+                self._retire(s, device_synced=True)
 
     # -- shared machinery ----------------------------------------------------
 
@@ -1856,6 +1976,13 @@ class ContinuousBatcher:
         s.generated.append(token)
         s.logprobs.append(logprob)
         s.next_input = token
+        if s.first_token_at is None:
+            # one observation a request: a recompute after a preemption
+            # emits nothing twice, and its first token is long out
+            s.first_token_at = t = time.monotonic()
+            if s.first_dispatch_at is not None:
+                _M_PREFILL_SPAN.observe(t - s.first_dispatch_at)
+                _M_TTFT.observe(t - s.arrived_at)
         if s.trace is not None:
             # one instant span per emitted token — the decode-step
             # analogue of the per-chunk prefill span (the guard is a

@@ -15,6 +15,8 @@ span                        emitted by
 ``server.generate``         via the ``X-HVD-TPU-Trace-Parent`` header
 ``batch.queue``             MicroBatcher admission -> dispatch coalescing wait
 ``batch.forward``           the padded micro-batch forward
+``gen.queue``               ContinuousBatcher, arrival -> the sequence's first
+                            prefill chunk is dispatched (once a request)
 ``gen.prefill``             ContinuousBatcher, one span per prefill chunk
 ``gen.decode``              one span per decode step that emitted a token
 ``gen.preempt``             KV-block preemption (the recompute is the next
@@ -35,6 +37,44 @@ publish best-effort to the rendezvous ``trace`` KV scope for live
 fleets. ``python -m tools.trace`` merges either source into one
 cross-host chrome://tracing timeline for a request id.
 
+**Loop spans** are the second kind: work that belongs to no single
+request, recorded on the thread that does it. The generation scheduler
+(``ContinuousBatcher._loop``) opens, every iteration, one root and up to
+seven kinds of children through a :class:`LoopTrace`:
+
+==========================  =================================================
+loop span                   covers (args)
+==========================  =================================================
+``gen.iter``                one scheduler iteration, the root (busy, running,
+                            waiting, inflight)
+``gen.admit``               queue drain, cancellations, expiry, admission
+``gen.prefill.prepare``     block growth, host arrays, ``SampleParams``, the
+                            table row of one prefill chunk
+``gen.prefill.dispatch``    the prefill program's call until it returns (seq,
+                            request, chunk, prefilled, total)
+``gen.decode.prepare``      decode block growth, state rebuild, table upload
+                            (program, rebuilt)
+``gen.decode.dispatch``     the decode / verify / beam program's call
+                            (program, lanes)
+``gen.wait``                every readback of a device result (program)
+``gen.deliver``             results mirrored into sequences, tokens put on
+                            streams, block registration, retirement, on_step
+==========================  =================================================
+
+They share the request spans' record shape (trace id ``gen-iter:<n>``,
+span id, parent, name, args) but are stamped with
+``time.perf_counter_ns()`` at both ends: the clock a benchmark in the
+same process holds. One context manager (:class:`LoopSpan`) does three
+things over the same interval: it appends the closed span to an
+always-on, bounded, in-memory ring (:func:`loop_spans` reads it; no
+file, no KV, no knob), it holds a ``jax.profiler.TraceAnnotation``
+``hvd.<name>`` open, so that any profile an operator captures shows the
+loop's phases on the scheduler's thread beside the device's operations
+on the profiler's clock, and it adds its *self time* (duration minus
+what its child spans cover) to the loop's per-iteration totals, which
+feed ``hvd_tpu_gen_phase_seconds{phase}``. A ``request`` arg ties a loop
+span to a sampled request's trace.
+
 Sampling is head-based and deterministic: ``HVD_TPU_TRACE_SAMPLE`` is
 the traced fraction, and the decision is a hash of the request id (not
 ``hash()`` — PYTHONHASHSEED must not split the decision across hosts),
@@ -47,6 +87,7 @@ and the timeline's no-op guard follow.
 
 import collections
 import hashlib
+import itertools
 import json
 import os
 import threading
@@ -59,8 +100,9 @@ from . import _locks
 __all__ = ["TraceContext", "Tracer", "Span", "tracer", "reset",
            "request_span", "span", "span_for", "emit_span", "collective",
            "current", "set_current", "sampled", "note_request",
-           "last_request_id", "new_request_id", "TRACE_PARENT_HEADER",
-           "ATTEMPT_HEADER", "KV_SCOPE"]
+           "last_request_id", "new_request_id", "LoopTrace", "LoopSpan",
+           "loop_spans", "TRACE_PARENT_HEADER", "ATTEMPT_HEADER",
+           "KV_SCOPE"]
 
 #: header carrying the upstream hop's encoded TraceContext so a
 #: replica's server span nests under the router's proxy span
@@ -302,8 +344,9 @@ def tracer() -> Optional[Tracer]:
 
 
 def reset() -> None:
-    """Close the span writer, drop the tracer and the thread's context,
-    and re-read the knobs — tests and elastic resets."""
+    """Close the span writer, drop the tracer, the thread's context and
+    the loop spans' ring, and re-read the knobs — tests and elastic
+    resets."""
     global _TRACER, _RESOLVED, _LAST_REQUEST
     tr = _TRACER
     if tr is not None:
@@ -316,6 +359,7 @@ def reset() -> None:
         _RESOLVED = False
     _LAST_REQUEST = None
     _tls.ctx = None
+    _LOOP_RING.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -472,3 +516,162 @@ def collective(entry: tuple) -> None:
         return
     tr.emit(f"collective:{entry[0]}:{entry[1]}", ctx.trace_id,
             uuid.uuid4().hex[:16], ctx.span_id, time.time() * 1e6, 0.0)
+
+
+# ---------------------------------------------------------------------------
+# loop spans: work that belongs to no single request
+# ---------------------------------------------------------------------------
+
+#: closed loop spans retained, oldest evicted first. A benchmark reads
+#: the ring up to 90 s after the part of a window it traced. The
+#: generation loop runs 4 iterations a second today and closes 6 spans in
+#: an iteration that only decodes, 11 in one that carries a prefill chunk
+#: too: ten times that rate for 90 s is 3600 iterations, 39 600 spans at
+#: the most. A record is a tuple of 8 with four ints and, on about half
+#: the spans, an args dict of its own: 386 bytes a span over such an
+#: iteration (sys.getsizeof, CPython 3.12), 15.8 MB for a full ring.
+_LOOP_RING_DEPTH = 40960
+
+_LOOP_RING: "collections.deque" = collections.deque(maxlen=_LOOP_RING_DEPTH)
+_LOOP_SPAN_IDS = itertools.count(1)
+_LOOP_ITER_IDS = itertools.count(1)
+_perf_ns = time.perf_counter_ns
+
+
+class _NullLoopSpan(_NullSpan):
+    """What :meth:`LoopTrace.span` returns outside an iteration."""
+
+    __slots__ = ()
+    dur_ns = 0
+
+
+_NULL_LOOP_SPAN = _NullLoopSpan()
+
+
+class LoopSpan:
+    """One interval of a loop's work: ring record, profiler annotation
+    and self-time accounting over the same two stamps. Made by
+    :class:`LoopTrace`; entered and exited on the loop's own thread."""
+
+    __slots__ = ("_loop", "name", "span_id", "parent", "args", "start_ns",
+                 "end_ns", "_child_ns", "_ann")
+
+    def __init__(self, loop: "LoopTrace", name: str, args: dict):
+        self._loop = loop
+        self.name = name
+        self.args = args
+        self.span_id = next(_LOOP_SPAN_IDS)
+        self._child_ns = 0
+        # with no profiler session on, an annotation records nothing:
+        # leave it out (270 ns to make one)
+        self._ann = loop._annotation("hvd." + name, **args) \
+            if loop._profiling else None
+
+    def annotate(self, **kw) -> None:
+        """Attach args to the ring's record before the span closes (the
+        profiler's annotation keeps the args it was opened with)."""
+        self.args.update(kw)
+
+    @property
+    def dur_ns(self) -> int:
+        return self.end_ns - self.start_ns
+
+    def __enter__(self):
+        loop = self._loop
+        self.parent = loop._top
+        loop._top = self
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.start_ns = _perf_ns()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        self.end_ns = end = _perf_ns()
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
+        loop, parent, name = self._loop, self.parent, self.name
+        loop._top = parent
+        dur = end - self.start_ns
+        self_ns = loop.self_ns
+        self_ns[name] = self_ns.get(name, 0) + dur - self._child_ns
+        if parent is not None:
+            parent._child_ns += dur
+        _LOOP_RING.append((name, self.start_ns, end, self.span_id,
+                           parent.span_id if parent is not None else None,
+                           loop.prefix, loop.iteration_id,
+                           self.args or None))
+        if parent is None:
+            loop._close_iteration()
+        return False
+
+
+class LoopTrace:
+    """The spans of one loop, recorded on the thread that runs it.
+
+    :meth:`iteration` opens the root span of one pass (named ``root``,
+    e.g. ``gen.iter``; its trace id is ``<prefix>-iter:<n>`` with
+    ``prefix`` the root's first name component and ``n`` unique in the
+    process); :meth:`span` opens a child of whatever span is innermost,
+    and is a no-op outside an iteration. When a root closes,
+    :attr:`self_ns` holds the pass's self time by span name, in
+    nanoseconds, which sum to the root's duration exactly; with
+    ``histogram`` (a family labelled ``phase``) and ``observe=True`` each
+    entry is observed under the name without its prefix."""
+
+    def __init__(self, root: str, histogram=None):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+        self.root = root
+        self.prefix = root.split(".", 1)[0]
+        self._histogram = histogram
+        self._bound: dict = {}
+        self._top: Optional[LoopSpan] = None
+        self._observe = False
+        self._profiling = False
+        self.iteration_id = 0
+        self.self_ns: dict = {}
+
+    def iteration(self, observe: bool = True, **args) -> LoopSpan:
+        self.iteration_id = next(_LOOP_ITER_IDS)
+        self._observe = observe
+        # asked once a pass: a session that starts mid-pass shows the
+        # loop from its next pass on
+        self._profiling = self._annotation.is_enabled()
+        self._top = None        # a pass that died mid-span left its stack
+        self.self_ns = {}
+        return LoopSpan(self, self.root, args)
+
+    def span(self, name: str, **args):
+        if self._top is None:
+            return _NULL_LOOP_SPAN
+        return LoopSpan(self, name, args)
+
+    def _close_iteration(self) -> None:
+        if not self._observe or self._histogram is None:
+            return
+        bound = self._bound
+        for name, ns in self.self_ns.items():
+            child = bound.get(name)
+            if child is None:
+                child = bound[name] = self._histogram.labels(
+                    phase=name.split(".", 1)[1])
+            child.observe(ns * 1e-9)
+
+
+def loop_spans(since: float = 0.0) -> list:
+    """The ring's loop spans that ended at or after ``since`` (an
+    instant on ``time.perf_counter()``), oldest first, as dicts:
+    ``trace`` (``<prefix>-iter:<n>``), ``span``, ``parent``, ``name``,
+    ``start_ns`` / ``end_ns`` (``time.perf_counter_ns()``) and ``args``."""
+    while True:
+        try:
+            ring = list(_LOOP_RING)
+            break
+        except RuntimeError:        # appended to while being copied
+            continue
+    since_ns = int(since * 1e9)
+    return [{"trace": f"{pre}-iter:{n}", "span": sid, "parent": parent,
+             "name": name, "start_ns": t0, "end_ns": t1,
+             "args": dict(args) if args else {}}
+            for name, t0, t1, sid, parent, pre, n, args in ring
+            if t1 >= since_ns]
