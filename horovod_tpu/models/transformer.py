@@ -24,7 +24,13 @@ with ``C`` prompt tokens, of which ``live`` are real) and a *decode
 step* (``C == 1``). New K/V are scattered into fixed-size cache blocks
 through each sequence's block table, then attention gathers the whole
 table back — so live KV memory scales with live tokens, not
-``max_len × batch``. Block 0 is the **null block**: padded slots and
+``max_len × batch``. The pools are written **in place**: one live
+``(layers, blocks, block_size, row)`` pool is threaded
+through the layers, layer ``i`` scatters at ``[i, blocks, offsets]``
+and gathers ``[i, block_tables]`` from what it has just written, and
+hands the pool on — no layer's slab is sliced out or put back, so a
+program that donates the pools holds a single version of each.
+Block 0 is the **null block**: padded slots and
 dead batch lanes write there (and only there), which keeps every shape
 static across steps — the jit cache sees exactly two programs, one per
 phase. The paged read path deliberately reuses
@@ -68,8 +74,12 @@ class TransformerConfig:
 class PagedCache:
     """The paged-KV view threaded through one incremental forward.
 
-    ``k``/``v``: ``(num_layers, num_blocks, block_size, heads, head_dim)``
-    pools (block 0 reserved as the null block). ``block_tables``:
+    ``k``/``v``: ``(num_layers, num_blocks, block_size, row)`` pools, a
+    token's ``heads * head_dim`` values at the head of each row (block 0
+    reserved as the null block), as
+    :func:`~horovod_tpu.serving.generation.kv_cache.make_pools` lays
+    them out; the forward returns them updated in place of the ones it
+    was given, every other row untouched. ``block_tables``:
     ``(B, max_blocks)`` int32 — each row maps a sequence's logical block
     index to a pool block (0-padded past its allocation). ``lengths``:
     ``(B,)`` tokens already in each sequence's cache (the chunk starts
@@ -131,14 +141,20 @@ class Attention(nn.Module):
             out = attn(q, k, v, mask, dt)
             return jnp.einsum("bshd,hde->bse", out, wo.astype(dt))
         # -- paged incremental path ---------------------------------------
-        # layer_cache: this layer's (num_blocks, block_size, H, D) pools
-        # plus the batch's tables/positions; see PagedCache. The named
+        # layer_cache: the whole (L, num_blocks, block_size, row) pools,
+        # this layer's static index into them, and the batch's
+        # tables/positions; see PagedCache. The layer writes and reads
+        # only its own [layer] plane of the one live pool and hands the
+        # updated pool on, so XLA keeps a single version of the donated
+        # buffer: no slab is sliced out and none is put back. Heads and
+        # head_dim are one merged, lane-aligned minor axis in the pool
+        # (make_pools says why: the layout a TPU gives the array). The named
         # scopes cost nothing at run time; under flax's own module
         # scopes they name a captured profile's operations
         # ``layer_<i>/attn/kv_write`` and so on (the MLP is ``layer_<i>/mlp``).
-        k_slab, v_slab, block_tables, positions, live = layer_cache
+        k_pool, v_pool, layer, block_tables, positions, live = layer_cache
         B, C = x.shape[0], x.shape[1]
-        block_size = k_slab.shape[1]
+        block_size = k_pool.shape[2]
         with jax.named_scope("kv_write"):
             # scatter the chunk's K/V through the block tables; pad
             # tokens (and dead lanes) route to the null block 0
@@ -148,18 +164,25 @@ class Attention(nn.Module):
                 block_tables, blk_idx.astype(jnp.int32), axis=1)  # (B, C)
             valid = jnp.arange(C)[None, :] < live[:, None]
             blocks = jnp.where(valid, blocks, 0)
-            k_slab = k_slab.at[blocks, offsets].set(k)
-            v_slab = v_slab.at[blocks, offsets].set(v)
+            # a token's row is its H*D values, zero-padded to the
+            # pool's lane-aligned width
+            pad = ((0, 0), (0, 0), (0, k_pool.shape[3] - H * D))
+            k_pool = k_pool.at[layer, blocks, offsets].set(
+                jnp.pad(k.reshape(B, C, H * D), pad))
+            v_pool = v_pool.at[layer, blocks, offsets].set(
+                jnp.pad(v.reshape(B, C, H * D), pad))
         with jax.named_scope("kv_gather"):
-            # gather every table slot back as one contiguous (B, T, H, D)
-            # view — T = max_blocks * block_size, position t lives at
-            # index t
-            kc = k_slab[block_tables].reshape(B, -1, H, D)
-            vc = v_slab[block_tables].reshape(B, -1, H, D)
+            # gather every table slot back, from the pool just written,
+            # as one contiguous (B, T, H, D) view — T = max_blocks *
+            # block_size, position t lives at index t
+            kc = k_pool[layer, block_tables][..., :H * D].reshape(
+                B, -1, H, D)
+            vc = v_pool[layer, block_tables][..., :H * D].reshape(
+                B, -1, H, D)
         with jax.named_scope("attention"):
             out = _default_attention(q, kc, vc, mask, dt)
         return (jnp.einsum("bshd,hde->bse", out, wo.astype(dt)),
-                (k_slab, v_slab))
+                (k_pool, v_pool))
 
 
 class MlpBlock(nn.Module):
@@ -217,7 +240,6 @@ class Transformer(nn.Module):
             x = emb.astype(cfg.dtype)[tokens] \
                 + pos.astype(cfg.dtype)[None, :S]
             mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
-            layer_caches = [None] * cfg.num_layers
         else:
             # incremental: S == chunk length C; absolute positions come
             # from each sequence's cache length (clipped only to keep
@@ -232,23 +254,21 @@ class Transformer(nn.Module):
             t_max = cache.block_tables.shape[1] * cache.k.shape[2]
             mask = (jnp.arange(t_max)[None, None, None, :]
                     <= positions[:, None, :, None])
-            layer_caches = [
-                (cache.k[i], cache.v[i], cache.block_tables, positions,
-                 cache.live) for i in range(cfg.num_layers)]
         k_pool, v_pool = (None, None) if cache is None else (cache.k,
                                                             cache.v)
         layer_cls = DecoderLayer
         if cfg.remat and cache is None:
             layer_cls = nn.remat(DecoderLayer, static_argnums=())
         for i in range(cfg.num_layers):
-            out = layer_cls(cfg, name=f"layer_{i}")(x, mask,
-                                                    layer_caches[i])
+            layer = layer_cls(cfg, name=f"layer_{i}")
             if cache is None:
-                x = out
+                x = layer(x, mask, None)
             else:
-                x, (k_i, v_i) = out
-                k_pool = k_pool.at[i].set(k_i)
-                v_pool = v_pool.at[i].set(v_i)
+                # one live pool: layer i scatters into and gathers from
+                # plane i of what layer i-1 returned
+                x, (k_pool, v_pool) = layer(
+                    x, mask, (k_pool, v_pool, i, cache.block_tables,
+                              positions, cache.live))
         x = nn.LayerNorm(dtype=cfg.dtype, param_dtype=jnp.float32,
                          name="ln_f")(x)
         if cache is not None and logits_at is not None:

@@ -67,7 +67,8 @@ def _decode(b64: str, dtype_name: str, shape: Sequence[int]) -> np.ndarray:
 def pack_blocks(hashes: Sequence[str], k_np: np.ndarray, v_np: np.ndarray,
                 wire_dtype: str = "native") -> Dict:
     """The ``/v1/kv/fetch`` response document for ``hashes``' block
-    contents (``k_np``/``v_np`` shaped ``(layers, n, bs, heads, hd)``).
+    contents (``k_np``/``v_np`` shaped ``(layers, n, bs, row)``, the
+    pools' own layout; the shape travels in the document).
     Returns ``{"hashes", "shape", "dtype", "wire_dtype", "k", "v"}``;
     an empty ``hashes`` packs to ``{"hashes": []}``."""
     if wire_dtype not in WIRE_DTYPES:

@@ -432,14 +432,29 @@ class BlockAllocator:
         self._publish(in_use, stats)
 
 
+#: a TPU's lane count: the minor axis of an array is tiled in 128s
+_LANES = 128
+
+
 def make_pools(model_cfg, num_blocks: int, block_size: int):
     """Zeroed K/V pools for ``model_cfg`` (a
     :class:`~horovod_tpu.models.transformer.TransformerConfig`):
-    ``(num_layers, num_blocks, block_size, heads, head_dim)`` each, in
-    the model's activation dtype."""
+    ``(num_layers, num_blocks, block_size, row)`` each, in the model's
+    activation dtype. A row is one token's K (or V): its
+    ``heads * head_dim`` values, then zeros up to a multiple of 128.
+
+    The shape is chosen for the layout a TPU gives it. The device tiles
+    an array's two minor axes ``(8, 128)`` and stores it in whichever
+    axis order pads least. ``(..., 25, 64)`` would pad to ``(32, 128)``,
+    so a ``(L, N, bs, heads, head_dim)`` pool is stored blocks-minor,
+    and so is ``(L, N, bs, 1600)`` as soon as ``N`` is a multiple of
+    128 — and the paged programs then relayout every slab they scatter
+    into or gather from, or the whole pool. A row that is a multiple of
+    128 pads nothing, row-major wins for any ``N``, and a token's row is
+    contiguous; the device would have padded 1600 to 1664 itself."""
     import jax.numpy as jnp
-    shape = (model_cfg.num_layers, num_blocks, block_size,
-             model_cfg.num_heads, model_cfg.head_dim)
+    row = -(-model_cfg.num_heads * model_cfg.head_dim // _LANES) * _LANES
+    shape = (model_cfg.num_layers, num_blocks, block_size, row)
     return jnp.zeros(shape, model_cfg.dtype), jnp.zeros(shape,
                                                         model_cfg.dtype)
 
@@ -455,7 +470,7 @@ def block_bytes(model_cfg, block_size: int) -> int:
 def gather_blocks(k, v, blocks: Sequence[int]):
     """Materialize the contents of pool ``blocks`` on the host for the
     disagg KV wire: ``(k_np, v_np)``, each
-    ``(num_layers, len(blocks), block_size, heads, head_dim)`` in the
+    ``(num_layers, len(blocks), block_size, row)`` in the
     pool dtype. Must run on the scheduler thread (the pools are donated
     device buffers the scheduler owns)."""
     idx = list(blocks)
@@ -478,7 +493,12 @@ def build_program(model):
     """The raw-logits jitted incremental forward.
 
     ``(params, PagedCache, tokens) -> (logits, PagedCache)``; the cache
-    argument is donated so XLA updates the pools in place. Called with
+    argument is donated and the forward threads one live pool through
+    its layers (each scatters into and gathers from its own plane of
+    it), so XLA updates the pools in place: the compiled program
+    aliases them input to output and copies neither a layer's slab nor
+    a pool (``tests/test_paged_inplace.py`` holds all five programs to
+    that). Called with
     ``tokens`` of shape ``(1, prefill_chunk)`` it is the prefill
     program; with ``(max_seqs, DECODE_WIDTH)`` it is the decode
     program — two compilations of one function. Memoized on the model
@@ -761,8 +781,16 @@ def build_verify_program(model, spec_tokens: int):
             tables, jnp.minimum(positions // block_size,
                                 tables.shape[1] - 1), axis=1)
         offsets = positions % block_size
-        orig_k = k[:, blocks, offsets]
-        orig_v = v[:, blocks, offsets]
+        layers = jnp.arange(k.shape[0])[:, None, None]
+        orig_k = k[layers, blocks, offsets]
+        orig_v = v[layers, blocks, offsets]
+        # the forward updates the pools in place. Writing the snapshot
+        # straight back changes no value and makes the pool the forward
+        # sees the successor of the one just read: XLA then orders the
+        # read before the in-place writes; left unordered, it copies
+        # each whole pool to keep the old version readable
+        k = k.at[layers, blocks, offsets].set(orig_k)
+        v = v.at[layers, blocks, offsets].set(orig_v)
 
         cache = PagedCache(k, v, tables, state.lengths, width)
         logits, cache = model.apply(params, chunk, cache=cache)
@@ -801,8 +829,8 @@ def build_verify_program(model, spec_tokens: int):
         # the committed prefix, whose restore writes go to block 0
         committed = jnp.arange(C)[None, :] < n_emit[:, None]
         rb = jnp.where(committed, 0, blocks)
-        new_k = cache.k.at[:, rb, offsets].set(orig_k)
-        new_v = cache.v.at[:, rb, offsets].set(orig_v)
+        new_k = cache.k.at[layers, rb, offsets].set(orig_k)
+        new_v = cache.v.at[layers, rb, offsets].set(orig_v)
 
         retired = alive & ((lead < n_emit) | (state.remaining <= n_emit))
         last = jnp.take_along_axis(
