@@ -219,7 +219,7 @@ def server_phase(cfg=None, requests=REQUESTS, **engine_kwargs) -> dict:
         placement = {
             "params_devices": sorted(map(str, _devices_of(engine.params))),
             "kv_pool_devices": sorted(map(str, _devices_of(
-                (engine.batcher._k, engine.batcher._v))))}
+                engine.batcher._pools)))}
         # warm-up: one request long enough for a second prefill chunk
         # compiles the prefill and decode programs; its per-token deadline
         # is lifted because a token that waits on a compile is not starved

@@ -475,11 +475,11 @@ def generation_sweep(num_requests: int = 24, batch_slots: int = 8,
                     chunk = prompts[i][done:done + prefill_chunk]
                     buf = np.zeros((1, prefill_chunk), np.int32)
                     buf[0, :len(chunk)] = chunk
-                    cache = PagedCache(k, v, jnp.asarray(tables[j:j + 1]),
+                    cache = PagedCache((k, v), jnp.asarray(tables[j:j + 1]),
                                        jnp.asarray([done], jnp.int32),
                                        jnp.asarray([len(chunk)], jnp.int32))
                     logits, cache = program(params, cache, jnp.asarray(buf))
-                    k, v = cache.k, cache.v
+                    k, v = cache.pools
                     done += len(chunk)
                 seqs[j].append(int(np.argmax(
                     np.asarray(logits)[0, len(chunk) - 1])))
@@ -493,10 +493,10 @@ def generation_sweep(num_requests: int = 24, batch_slots: int = 8,
                     tokens[j, 0] = seqs[j][-1]
                     lengths[j] = len(seqs[j]) - 1
                     live[j] = 1
-                cache = PagedCache(k, v, jnp.asarray(tables),
+                cache = PagedCache((k, v), jnp.asarray(tables),
                                    jnp.asarray(lengths), jnp.asarray(live))
                 logits, cache = program(params, cache, jnp.asarray(tokens))
-                k, v = cache.k, cache.v
+                k, v = cache.pools
                 decode_steps += 1
                 for j in range(len(group)):
                     seqs[j].append(int(np.argmax(np.asarray(logits)[j, 0])))

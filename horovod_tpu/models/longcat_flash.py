@@ -1,0 +1,417 @@
+"""LongCat-Flash: latent attention, the shortcut-connected double layer,
+routed experts with zero-compute experts. Serving first.
+
+The second block the generation plane serves (the first is the GPT-2
+block of :mod:`.transformer`). It enters through the same contract,
+``apply(params, tokens, cache=PagedCache, logits_at=...)``, and declares
+its cache (:meth:`LongcatFlashConfig.cache_spec`): one **latent row**
+an attention, shared by all heads, and two attentions a layer.
+
+One layer (RMSNorm, no biases; ``A`` latent attention, ``F`` a gated
+MLP, ``M`` the expert layer)::
+
+    h = h + A_0(in_0(h))
+    u = post_0(h)
+    m = M(u)                  # the shortcut: added at the layer's end
+    h = h + F_0(u)
+    h = h + A_1(in_1(h))
+    h = h + F_1(post_1(h)) + m
+
+Latent attention (MLA) keeps, for a token, the normalised and scaled
+``kv_lora_rank`` latent ``c`` and one rotated ``qk_rope_head_dim`` key
+``kr`` for all heads: a pool row is ``[c | kr | zeros]`` up to a
+multiple of 128. Two forms read it. **Absorbed** (a decode step, any
+narrow chunk): ``W_kvb``'s key half is folded into the query and its
+value half applied after the weighted sum, so scores and context are
+taken against the cached rows themselves and the cached context is never
+expanded. **Expanded** (a wide prefill chunk): keys and values are
+rebuilt from the rows, by groups of heads, and attention runs at head
+width; past ``r (dn + dv) / (2 r - dn - dv)`` queries a chunk (171 at
+the published widths) it is the cheaper of the two, by the count of
+multiplications alone.
+
+Weights are created and held in ``param_dtype`` (bfloat16 when served;
+the router and its correction bias in float32) and nothing casts a
+weight inside a call: a matmul takes them as they lie. The expert layer
+is :func:`horovod_tpu.parallel.moe.held_experts_mlp`: this chip holds
+``held_experts``, routes over all of the router's outputs, and computes
+its own experts' part. Routing counts leave the model through the flax
+collection ``moe_stats`` (one int32 vector a layer).
+
+Named scopes, under flax's module scopes:
+``layer_<i>/attn_<j>/{q_proj,kv_write,kv_gather,absorb,attention,out_proj}``,
+``layer_<i>/mlp_<j>``, ``layer_<i>/moe/{router,sort,experts,identity,combine}``,
+``head``.
+"""
+
+import dataclasses
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..parallel.moe import STATS_COLLECTION, held_experts_mlp, route_topk
+from .transformer import CacheSpec, PagedCache
+
+Dtype = Any
+
+#: heads the expanded form of latent attention rebuilds keys and values
+#: for at a time: its float32 scores are heads x chunk x table x 4 bytes
+#: (0.55 GB at 16 x 512 x 16896); a head count it does not divide is
+#: taken whole
+HEAD_GROUP = 16
+
+
+@dataclasses.dataclass(frozen=True)
+class LongcatFlashConfig:
+    """The source's own key names (``config.json`` of
+    ``meituan-longcat/LongCat-Flash-Chat``), published values as
+    defaults. ``n_routed_experts`` is the whole model's count (the
+    router has ``n_routed_experts + zero_expert_num`` outputs);
+    ``held_experts`` names the FFN experts whose weights this chip
+    holds, ``(first, end)``."""
+
+    vocab_size: int = 131072
+    hidden_size: int = 6144
+    ffn_hidden_size: int = 12288
+    expert_ffn_hidden_size: int = 2048
+    num_layers: int = 28
+    num_attention_heads: int = 64
+    kv_lora_rank: int = 512
+    q_lora_rank: int = 1536
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    qk_nope_head_dim: int = 128
+    mla_scale_q_lora: bool = True
+    mla_scale_kv_lora: bool = True
+    routed_scaling_factor: float = 6.0
+    n_routed_experts: int = 512
+    zero_expert_num: int = 256
+    moe_topk: int = 12
+    max_position_embeddings: int = 131072
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 1e7
+    held_experts: Tuple[int, int] = (0, 512)
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = jnp.bfloat16
+
+    @property
+    def max_seq_len(self) -> int:
+        return self.max_position_embeddings
+
+    def cache_spec(self) -> CacheSpec:
+        """One latent row an attention, two attentions a layer."""
+        return CacheSpec(
+            planes=2 * self.num_layers,
+            rows=(("latent", self.kv_lora_rank + self.qk_rope_head_dim),),
+            dtype=self.dtype)
+
+    def expands(self, chunk: int) -> bool:
+        """Whether a chunk of ``chunk`` queries takes the expanded form."""
+        r, dn, dv = self.kv_lora_rank, self.qk_nope_head_dim, self.v_head_dim
+        return chunk * (2 * r - dn - dv) > r * (dn + dv)
+
+
+def _init(std=0.02):
+    return nn.initializers.normal(std)
+
+
+def rms_norm(x, weight, eps):
+    """Normalise in float32, weigh in the activations' dtype."""
+    x32 = x.astype(jnp.float32)
+    x32 = x32 * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return x32.astype(x.dtype) * weight.astype(x.dtype)
+
+
+def rotary_interleaved(x, positions, theta):
+    """Rotate the pairs ``(x[2i], x[2i+1])`` of the last axis by
+    ``position * theta ** (-2i / d)``. ``x``: ``(B, S, ..., d)``;
+    ``positions``: ``(B, S)``. Angles in float32."""
+    d = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[..., None] * freq     # (B, S, d/2)
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[2:])
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
+                    axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+    """MLA. ``layer_cache`` is ``(pool, plane, block_tables, live)`` on
+    the paged path; returns ``(out, pool)`` then, ``out`` otherwise."""
+
+    cfg: LongcatFlashConfig
+
+    @nn.compact
+    def __call__(self, x, positions, mask, layer_cache=None):
+        cfg = self.cfg
+        D, H = cfg.hidden_size, cfg.num_attention_heads
+        r, rq = cfg.kv_lora_rank, cfg.q_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        pd, dt = cfg.param_dtype, cfg.dtype
+        q_a = self.param("q_a_proj", _init(), (D, rq), pd)
+        q_a_norm = self.param("q_a_layernorm", nn.initializers.ones, (rq,),
+                              pd)
+        q_b = self.param("q_b_proj", _init(), (rq, H, dn + dr), pd)
+        kv_a = self.param("kv_a_proj", _init(), (D, r + dr), pd)
+        kv_a_norm = self.param("kv_a_layernorm", nn.initializers.ones,
+                               (r,), pd)
+        # kv_b_proj, held as its two halves so that neither form slices
+        # a weight inside a call
+        w_uk = self.param("kv_b_proj_nope", _init(), (r, H, dn), pd)
+        w_uv = self.param("kv_b_proj_v", _init(), (r, H, dv), pd)
+        w_o = self.param("o_proj", _init(), (H, dv, D), pd)
+        B, C = x.shape[0], x.shape[1]
+
+        with jax.named_scope("q_proj"):
+            q = rms_norm(x @ q_a, q_a_norm, cfg.rms_norm_eps)
+            q = jnp.einsum("bsq,qhd->bshd", q, q_b)
+            if cfg.mla_scale_q_lora:
+                q = q * jnp.asarray((D / rq) ** 0.5, dt)
+            q_nope = q[..., :dn]
+            q_rope = rotary_interleaved(q[..., dn:], positions,
+                                        cfg.rope_theta)
+        with jax.named_scope("kv_write"):
+            ckr = x @ kv_a
+            c = rms_norm(ckr[..., :r], kv_a_norm, cfg.rms_norm_eps)
+            if cfg.mla_scale_kv_lora:
+                c = c * jnp.asarray((D / r) ** 0.5, dt)
+            kr = rotary_interleaved(ckr[..., r:], positions, cfg.rope_theta)
+            # what a token leaves behind: [c | kr | zeros] up to the
+            # pool's lane-aligned row
+            width = r + dr if layer_cache is None \
+                else layer_cache[0].shape[3]
+            new_rows = jnp.pad(jnp.concatenate([c, kr], axis=-1),
+                               ((0, 0), (0, 0), (0, width - r - dr)))
+            if layer_cache is not None:
+                pool, plane, block_tables, live = layer_cache
+                block_size = pool.shape[2]
+                blocks = jnp.take_along_axis(
+                    block_tables,
+                    (positions // block_size).astype(jnp.int32), axis=1)
+                valid = jnp.arange(C)[None, :] < live[:, None]
+                # pad tokens and dead lanes write to the null block 0
+                blocks = jnp.where(valid, blocks, 0)
+                pool = pool.at[plane, blocks, positions % block_size].set(
+                    new_rows)
+        if layer_cache is None:
+            rows = new_rows
+        else:
+            with jax.named_scope("kv_gather"):
+                # every table slot, from the pool just written:
+                # position t of a sequence lives at index t
+                rows = pool[plane, block_tables].reshape(B, -1, width)
+
+        scale = (dn + dr) ** -0.5
+        if cfg.expands(C):
+            ctx = self._expanded(q_nope, q_rope, rows, mask, w_uk, w_uv,
+                                 scale)
+        else:
+            ctx = self._absorbed(q_nope, q_rope, rows, mask, w_uk, w_uv,
+                                 scale)
+        with jax.named_scope("out_proj"):
+            out = jnp.einsum("bshd,hde->bse", ctx, w_o)
+        return out if layer_cache is None else (out, pool)
+
+    def _absorbed(self, q_nope, q_rope, rows, mask, w_uk, w_uv, scale):
+        """Scores and context against the cached rows themselves."""
+        cfg = self.cfg
+        r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        with jax.named_scope("absorb"):
+            q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)
+            # one contraction over the whole row: [q_lat | q_rope | 0]
+            # against [c | kr | 0]
+            q_row = jnp.pad(
+                jnp.concatenate([q_lat, q_rope], axis=-1),
+                ((0, 0),) * 3 + ((0, rows.shape[-1] - r - dr),))
+        with jax.named_scope("attention"):
+            scores = jnp.einsum("bshw,btw->bhst", q_row, rows,
+                                preferred_element_type=jnp.float32)
+            probs = _masked_softmax(scores * scale, mask).astype(rows.dtype)
+            ctx = jnp.einsum("bhst,btw->bshw", probs, rows)[..., :r]
+        with jax.named_scope("absorb"):
+            return jnp.einsum("bshr,rhd->bshd", ctx, w_uv)
+
+    def _expanded(self, q_nope, q_rope, rows, mask, w_uk, w_uv, scale):
+        """Keys and values rebuilt from the rows, a group of heads at a
+        time, attention at head width."""
+        cfg = self.cfg
+        r, dr, H = (cfg.kv_lora_rank, cfg.qk_rope_head_dim,
+                    cfg.num_attention_heads)
+        g = HEAD_GROUP if H % HEAD_GROUP == 0 else H
+        c, kr = rows[..., :r], rows[..., r:r + dr]
+
+        def by_group(a, axis):       # split the head axis, groups first
+            a = a.reshape(a.shape[:axis] + (H // g, g) + a.shape[axis + 1:])
+            return jnp.moveaxis(a, axis, 0)
+
+        def group(args):
+            qn, qr, uk, uv = args
+            k_nope = jnp.einsum("btr,rhd->bthd", c, uk)
+            v = jnp.einsum("btr,rhd->bthd", c, uv)
+            scores = (jnp.einsum("bshd,bthd->bhst", qn, k_nope,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bshd,btd->bhst", qr, kr,
+                                   preferred_element_type=jnp.float32))
+            probs = _masked_softmax(scores * scale, mask).astype(v.dtype)
+            return jnp.einsum("bhst,bthd->bshd", probs, v)
+
+        with jax.named_scope("attention"):
+            ctx = jax.lax.map(group, (by_group(q_nope, 2),
+                                      by_group(q_rope, 2),
+                                      by_group(w_uk, 1), by_group(w_uv, 1)))
+            # (groups, B, S, g, dv) -> (B, S, H, dv)
+            ctx = jnp.moveaxis(ctx, 0, 2)
+            return ctx.reshape(ctx.shape[:2] + (H, ctx.shape[-1]))
+
+
+def _masked_softmax(scores, mask):
+    """Causal softmax in float32."""
+    scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
+    return jax.nn.softmax(scores, axis=-1)
+
+
+class GatedMlp(nn.Module):
+    """``W_down(silu(W_gate x) * (W_up x))``."""
+
+    cfg: LongcatFlashConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        D, F, pd = cfg.hidden_size, cfg.ffn_hidden_size, cfg.param_dtype
+        gate = self.param("gate_proj", _init(), (D, F), pd)
+        up = self.param("up_proj", _init(), (D, F), pd)
+        down = self.param("down_proj", _init(), (F, D), pd)
+        return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+class ExpertLayer(nn.Module):
+    """The router over every output of the model and this chip's held
+    experts. Returns ``M(u)`` as far as this chip computes it."""
+
+    cfg: LongcatFlashConfig
+
+    @nn.compact
+    def __call__(self, u, valid):
+        cfg = self.cfg
+        D, Fe, pd = cfg.hidden_size, cfg.expert_ffn_hidden_size, \
+            cfg.param_dtype
+        n_out = cfg.n_routed_experts + cfg.zero_expert_num
+        n_held = cfg.held_experts[1] - cfg.held_experts[0]
+        router = self.param("router", _init(), (D, n_out), jnp.float32)
+        bias = self.param("e_score_correction_bias", nn.initializers.zeros,
+                          (n_out,), jnp.float32)
+        w_gate = self.param("experts_gate", _init(), (n_held, D, Fe), pd)
+        w_up = self.param("experts_up", _init(), (n_held, D, Fe), pd)
+        w_down = self.param("experts_down", _init(), (n_held, Fe, D), pd)
+        B, C = u.shape[0], u.shape[1]
+        flat = u.reshape(B * C, D)
+        with jax.named_scope("router"):
+            # the router runs in float32, whatever the activations are
+            logits = jnp.dot(flat.astype(jnp.float32), router,
+                             precision=jax.lax.Precision.HIGHEST)
+            idx, weights = route_topk(logits, bias, cfg.moe_topk,
+                                      cfg.routed_scaling_factor)
+        held, zero, stats = held_experts_mlp(
+            flat, idx, weights, w_gate, w_up, w_down, cfg.held_experts,
+            cfg.n_routed_experts, valid=valid.reshape(B * C))
+        if not self.is_initializing():   # init would keep the counts
+            self.sow(STATS_COLLECTION, "counts", stats)
+        with jax.named_scope("combine"):
+            return (held + zero).astype(u.dtype).reshape(B, C, D)
+
+
+class DoubleLayer(nn.Module):
+    """The shortcut-connected double layer (module docstring)."""
+
+    cfg: LongcatFlashConfig
+
+    @nn.compact
+    def __call__(self, h, positions, mask, valid, layer_cache=None):
+        cfg = self.cfg
+        norm = lambda name, x: rms_norm(  # noqa: E731
+            x, self.param(name, nn.initializers.ones, (cfg.hidden_size,),
+                          cfg.param_dtype), cfg.rms_norm_eps)
+        pool = None
+
+        def attend(j, x):
+            nonlocal pool
+            attn = LatentAttention(cfg, name=f"attn_{j}")
+            if layer_cache is None:
+                return attn(x, positions, mask)
+            first_pool, layer, block_tables, live = layer_cache
+            out, pool = attn(
+                x, positions, mask,
+                (first_pool if pool is None else pool, 2 * layer + j,
+                 block_tables, live))
+            return out
+
+        h = h + attend(0, norm("input_layernorm_0", h))
+        u = norm("post_attention_layernorm_0", h)
+        m = ExpertLayer(cfg, name="moe")(u, valid)
+        h = h + GatedMlp(cfg, name="mlp_0")(u)
+        h = h + attend(1, norm("input_layernorm_1", h))
+        h = h + GatedMlp(cfg, name="mlp_1")(
+            norm("post_attention_layernorm_1", h)) + m
+        return h if layer_cache is None else (h, pool)
+
+
+class LongcatFlash(nn.Module):
+    cfg: LongcatFlashConfig
+
+    @nn.compact
+    def __call__(self, tokens, cache: Optional[PagedCache] = None,
+                 logits_at=None):
+        cfg = self.cfg
+        B, S = tokens.shape
+        emb = self.param("embed_tokens", _init(),
+                         (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
+        head = self.param("lm_head", _init(),
+                          (cfg.hidden_size, cfg.vocab_size), cfg.param_dtype)
+        h = emb[tokens].astype(cfg.dtype)
+        if cache is None:
+            positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+            mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
+            valid = jnp.ones((B, S), jnp.bool_)
+            pool = None
+        else:
+            # incremental: the chunk starts at each sequence's cache
+            # length; gathered slot t holds absolute position t, and a
+            # query at position p attends to every t <= p
+            positions = cache.lengths[:, None] + jnp.arange(S)[None, :]
+            (pool,) = cache.pools
+            t_max = cache.block_tables.shape[1] * pool.shape[2]
+            mask = (jnp.arange(t_max)[None, None, None, :]
+                    <= positions[:, None, :, None])
+            valid = jnp.arange(S)[None, :] < cache.live[:, None]
+        for i in range(cfg.num_layers):
+            layer = DoubleLayer(cfg, name=f"layer_{i}")
+            if cache is None:
+                h = layer(h, positions, mask, valid)
+            else:
+                h, pool = layer(h, positions, mask, valid,
+                                (pool, i, cache.block_tables, cache.live))
+        h = rms_norm(h, self.param("norm", nn.initializers.ones,
+                                   (cfg.hidden_size,), cfg.param_dtype),
+                     cfg.rms_norm_eps)
+        if cache is not None and logits_at is not None:
+            # the caller samples one position a row: project only that
+            h = jnp.take_along_axis(
+                h, logits_at.astype(jnp.int32)[:, None, None], axis=1)
+        with jax.named_scope("head"):
+            # float32 logits from the weights as they lie
+            logits = jnp.einsum("bse,ev->bsv", h, head,
+                                preferred_element_type=jnp.float32)
+        if cache is None:
+            return logits
+        cache = dataclasses.replace(cache, pools=(pool,))
+        if logits_at is not None:
+            return logits[:, 0], cache
+        return logits, cache

@@ -1,5 +1,18 @@
 """Decoder-only transformer, TPU-first.
 
+Which models this package serves: :class:`Transformer` here is the
+GPT-2 block (multi-head attention, learned positions, LayerNorm, GELU,
+tied head; ``gpt2-xl`` is the published architecture it matches) and
+:mod:`.longcat_flash` is the LongCat-Flash block (latent attention,
+the shortcut-connected double layer, routed and zero-compute experts).
+Both enter :class:`~horovod_tpu.serving.generation.GenerationEngine`
+through the same ``apply(params, tokens, cache=PagedCache,
+logits_at=...)`` contract, and each declares the cache it keeps with a
+:class:`CacheSpec` (``cfg.cache_spec()``); :class:`PagedCache` and
+:class:`CacheSpec` live in this file for both. ``models/`` also holds
+ResNet, Inception, VGG and an MLP, which are trained, not served by
+the generation plane.
+
 The reference has no transformer (its benchmarks are CNNs), but the TPU
 build's parallelism strategies (TP/SP/PP/EP/ring attention — SURVEY.md §2.3,
 §7 stage 8) need a first-class transformer to exercise them. Design:
@@ -45,7 +58,7 @@ hot path (the selected row stays bit-identical to the full projection).
 """
 
 import dataclasses
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -69,14 +82,43 @@ class TransformerConfig:
     attention_fn: Optional[Callable] = None
     remat: bool = False
 
+    def cache_spec(self) -> "CacheSpec":
+        """A layer's attention keeps a token's K and its V: two rows of
+        ``heads * head_dim`` values, one plane a layer."""
+        width = self.num_heads * self.head_dim
+        return CacheSpec(planes=self.num_layers,
+                         rows=(("k", width), ("v", width)),
+                         dtype=self.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What a served model keeps in the paged cache: its declaration.
+
+    ``planes``: how many attention sublayers keep state (a pool's
+    leading axis). ``rows``: ``(name, width)`` for each array a token
+    leaves behind in one plane: one pool a row, ``width`` values of
+    ``dtype`` at the head of a pool row.
+    :func:`~horovod_tpu.serving.generation.kv_cache.make_pools`,
+    ``block_bytes``, ``gather_blocks``/``scatter_blocks``, the disagg
+    wire codec and the five programs read nothing about a model's cache
+    but this; the model's paged forward gets the pools in the order of
+    ``rows`` and hands them back in it.
+    """
+
+    planes: int
+    rows: Tuple[Tuple[str, int], ...]
+    dtype: Dtype
+
 
 @dataclasses.dataclass(frozen=True)
 class PagedCache:
-    """The paged-KV view threaded through one incremental forward.
+    """The paged-cache view threaded through one incremental forward.
 
-    ``k``/``v``: ``(num_layers, num_blocks, block_size, row)`` pools, a
-    token's ``heads * head_dim`` values at the head of each row (block 0
-    reserved as the null block), as
+    ``pools``: one ``(planes, num_blocks, block_size, row)`` array for
+    each row of the model's :class:`CacheSpec`, in its order (for
+    :class:`Transformer`: K and V, a token's ``heads * head_dim`` values
+    at the head of each row; block 0 reserved as the null block), as
     :func:`~horovod_tpu.serving.generation.kv_cache.make_pools` lays
     them out; the forward returns them updated in place of the ones it
     was given, every other row untouched. ``block_tables``:
@@ -89,15 +131,14 @@ class PagedCache:
     through ``jax.jit`` argument trees.
     """
 
-    k: Any
-    v: Any
+    pools: Tuple[Any, ...]
     block_tables: Any
     lengths: Any
     live: Any
 
 
 jax.tree_util.register_dataclass(
-    PagedCache, data_fields=["k", "v", "block_tables", "lengths", "live"],
+    PagedCache, data_fields=["pools", "block_tables", "lengths", "live"],
     meta_fields=[])
 
 
@@ -251,11 +292,10 @@ class Transformer(nn.Module):
                 + pos.astype(cfg.dtype)[safe_pos]
             # gathered cache slot t holds absolute position t; a chunk
             # query at absolute position p attends to every t <= p
-            t_max = cache.block_tables.shape[1] * cache.k.shape[2]
+            t_max = cache.block_tables.shape[1] * cache.pools[0].shape[2]
             mask = (jnp.arange(t_max)[None, None, None, :]
                     <= positions[:, None, :, None])
-        k_pool, v_pool = (None, None) if cache is None else (cache.k,
-                                                            cache.v)
+        k_pool, v_pool = (None, None) if cache is None else cache.pools
         layer_cls = DecoderLayer
         if cfg.remat and cache is None:
             layer_cls = nn.remat(DecoderLayer, static_argnums=())
@@ -288,6 +328,6 @@ class Transformer(nn.Module):
         if cache is None:
             return logits
         if logits_at is not None:
-            return logits[:, 0], dataclasses.replace(cache, k=k_pool,
-                                                     v=v_pool)
-        return logits, dataclasses.replace(cache, k=k_pool, v=v_pool)
+            return logits[:, 0], dataclasses.replace(
+                cache, pools=(k_pool, v_pool))
+        return logits, dataclasses.replace(cache, pools=(k_pool, v_pool))

@@ -58,7 +58,7 @@ def fetch_blocks(source: str, hashes: Sequence[str],
                  request_id: Optional[str] = None):
     """Pull ``hashes``' packed payloads from ``source``'s
     ``POST /v1/kv/fetch``; returns :func:`~.wire.unpack_blocks`'s
-    ``(served_hashes, k_np, v_np, wire_bytes)``. The prefill side may
+    ``(served_hashes, rows, wire_bytes)``. The prefill side may
     serve a shorter prefix than asked (blocks evicted since the offer
     was computed) — the importer tolerates that."""
     headers = {"Content-Type": "application/json"}
@@ -103,7 +103,7 @@ def pull_and_import(engine, hashes: Sequence[str],
                 "error": None if hashes else "empty offer"}
     held = engine.kv_probe(hashes)
     missing = hashes[held:]
-    payload_hashes, k_np, v_np, nbytes = [], None, None, 0
+    payload_hashes, rows, nbytes = [], None, 0
     error = None
     if missing and source:
         t0 = time.perf_counter()
@@ -112,7 +112,7 @@ def pull_and_import(engine, hashes: Sequence[str],
                                args={"blocks": len(missing),
                                      "source": source}):
                 _FP_TRANSFER.fire()
-                payload_hashes, k_np, v_np, nbytes = fetch_blocks(
+                payload_hashes, rows, nbytes = fetch_blocks(
                     source, missing, wire_dtype=wire_dtype,
                     timeout=timeout, request_id=request_id)
         except Exception as e:  # noqa: BLE001 — degrade, never error
@@ -120,7 +120,7 @@ def pull_and_import(engine, hashes: Sequence[str],
             log.warning("disagg: KV pull from %s failed, degrading to "
                         "local re-prefill (request %s): %s",
                         source, request_id, e)
-            payload_hashes, k_np, v_np, nbytes = [], None, None, 0
+            payload_hashes, rows, nbytes = [], None, 0
         _M_TRANSFER_SECONDS.inc(time.perf_counter() - t0)
         if nbytes:
             _M_TRANSFER_BYTES.inc(nbytes)
@@ -130,7 +130,7 @@ def pull_and_import(engine, hashes: Sequence[str],
             with _tracing.span("disagg.admit",
                                args={"payload_blocks": len(payload_hashes)}):
                 held, imported = engine.kv_import(
-                    hashes, payload_hashes, k_np, v_np)
+                    hashes, payload_hashes, rows)
         except Exception as e:  # noqa: BLE001 — degrade, never error
             error = str(e)
             log.warning("disagg: KV admit failed, degrading to local "

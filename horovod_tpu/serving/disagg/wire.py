@@ -6,7 +6,8 @@ A manifest is the chain-hash list of a prompt's matchable full blocks
 replica's scheduler computes, so the prefill pool, the decode pool,
 and the router all name blocks identically without exchanging tokens).
 A payload (:func:`pack_blocks` / :func:`unpack_blocks`) carries the
-actual K/V contents of a hash subset as base64 inside the JSON body of
+actual cache contents of a hash subset (every pool the model's cache
+declaration names) as base64 inside the JSON body of
 ``POST /v1/kv/fetch`` — self-describing (shape + dtypes ride along),
 so a fetch can be answered and verified without out-of-band context.
 
@@ -64,13 +65,18 @@ def _decode(b64: str, dtype_name: str, shape: Sequence[int]) -> np.ndarray:
     return np.frombuffer(raw, dtype=dt).reshape(tuple(shape))
 
 
-def pack_blocks(hashes: Sequence[str], k_np: np.ndarray, v_np: np.ndarray,
+def pack_blocks(hashes: Sequence[str], rows,
                 wire_dtype: str = "native") -> Dict:
     """The ``/v1/kv/fetch`` response document for ``hashes``' block
-    contents (``k_np``/``v_np`` shaped ``(layers, n, bs, row)``, the
-    pools' own layout; the shape travels in the document).
-    Returns ``{"hashes", "shape", "dtype", "wire_dtype", "k", "v"}``;
-    an empty ``hashes`` packs to ``{"hashes": []}``."""
+    contents. ``rows`` is what
+    :func:`~horovod_tpu.serving.generation.kv_cache.gather_blocks`
+    returns: one ``(planes, n, bs, row)`` array for each pool of the
+    model's cache declaration, in its order (K and V for the GPT-2
+    block, one latent row for latent attention); every array's shape
+    and dtypes travel in the document, so the codec knows nothing of a
+    model. Returns ``{"hashes", "rows": [{"shape", "dtype",
+    "wire_dtype", "data"}, ...]}``; an empty ``hashes`` packs to
+    ``{"hashes": []}``."""
     if wire_dtype not in WIRE_DTYPES:
         raise ValueError(
             f"HVD_TPU_DISAGG_WIRE_DTYPE={wire_dtype!r}: must be one of "
@@ -78,27 +84,25 @@ def pack_blocks(hashes: Sequence[str], k_np: np.ndarray, v_np: np.ndarray,
     hashes = [str(h) for h in hashes]
     if not hashes:
         return {"hashes": []}
-    k_b64, wire_name = _encode(np.asarray(k_np), wire_dtype)
-    v_b64, _ = _encode(np.asarray(v_np), wire_dtype)
-    return {"hashes": hashes,
-            "shape": list(np.asarray(k_np).shape),
-            "dtype": str(np.asarray(k_np).dtype),
-            "wire_dtype": wire_name,
-            "k": k_b64, "v": v_b64}
+    packed = []
+    for arr in rows:
+        arr = np.asarray(arr)
+        data, wire_name = _encode(arr, wire_dtype)
+        packed.append({"shape": list(arr.shape), "dtype": str(arr.dtype),
+                       "wire_dtype": wire_name, "data": data})
+    return {"hashes": hashes, "rows": packed}
 
 
-def unpack_blocks(doc: Dict) -> Tuple[List[str], Optional[np.ndarray],
-                                      Optional[np.ndarray], int]:
-    """Invert :func:`pack_blocks`:
-    ``(hashes, k_np, v_np, wire_bytes)``. Arrays come back in the wire
-    dtype (the importer's ``scatter_blocks`` casts to the pool dtype);
-    ``wire_bytes`` is the payload size actually moved, the
+def unpack_blocks(doc: Dict) -> Tuple[List[str], Optional[Tuple], int]:
+    """Invert :func:`pack_blocks`: ``(hashes, rows, wire_bytes)``.
+    Arrays come back in the wire dtype (the importer's
+    ``scatter_blocks`` casts to the pool dtype); ``wire_bytes`` is the
+    payload size actually moved, the
     ``hvd_tpu_disagg_transfer_bytes_total`` increment."""
     hashes = [str(h) for h in doc.get("hashes", [])]
     if not hashes:
-        return [], None, None, 0
-    shape = doc["shape"]
-    wire_name = doc.get("wire_dtype") or doc["dtype"]
-    k_np = _decode(doc["k"], wire_name, shape)
-    v_np = _decode(doc["v"], wire_name, shape)
-    return hashes, k_np, v_np, k_np.nbytes + v_np.nbytes
+        return [], None, 0
+    rows = tuple(
+        _decode(r["data"], r.get("wire_dtype") or r["dtype"], r["shape"])
+        for r in doc["rows"])
+    return hashes, rows, sum(r.nbytes for r in rows)

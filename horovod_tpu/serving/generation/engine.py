@@ -17,10 +17,17 @@ same parts:
   (:mod:`~horovod_tpu.serving.generation.kv_cache`), sized by
   ``HVD_TPU_GEN_NUM_BLOCKS`` x ``HVD_TPU_GEN_BLOCK_SIZE``.
 
-The model must be a
-:class:`~horovod_tpu.models.transformer.Transformer` (or expose the
-same ``apply(params, tokens, cache=PagedCache)`` contract and a ``cfg``
-with ``num_layers/num_heads/head_dim/max_seq_len/dtype``).
+The model is any flax module with the paged ``apply`` contract
+(``docs/serving_models.md``): ``apply(params, tokens,
+cache=PagedCache, logits_at=None) -> (logits, PagedCache)``, and a
+``cfg`` that **declares its cache** (``cfg.cache_spec()``, a
+:class:`~horovod_tpu.models.transformer.CacheSpec`: planes, row names
+and widths, dtype; pools, block bytes, the disagg wire and the programs
+are driven by it) and gives ``max_seq_len`` and ``vocab_size``. A model
+with routed experts also gives ``cfg.held_experts``; its programs then
+return routing counts.
+:class:`~horovod_tpu.models.transformer.Transformer` and
+:class:`~horovod_tpu.models.longcat_flash.LongcatFlash` are the two.
 """
 
 from typing import Any, List, Optional, Sequence
@@ -274,12 +281,12 @@ class GenerationEngine:
     def kv_export(self, hashes: Sequence[str], timeout: float = 30.0):
         """Serve ``POST /v1/kv/fetch``: read the requested blocks'
         contents off the pools (scheduler-thread control op). Returns
-        ``(served_hashes, k_np, v_np)``."""
+        ``(served_hashes, rows)``, one array for each cache pool."""
         return self.batcher.execute(
             lambda: self.batcher.export_kv_blocks(hashes), timeout=timeout)
 
     def kv_import(self, hashes: Sequence[str],
-                  payload_hashes: Sequence[str], k_data, v_data,
+                  payload_hashes: Sequence[str], rows,
                   timeout: float = 30.0):
         """Serve ``POST /v1/kv/offer``'s admit step: write transferred
         payloads into pool blocks and register them (remote) in the
@@ -287,7 +294,7 @@ class GenerationEngine:
         ``(already_held, imported)``."""
         return self.batcher.execute(
             lambda: self.batcher.import_kv_blocks(
-                hashes, payload_hashes, k_data, v_data), timeout=timeout)
+                hashes, payload_hashes, rows), timeout=timeout)
 
     def reload(self, step: Optional[int] = None) -> bool:
         """Force a checkpoint hot-reload now (see

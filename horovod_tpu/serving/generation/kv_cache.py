@@ -436,12 +436,21 @@ class BlockAllocator:
 _LANES = 128
 
 
+def _row(width: int) -> int:
+    """A pool row for ``width`` values: the next multiple of 128."""
+    return -(-int(width) // _LANES) * _LANES
+
+
 def make_pools(model_cfg, num_blocks: int, block_size: int):
-    """Zeroed K/V pools for ``model_cfg`` (a
-    :class:`~horovod_tpu.models.transformer.TransformerConfig`):
-    ``(num_layers, num_blocks, block_size, row)`` each, in the model's
-    activation dtype. A row is one token's K (or V): its
-    ``heads * head_dim`` values, then zeros up to a multiple of 128.
+    """Zeroed cache pools for ``model_cfg``, read off its declaration
+    (``model_cfg.cache_spec()``, a
+    :class:`~horovod_tpu.models.transformer.CacheSpec`): a tuple with
+    one ``(planes, num_blocks, block_size, row)`` array for each
+    declared row, in the declared dtype. A pool row holds what one
+    token leaves in one plane (for
+    :class:`~horovod_tpu.models.transformer.TransformerConfig`: its K,
+    or its V, ``heads * head_dim`` values), then zeros up to a multiple
+    of 128.
 
     The shape is chosen for the layout a TPU gives it. The device tiles
     an array's two minor axes ``(8, 128)`` and stores it in whichever
@@ -453,39 +462,65 @@ def make_pools(model_cfg, num_blocks: int, block_size: int):
     128 pads nothing, row-major wins for any ``N``, and a token's row is
     contiguous; the device would have padded 1600 to 1664 itself."""
     import jax.numpy as jnp
-    row = -(-model_cfg.num_heads * model_cfg.head_dim // _LANES) * _LANES
-    shape = (model_cfg.num_layers, num_blocks, block_size, row)
-    return jnp.zeros(shape, model_cfg.dtype), jnp.zeros(shape,
-                                                        model_cfg.dtype)
+    spec = model_cfg.cache_spec()
+    return tuple(
+        jnp.zeros((spec.planes, num_blocks, block_size, _row(width)),
+                  spec.dtype) for _, width in spec.rows)
 
 
 def block_bytes(model_cfg, block_size: int) -> int:
-    """Bytes of KV cache one block holds (K and V, all layers)."""
+    """Bytes of cache one block holds: every declared row, padded as
+    :func:`make_pools` allocates it, in every plane."""
     import jax.numpy as jnp
-    itemsize = jnp.dtype(model_cfg.dtype).itemsize
-    return (2 * model_cfg.num_layers * block_size * model_cfg.num_heads
-            * model_cfg.head_dim * itemsize)
+    spec = model_cfg.cache_spec()
+    return (spec.planes * block_size * jnp.dtype(spec.dtype).itemsize
+            * sum(_row(width) for _, width in spec.rows))
 
 
-def gather_blocks(k, v, blocks: Sequence[int]):
+def gather_blocks(pools, blocks: Sequence[int]):
     """Materialize the contents of pool ``blocks`` on the host for the
-    disagg KV wire: ``(k_np, v_np)``, each
-    ``(num_layers, len(blocks), block_size, row)`` in the
-    pool dtype. Must run on the scheduler thread (the pools are donated
-    device buffers the scheduler owns)."""
+    disagg KV wire: one ``(planes, len(blocks), block_size, row)``
+    array for each pool, in the pools' order and dtype. Must run on the
+    scheduler thread (the pools are donated device buffers the
+    scheduler owns)."""
     idx = list(blocks)
-    return np.asarray(k[:, idx]), np.asarray(v[:, idx])
+    return tuple(np.asarray(p[:, idx]) for p in pools)
 
 
-def scatter_blocks(k, v, blocks: Sequence[int], k_data, v_data):
-    """Write transferred block contents into pool slots ``blocks``;
-    returns the new ``(k, v)`` pool arrays (functional ``.at[].set``, so
-    an in-flight decode step's buffers are untouched). Scheduler-thread
-    only, like :func:`gather_blocks`."""
+def scatter_blocks(pools, blocks: Sequence[int], data):
+    """Write transferred block contents (``data``: one array a pool, as
+    :func:`gather_blocks` gives them) into pool slots ``blocks``;
+    returns the new pools (functional ``.at[].set``, so an in-flight
+    decode step's buffers are untouched). Scheduler-thread only, like
+    :func:`gather_blocks`."""
     idx = list(blocks)
-    dt = k.dtype
-    return (k.at[:, idx].set(np.asarray(k_data, dtype=dt)),
-            v.at[:, idx].set(np.asarray(v_data, dtype=dt)))
+    if len(data) != len(pools):
+        raise ValueError(
+            f"{len(data)} transferred rows for a cache of {len(pools)}")
+    return tuple(p.at[:, idx].set(np.asarray(d, dtype=p.dtype))
+                 for p, d in zip(pools, data))
+
+
+def _paged_apply(model, params, tokens, cache, logits_at=None):
+    """``model.apply`` on the paged path: ``(logits, cache, stats)``.
+    ``stats`` is ``()`` for a model that declares no experts, so its
+    programs return what they always did; for one that does
+    (``model.cfg.held_experts``) it is ``(counts,)``: the id of the
+    first expert the model holds, then the layers' int32 routing counts
+    summed. The sampling programs return it beside their tokens, and the
+    vector says whose counts they are."""
+    kwargs = {} if logits_at is None else {"logits_at": logits_at}
+    if not getattr(model.cfg, "held_experts", None):
+        logits, cache = model.apply(params, tokens, cache=cache, **kwargs)
+        return logits, cache, ()
+    import jax
+
+    from ...parallel.moe import STATS_COLLECTION
+    (logits, cache), sown = model.apply(
+        params, tokens, cache=cache, mutable=[STATS_COLLECTION], **kwargs)
+    counts = sum(jax.tree_util.tree_leaves(sown[STATS_COLLECTION]))
+    first = jax.numpy.asarray(model.cfg.held_experts[:1], counts.dtype)
+    return logits, cache, (jax.numpy.concatenate([first, counts]),)
 
 
 @functools.lru_cache(maxsize=8)
@@ -649,7 +684,8 @@ def _sample_tokens(logits, sample: SampleParams):
 def build_prefill_program(model):
     """The sampling prefill program:
     ``(params, PagedCache, tokens, SampleParams) ->
-    (token (B,), logprob (B,), PagedCache)``.
+    (token (B,), logprob (B,), PagedCache)`` (and, last, the routing
+    counts of a model with experts: :func:`_paged_apply`).
 
     One chunk of prompt K/V lands in the cache and the *final* live
     position's next token is sampled on device — the host never sees
@@ -661,10 +697,10 @@ def build_prefill_program(model):
 
     def _prefill(params, cache, tokens, sample):
         at = jnp.maximum(cache.live - 1, 0).astype(jnp.int32)
-        logits, cache = model.apply(params, tokens, cache=cache,
-                                    logits_at=at)
+        logits, cache, stats = _paged_apply(model, params, tokens, cache,
+                                            logits_at=at)
         token, logprob = sample_tokens(logits, sample)
-        return token, logprob, cache
+        return (token, logprob, cache, *stats)
 
     return jax.jit(_prefill, donate_argnums=(1,))
 
@@ -672,8 +708,10 @@ def build_prefill_program(model):
 @functools.lru_cache(maxsize=8)
 def build_decode_program(model, decode_width: int = 2):
     """The device-resident decode step:
-    ``(params, k, v, tables, DecodeState) ->
-    (k, v, DecodeState, token (B,), logprob (B,))``.
+    ``(params, pools, tables, DecodeState) ->
+    (pools, DecodeState, token (B,), logprob (B,))`` (and, last, the
+    routing counts of a model with experts). ``pools`` is the tuple
+    :func:`make_pools` returned, whatever the model declared.
 
     One fixed-shape step over every lane: write K/V at each live lane's
     cache position (dead lanes route to the null block), sample the
@@ -681,7 +719,7 @@ def build_decode_program(model, decode_width: int = 2):
     back as the next inputs, lengths/remaining/emitted tick forward,
     and lanes hitting EOS or ``max_tokens`` drop their own ``live``
     flag so a speculatively enqueued next step is already harmless.
-    ``k``/``v`` and the state are donated (the persistent device
+    The pools and the state are donated (the persistent device
     buffers); ``tables`` is NOT — the host re-uploads it only when a
     block table actually changed, and block growth alone never forces
     a pipeline flush. The per-step device->host transfer is the
@@ -690,14 +728,15 @@ def build_decode_program(model, decode_width: int = 2):
     import jax
     import jax.numpy as jnp
 
-    def _decode(params, k, v, tables, state):
+    def _decode(params, pools, tables, state):
         B = state.tokens.shape[0]
         tokens = jnp.zeros((B, decode_width), jnp.int32)
         tokens = tokens.at[:, 0].set(state.tokens)
         live = jnp.minimum(state.live, 1).astype(jnp.int32)
-        cache = PagedCache(k, v, tables, state.lengths, live)
-        logits, cache = model.apply(params, tokens, cache=cache,
-                                    logits_at=jnp.zeros((B,), jnp.int32))
+        cache = PagedCache(pools, tables, state.lengths, live)
+        logits, cache, stats = _paged_apply(
+            model, params, tokens, cache,
+            logits_at=jnp.zeros((B,), jnp.int32))
         sampled, logprob = sample_tokens(logits, state.sample)
         alive = live > 0
         token = jnp.where(alive, sampled, state.tokens)
@@ -711,17 +750,17 @@ def build_decode_program(model, decode_width: int = 2):
             eos=state.eos,
             sample=dataclasses.replace(
                 state.sample, emitted=state.sample.emitted + live))
-        return cache.k, cache.v, new_state, token, logprob
+        return (cache.pools, new_state, token, logprob, *stats)
 
-    return jax.jit(_decode, donate_argnums=(1, 2, 4))
+    return jax.jit(_decode, donate_argnums=(1, 3))
 
 
 @functools.lru_cache(maxsize=8)
 def build_verify_program(model, spec_tokens: int):
     """The speculative-decoding verify step:
-    ``(params, k, v, tables, DecodeState, draft (B, S), draft_len (B,))
-    -> (k, v, DecodeState, pred (B, S+1), logprob (B, S+1),
-    n_emit (B,))``.
+    ``(params, pools, tables, DecodeState, draft (B, S),
+    draft_len (B,)) -> (pools, DecodeState, pred (B, S+1),
+    logprob (B, S+1), n_emit (B,))``.
 
     One paged forward scores a lane's current input token plus up to
     ``S = spec_tokens`` drafted continuations in a single chunk of
@@ -747,7 +786,7 @@ def build_verify_program(model, spec_tokens: int):
     ``draft_len == 0`` degrades to precisely the plain decode step
     (accept 0 drafts, emit 1 token).
 
-    ``k``/``v`` and the state are donated; ``tables`` is not. The
+    The pools and the state are donated; ``tables`` is not. The
     per-step transfer is ``(B, S+1)`` tokens + logprobs plus the
     ``(B,)`` accept count — still never logits.
     """
@@ -759,9 +798,8 @@ def build_verify_program(model, spec_tokens: int):
         raise ValueError(f"spec_tokens={spec_tokens}: must be >= 1")
     C = S + 1
 
-    def _verify(params, k, v, tables, state, draft, draft_len):
-        B = state.tokens.shape[0]
-        block_size = k.shape[2]
+    def _verify(params, pools, tables, state, draft, draft_len):
+        block_size = pools[0].shape[2]
         live = jnp.minimum(state.live, 1).astype(jnp.int32)
         alive = live > 0
         # a draft may never reach past the lane's budget: emitting n
@@ -781,19 +819,20 @@ def build_verify_program(model, spec_tokens: int):
             tables, jnp.minimum(positions // block_size,
                                 tables.shape[1] - 1), axis=1)
         offsets = positions % block_size
-        layers = jnp.arange(k.shape[0])[:, None, None]
-        orig_k = k[layers, blocks, offsets]
-        orig_v = v[layers, blocks, offsets]
+        layers = jnp.arange(pools[0].shape[0])[:, None, None]
+        orig = tuple(p[layers, blocks, offsets] for p in pools)
         # the forward updates the pools in place. Writing the snapshot
         # straight back changes no value and makes the pool the forward
         # sees the successor of the one just read: XLA then orders the
         # read before the in-place writes; left unordered, it copies
         # each whole pool to keep the old version readable
-        k = k.at[layers, blocks, offsets].set(orig_k)
-        v = v.at[layers, blocks, offsets].set(orig_v)
+        pools = tuple(p.at[layers, blocks, offsets].set(o)
+                      for p, o in zip(pools, orig))
 
-        cache = PagedCache(k, v, tables, state.lengths, width)
-        logits, cache = model.apply(params, chunk, cache=cache)
+        cache = PagedCache(pools, tables, state.lengths, width)
+        # a verify step's routing is not counted: the counters would
+        # hold the rejected drafts' tokens too
+        logits, cache, _ = _paged_apply(model, params, chunk, cache)
 
         # per-position resample: position i's draw is the plain
         # decoder's emission `emitted + i` — same ops, same fold_in,
@@ -829,8 +868,8 @@ def build_verify_program(model, spec_tokens: int):
         # the committed prefix, whose restore writes go to block 0
         committed = jnp.arange(C)[None, :] < n_emit[:, None]
         rb = jnp.where(committed, 0, blocks)
-        new_k = cache.k.at[layers, rb, offsets].set(orig_k)
-        new_v = cache.v.at[layers, rb, offsets].set(orig_v)
+        new_pools = tuple(p.at[layers, rb, offsets].set(o)
+                          for p, o in zip(cache.pools, orig))
 
         retired = alive & ((lead < n_emit) | (state.remaining <= n_emit))
         last = jnp.take_along_axis(
@@ -844,16 +883,16 @@ def build_verify_program(model, spec_tokens: int):
             eos=state.eos,
             sample=dataclasses.replace(
                 state.sample, emitted=state.sample.emitted + n_emit))
-        return new_k, new_v, new_state, pred, logp, n_emit
+        return new_pools, new_state, pred, logp, n_emit
 
-    return jax.jit(_verify, donate_argnums=(1, 2, 4))
+    return jax.jit(_verify, donate_argnums=(1, 3))
 
 
 @functools.lru_cache(maxsize=8)
 def build_beam_program(model, beam_k: int, decode_width: int = 2):
     """The beam-search step:
-    ``(params, k, v, tables, tokens (B,), lengths (B,), live (B,)) ->
-    (k, v, top_tok (B, beam_k), top_lp (B, beam_k))``.
+    ``(params, pools, tables, tokens (B,), lengths (B,), live (B,)) ->
+    (pools, top_tok (B, beam_k), top_lp (B, beam_k))``.
 
     The decode program's forward — identical chunk shape, identical
     K/V write path — returning the ``beam_k`` highest-logprob
@@ -865,7 +904,7 @@ def build_beam_program(model, beam_k: int, decode_width: int = 2):
     bit-identical to plain greedy decode, logprobs included. Beam
     state (tokens/lengths/live/tables) is host-managed: the beam loop
     is synchronous and re-forms the batch every step as beams fork and
-    finish. ``k``/``v`` are donated."""
+    finish. The pools are donated."""
     import jax
     import jax.numpy as jnp
 
@@ -873,16 +912,17 @@ def build_beam_program(model, beam_k: int, decode_width: int = 2):
     if K < 1:
         raise ValueError(f"beam_k={beam_k}: must be >= 1")
 
-    def _beam_step(params, k, v, tables, tokens, lengths, live):
+    def _beam_step(params, pools, tables, tokens, lengths, live):
         B = tokens.shape[0]
         chunk = jnp.zeros((B, decode_width), jnp.int32)
         chunk = chunk.at[:, 0].set(tokens)
         live = jnp.minimum(live, 1).astype(jnp.int32)
-        cache = PagedCache(k, v, tables, lengths, live)
-        logits, cache = model.apply(params, chunk, cache=cache,
-                                    logits_at=jnp.zeros((B,), jnp.int32))
+        cache = PagedCache(pools, tables, lengths, live)
+        logits, cache, _ = _paged_apply(
+            model, params, chunk, cache,
+            logits_at=jnp.zeros((B,), jnp.int32))
         top_lp, top_tok = jax.lax.top_k(
             jax.nn.log_softmax(logits, axis=-1), K)
-        return cache.k, cache.v, top_tok.astype(jnp.int32), top_lp
+        return cache.pools, top_tok.astype(jnp.int32), top_lp
 
-    return jax.jit(_beam_step, donate_argnums=(1, 2))
+    return jax.jit(_beam_step, donate_argnums=(1,))

@@ -129,6 +129,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ... import _locks
@@ -137,6 +138,7 @@ from ... import faults as _faults
 from ... import metrics as _metrics
 from ... import tracing as _tracing
 from ...models.transformer import PagedCache
+from ...parallel.moe import STATS_FIELDS
 from ..batcher import DeadlineExceededError, QueueFullError
 from .kv_cache import (BlockAllocator, BlocksExhaustedError, DecodeState,
                        SampleParams, chain_hash, gather_blocks,
@@ -148,6 +150,42 @@ _M_TOKENS = _metrics.counter(
     "tokens written into the paged KV cache (recomputed tokens after a "
     "preemption count again — they are real work), 'decode' counts "
     "generated tokens emitted to callers.",
+    labels=("phase",))
+_M_MOE_TOKENS = _metrics.counter(
+    "hvd_tpu_gen_moe_tokens_total",
+    "Live tokens routed by a served model's expert layers, one count a "
+    "token a MoE layer (a chunk's pad tokens and a decode step's dead "
+    "lanes are not routed and count nowhere). Only a model that "
+    "declares experts feeds the hvd_tpu_gen_moe_* counters; the counts "
+    "leave the prefill and decode programs as one int32 vector beside "
+    "the tokens and are read in the same transfer.")
+_M_MOE_PICKS = _metrics.counter(
+    "hvd_tpu_gen_moe_picks_total",
+    "Router picks (top-k a token a MoE layer) by what they fell on: "
+    "kind='held' an FFN expert this chip holds (a row of the grouped "
+    "matmul), 'zero' a zero-compute identity expert (no matmul), "
+    "'absent' an FFN expert another chip of the deployment holds "
+    "(nothing is added here). held + zero + absent = top_k x "
+    "hvd_tpu_gen_moe_tokens_total.",
+    labels=("kind",))
+_M_MOE_EXPERT_PICKS = _metrics.counter(
+    "hvd_tpu_gen_moe_held_expert_picks_total",
+    "Picks of each FFN expert this chip holds, by the expert's id in "
+    "the whole model, summed over MoE layers: the load the grouped "
+    "matmul sees, and its busiest expert is its straggler.",
+    labels=("expert",))
+_M_MOE_TOUCHED = _metrics.counter(
+    "hvd_tpu_gen_moe_experts_touched_total",
+    "Held experts with at least one live token, summed over MoE "
+    "layers and program calls, by the phase of the call: the expert "
+    "weights a call had to read. Divide by hvd_tpu_gen_moe_calls_total "
+    "of the phase x MoE layers for the mean a layer a call (a prefill "
+    "chunk touches nearly all, a decode step the few its lanes pick).",
+    labels=("phase",))
+_M_MOE_CALLS = _metrics.counter(
+    "hvd_tpu_gen_moe_calls_total",
+    "Prefill chunks and decode steps whose routing counts were read "
+    "(verify and beam steps are not counted).",
     labels=("phase",))
 _M_RUNNING = _metrics.gauge(
     "hvd_tpu_gen_running_seqs",
@@ -464,7 +502,8 @@ class ContinuousBatcher:
       params_fn: zero-arg callable returning the params to use for the
         next device call — the engine passes its hot-reload snapshot, so
         a checkpoint swap lands between steps, never inside one.
-      pools: the ``(k, v)`` pools from :func:`~.kv_cache.make_pools`.
+      pools: the tuple of pools from :func:`~.kv_cache.make_pools`,
+        one for each row the model's cache declaration names.
       allocator: the :class:`~.kv_cache.BlockAllocator` over the same
         pool.
       max_seq_len: hard cap on ``len(prompt) + max_tokens`` (the model's
@@ -516,12 +555,11 @@ class ContinuousBatcher:
                 f"prefill|decode|colocated")
         self._prefill_prog, self._decode_prog = programs
         self._params_fn = params_fn
-        self._k, self._v = pools
-        #: shape/dtype for rebuilding the pools after a genuine device
+        self._pools = tuple(pools)
+        #: shapes/dtypes for rebuilding the pools after a genuine device
         #: failure: the programs donate them, so a call that dies mid-
-        #: execution leaves self._k/_v pointing at deleted buffers
-        self._pool_shape = tuple(self._k.shape)
-        self._pool_dtype = self._k.dtype
+        #: execution leaves self._pools pointing at deleted buffers
+        self._pool_shapes = [(tuple(p.shape), p.dtype) for p in pools]
         self._alloc = allocator
         self._prefix_cache = bool(getattr(allocator, "prefix_cache", False))
         #: identity of the params object the last device call used —
@@ -581,8 +619,12 @@ class ContinuousBatcher:
         self._epoch = 0
         self._state_epoch = -1
         #: decode steps enqueued but not yet consumed:
-        #: (token_dev, logprob_dev, lane snapshot)
+        #: (token_dev, logprob_dev, lane snapshot, routing counts)
         self._inflight: "collections.deque" = collections.deque()
+        #: routing counts of prefill chunks not read back yet, as
+        #: (phase, device vector); a decode step's travel with its
+        #: flight. Both stay empty for a model without experts.
+        self._moe_pending: list = []
         #: the loop's spans (tracing.py): ring, profiler annotation and
         #: the self times that hvd_tpu_gen_phase_seconds and
         #: hvd_tpu_gen_step_seconds are derived from
@@ -840,30 +882,31 @@ class ContinuousBatcher:
         """Scheduler-thread body of ``POST /v1/kv/fetch`` (call via
         :meth:`execute`): pin the longest indexed prefix of ``hashes``,
         read those blocks' contents off the pools, release. Returns
-        ``(served_hashes, k_np, v_np)`` — a prefix of the request (the
+        ``(served_hashes, rows)``, ``rows`` one array a pool — a
+        prefix of the request (the
         tail may have evicted since the manifest was minted; the decode
         side re-prefills whatever is missing)."""
         hashes = [str(h) for h in hashes]
         if not self._prefix_cache:
-            return [], None, None
+            return [], None
         held = self._alloc.match(hashes)
         if not held:
-            return [], None, None
+            return [], None
         try:
-            k_np, v_np = gather_blocks(self._k, self._v, held)
+            rows = gather_blocks(self._pools, held)
         finally:
             self._alloc.free(held)
-        return hashes[:len(held)], k_np, v_np
+        return hashes[:len(held)], rows
 
     def import_kv_blocks(self, hashes: Sequence[str],
                          payload_hashes: Sequence[str],
-                         k_data, v_data) -> Tuple[int, int]:
+                         rows) -> Tuple[int, int]:
         """Scheduler-thread body of ``POST /v1/kv/offer`` (call via
         :meth:`execute`): register transferred block payloads into the
         local prefix cache so the next admission of the matching prompt
         attaches them with zero full-block prefill debt. ``hashes`` is
-        the full chain manifest; ``payload_hashes``/``k_data``/
-        ``v_data`` cover the blocks the source shipped (any order,
+        the full chain manifest; ``payload_hashes``/``rows`` (one
+        array a pool) cover the blocks the source shipped (any order,
         matched by hash). Returns ``(already_held, imported)`` block
         counts. The already-held chain prefix is pinned across the
         allocation so eviction can never tear a hole in it; imported
@@ -893,9 +936,8 @@ class ContinuousBatcher:
                 self._alloc.free(held)
                 return m, 0
             idx = [i for _, i in want]
-            self._k, self._v = scatter_blocks(
-                self._k, self._v, fresh,
-                np.asarray(k_data)[:, idx], np.asarray(v_data)[:, idx])
+            self._pools = scatter_blocks(
+                self._pools, fresh, [np.asarray(r)[:, idx] for r in rows])
             for b, (h, _) in zip(fresh, want):
                 self._alloc.register(b, h, remote=True)
         self._alloc.free(held + fresh)
@@ -1196,7 +1238,7 @@ class ContinuousBatcher:
             row = np.zeros((1, self.max_blocks), np.int32)
             row[0, :len(s.blocks)] = s.blocks
             args = (
-                PagedCache(self._k, self._v, jnp.asarray(row),
+                PagedCache(self._pools, jnp.asarray(row),
                            jnp.asarray(np.asarray([s.prefilled], np.int32)),
                            jnp.asarray(np.asarray([live], np.int32))),
                 jnp.asarray(tokens),
@@ -1286,7 +1328,7 @@ class ContinuousBatcher:
                 # simply not consumed.)
                 _M_TOKENS.labels(phase="decode").inc()
                 with self._spans.span("gen.wait", program="prefill"):
-                    tok_v, logp_v = np.asarray(tok), np.asarray(logp)
+                    tok_v, logp_v = self._readback((tok, logp))
                 logp_v = _corrupt_logprobs(logp_v, [s])
                 if not np.isfinite(logp_v[0]):
                     self._deliver_error(s, RuntimeError(
@@ -1297,9 +1339,41 @@ class ContinuousBatcher:
         if self.on_step is not None:
             self.on_step("prefill", [s.id])
 
+    def _readback(self, arrays, stats=()):
+        """A program's results on the host, as ``np.asarray`` gives
+        them. For a model with experts the same transfer brings the
+        routing counts: ``stats`` (that program's own) and those of
+        every prefill chunk since the last readback, each dispatched
+        before the program being read, so none is waited for."""
+        if not (stats or self._moe_pending):
+            return [np.asarray(a) for a in arrays]
+        stats, self._moe_pending = self._moe_pending + list(stats), []
+        out = jax.device_get(list(arrays) + [c for _, c in stats])
+        for (phase, _), counts in zip(stats, out[len(arrays):]):
+            self._count_moe(phase, counts)
+        return out[:len(arrays)]
+
+    def _count_moe(self, phase: str, counts) -> None:
+        """One program call's routing counts into the counters; the
+        vector's layout is the first held expert's id
+        (``kv_cache._paged_apply``), ``parallel.moe.STATS_FIELDS``,
+        then one entry a held expert."""
+        head = 1 + len(STATS_FIELDS)
+        first, tokens, held, zero, absent, touched = (
+            int(c) for c in counts[:head])
+        _M_MOE_CALLS.labels(phase=phase).inc()
+        _M_MOE_TOKENS.inc(tokens)
+        _M_MOE_PICKS.labels(kind="held").inc(held)
+        _M_MOE_PICKS.labels(kind="zero").inc(zero)
+        _M_MOE_PICKS.labels(kind="absent").inc(absent)
+        _M_MOE_TOUCHED.labels(phase=phase).inc(touched)
+        for e, n in enumerate(counts[head:], first):
+            if n:
+                _M_MOE_EXPERT_PICKS.labels(expert=str(e)).inc(int(n))
+
     def _run_prefill(self, cache: PagedCache, tokens, sample):
         try:
-            tok, logp, cache = self._prefill_prog(
+            tok, logp, cache, *stats = self._prefill_prog(
                 self._params(), cache, tokens, sample)
         except Exception:
             # the pools were donated into the failed call and may be
@@ -1309,7 +1383,10 @@ class ContinuousBatcher:
             # rebuild: waiting sequences still serve next iteration.
             self._reset_device()
             raise
-        self._k, self._v = cache.k, cache.v
+        self._pools = cache.pools
+        self._moe_pending.extend(("prefill", c) for c in stats)
+        if len(self._moe_pending) >= 64:
+            self._readback(())      # no token is read in this mode
         return tok, logp
 
     # -- decode --------------------------------------------------------------
@@ -1349,14 +1426,14 @@ class ContinuousBatcher:
             try:
                 with self._spans.span("gen.decode.dispatch",
                                       program="decode", lanes=len(batch)):
-                    out = self._decode_prog(self._params(), self._k,
-                                            self._v, self._dtables,
-                                            self._dstate)
+                    out = self._decode_prog(self._params(), self._pools,
+                                            self._dtables, self._dstate)
             except Exception:  # noqa: BLE001
                 self._reset_device()
                 return
-            self._k, self._v, self._dstate, tok, logp = out
-            self._inflight.append((tok, logp, list(self._lanes)))
+            self._pools, self._dstate, tok, logp, *stats = out
+            self._inflight.append((tok, logp, list(self._lanes),
+                                   [("decode", c) for c in stats]))
         # consume down to the configured pipeline depth — everything,
         # when nothing was enqueued this iteration
         limit = self.async_depth if batch else 0
@@ -1474,11 +1551,10 @@ class ContinuousBatcher:
             self._process_flight(now)
 
     def _process_flight(self, now: float) -> None:
-        tok_d, logp_d, lanes = self._inflight.popleft()
+        tok_d, logp_d, lanes, stats = self._inflight.popleft()
         try:
             with self._spans.span("gen.wait", program="decode"):
-                tok = np.asarray(tok_d)
-                logp = np.asarray(logp_d)
+                tok, logp = self._readback((tok_d, logp_d), stats)
         except Exception:  # noqa: BLE001 — the device step itself died
             self._reset_device()
             return
@@ -1564,13 +1640,13 @@ class ContinuousBatcher:
         try:
             with spans.span("gen.decode.dispatch", program="verify",
                             lanes=len(batch)):
-                out = self._verify_prog(self._params(), self._k, self._v,
+                out = self._verify_prog(self._params(), self._pools,
                                         self._dtables, self._dstate,
                                         draft_d, dlen_d)
         except Exception:  # noqa: BLE001
             self._reset_device()
             return
-        self._k, self._v, self._dstate, pred_d, logp_d, n_emit_d = out
+        self._pools, self._dstate, pred_d, logp_d, n_emit_d = out
         try:
             with spans.span("gen.wait", program="verify") as wait:
                 pred = np.asarray(pred_d)
@@ -1709,15 +1785,15 @@ class ContinuousBatcher:
             try:
                 with spans.span("gen.decode.dispatch", program="beam",
                                 lanes=len(active)):
-                    out = self._beam_prog(self._params(), self._k,
-                                          self._v, *args)
+                    out = self._beam_prog(self._params(), self._pools,
+                                          *args)
             except Exception:  # noqa: BLE001
                 # beam blocks are invisible to _reset_device (s.blocks
                 # is empty): free them first or they leak forever
                 _free_hyps(active)
                 self._reset_device()
                 return
-            self._k, self._v, top_tok_d, top_lp_d = out
+            self._pools, top_tok_d, top_lp_d = out
             try:
                 with spans.span("gen.wait", program="beam"):
                     top_tok = np.asarray(top_tok_d)
@@ -1783,10 +1859,9 @@ class ContinuousBatcher:
                                 break
                             blocks.extend(got)
                             src = pblocks[full]
-                            self._k = self._k.at[:, got[0]].set(
-                                self._k[:, src])
-                            self._v = self._v.at[:, got[0]].set(
-                                self._v[:, src])
+                            self._pools = tuple(
+                                p.at[:, got[0]].set(p[:, src])
+                                for p in self._pools)
                     new_active.append(
                         {"tokens": active[i]["tokens"] + [t],
                          "logprobs": active[i]["logprobs"] + [lp],
@@ -1893,8 +1968,9 @@ class ContinuousBatcher:
         self._lanes = [None] * self.max_seqs
         for s in list(self._running):
             self._deliver_error(s, err)
-        self._k = jnp.zeros(self._pool_shape, self._pool_dtype)
-        self._v = jnp.zeros(self._pool_shape, self._pool_dtype)
+        self._pools = tuple(jnp.zeros(shape, dtype)
+                            for shape, dtype in self._pool_shapes)
+        self._moe_pending.clear()
         # the rebuilt pools are zeroed: every indexed block's contents
         # are gone, so the content index must go with them
         self._alloc.reset_cache()

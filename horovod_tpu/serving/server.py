@@ -518,8 +518,8 @@ class _ServingHandler(_http.QuietHandler):
                 parent=self.headers.get(_tracing.TRACE_PARENT_HEADER),
                 args={"blocks": len(hashes)}):
             try:
-                served, k_np, v_np = gen.kv_export(hashes)
-                payload = pack_blocks(served, k_np, v_np, wire_dtype)
+                served, rows = gen.kv_export(hashes)
+                payload = pack_blocks(served, rows, wire_dtype)
             except ValueError as e:        # unknown wire dtype
                 self._respond(400, {"error": str(e)})
                 return

@@ -191,9 +191,9 @@ class TestWire:
         k = rng.randn(2, 3, 4, 2, 16).astype(np.float32)
         v = rng.randn(2, 3, 4, 2, 16).astype(np.float32)
         hashes = ["h0", "h1", "h2"]
-        doc = pack_blocks(hashes, k, v, "native")
+        doc = pack_blocks(hashes, (k, v), "native")
         json.dumps(doc)    # must be wire-serializable as-is
-        out_h, out_k, out_v, nbytes = unpack_blocks(doc)
+        out_h, (out_k, out_v), nbytes = unpack_blocks(doc)
         assert out_h == hashes
         assert out_k.dtype == np.float32
         assert np.array_equal(out_k, k) and np.array_equal(out_v, v)
@@ -203,8 +203,8 @@ class TestWire:
         rng = np.random.RandomState(SEED)
         k = rng.randn(1, 2, 4, 2, 16).astype(np.float32)
         v = rng.randn(1, 2, 4, 2, 16).astype(np.float32)
-        doc = pack_blocks(["h0", "h1"], k, v, "bf16")
-        out_h, out_k, out_v, nbytes = unpack_blocks(doc)
+        doc = pack_blocks(["h0", "h1"], (k, v), "bf16")
+        out_h, (out_k, out_v), nbytes = unpack_blocks(doc)
         assert out_h == ["h0", "h1"]
         assert str(out_k.dtype) == "bfloat16"
         assert nbytes == (k.nbytes + v.nbytes) // 2
@@ -214,11 +214,11 @@ class TestWire:
                               np.asarray(exact))
 
     def test_empty_and_bad_dtype(self):
-        assert unpack_blocks(pack_blocks([], None, None)) \
-            == ([], None, None, 0)
+        assert unpack_blocks(pack_blocks([], None)) \
+            == ([], None, 0)
         with pytest.raises(ValueError):
-            pack_blocks(["h"], np.zeros((1, 1, 2, 1, 4)),
-                        np.zeros((1, 1, 2, 1, 4)), "fp8")
+            pack_blocks(["h"], (np.zeros((1, 1, 2, 1, 4)),
+                                np.zeros((1, 1, 2, 1, 4))), "fp8")
 
 
 # ---------------------------------------------------------------------------
@@ -234,7 +234,7 @@ class TestExportImport:
         try:
             base = a.generate(PROMPT, max_tokens=8)
             hashes = a.kv_manifest(PROMPT)
-            served, k_np, v_np = a.kv_export(hashes)
+            served, rows = a.kv_export(hashes)
             assert served == hashes and len(served) == MANIFEST_BLOCKS
             # exporting must not corrupt the exporter: its own stats
             # still sum to capacity and the blocks stay matchable
@@ -242,7 +242,7 @@ class TestExportImport:
                 == a.allocator.capacity
             assert a.kv_probe(hashes) == MANIFEST_BLOCKS
 
-            held, imported = b.kv_import(hashes, served, k_np, v_np)
+            held, imported = b.kv_import(hashes, served, rows)
             assert (held, imported) == (0, MANIFEST_BLOCKS)
             assert b.kv_probe(hashes) == MANIFEST_BLOCKS
             assert b.allocator.remote_blocks == MANIFEST_BLOCKS
@@ -269,13 +269,13 @@ class TestExportImport:
         try:
             a.generate(PROMPT, max_tokens=4)
             hashes = a.kv_manifest(PROMPT)
-            served, k_np, v_np = a.kv_export(hashes)
-            assert b.kv_import(hashes, served, k_np, v_np) \
+            served, rows = a.kv_export(hashes)
+            assert b.kv_import(hashes, served, rows) \
                 == (0, MANIFEST_BLOCKS)
             stats = b.allocator.stats()
             # the second import of the identical manifest matches
             # everything and writes nothing
-            assert b.kv_import(hashes, served, k_np, v_np) \
+            assert b.kv_import(hashes, served, rows) \
                 == (MANIFEST_BLOCKS, 0)
             assert b.allocator.stats() == stats
             assert b.allocator.remote_blocks == MANIFEST_BLOCKS

@@ -151,11 +151,11 @@ class TestPagedParity:
         for chunk in (toks[0, :8], toks[0, 8:12]):
             buf = np.zeros((1, 8), np.int32)
             buf[0, :len(chunk)] = chunk
-            cache = PagedCache(k, v, jnp.asarray(table),
+            cache = PagedCache((k, v), jnp.asarray(table),
                                jnp.asarray([lengths], jnp.int32),
                                jnp.asarray([len(chunk)], jnp.int32))
             logits, cache = program(params, cache, jnp.asarray(buf))
-            k, v = cache.k, cache.v
+            k, v = cache.pools
             got.append(np.asarray(logits)[:, :len(chunk)])
             lengths += len(chunk)
         np.testing.assert_array_equal(np.concatenate(got, axis=1), full)
@@ -165,11 +165,11 @@ class TestPagedParity:
         for i in range(12, 16):
             buf = np.zeros((1, DECODE_WIDTH), np.int32)
             buf[0, 0] = toks[0, i]
-            cache = PagedCache(k, v, jnp.asarray(table),
+            cache = PagedCache((k, v), jnp.asarray(table),
                                jnp.asarray([i], jnp.int32),
                                jnp.asarray([1], jnp.int32))
             logits, cache = program(params, cache, jnp.asarray(buf))
-            k, v = cache.k, cache.v
+            k, v = cache.pools
             full_i = np.asarray(ref(params, jnp.asarray(toks[:, :i + 1])))
             np.testing.assert_array_equal(np.asarray(logits)[0, 0],
                                           full_i[0, -1])

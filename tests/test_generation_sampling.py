@@ -120,7 +120,7 @@ class TestDecodeProgramSurface:
                 top_p=jnp.ones((B,), jnp.float32),
                 key=jnp.zeros((B, 2), jnp.uint32),
                 emitted=jnp.zeros((B,), jnp.int32)))
-        k, v, new_state, tok, logp = prog(params, k, v, tables, state)
+        (k, v), new_state, tok, logp = prog(params, (k, v), tables, state)
         assert tok.shape == (B,) and tok.dtype == jnp.int32
         assert logp.shape == (B,) and logp.dtype == jnp.float32
         # no vocab axis anywhere in the host-consumed outputs
@@ -154,13 +154,13 @@ class TestDecodeProgramSurface:
                 top_p=jnp.ones((B,), jnp.float32),
                 key=jnp.zeros((B, 2), jnp.uint32),
                 emitted=jnp.zeros((B,), jnp.int32)))
-        _k, _v, ns, _tok, _logp = prog(params, k, v, tables, state)
+        (_k, _v), ns, _tok, _logp = prog(params, (k, v), tables, state)
         assert np.asarray(ns.live).tolist() == [0, 1]
         # snapshot host-side before the state is donated into step 2
         lengths1 = np.asarray(ns.lengths).tolist()
         tokens1 = np.asarray(ns.tokens).tolist()
         # a dead lane is frozen by the next step: no emission, no tick
-        _k, _v, ns2, tok2, _ = prog(params, _k, _v, tables, ns)
+        (_k, _v), ns2, tok2, _ = prog(params, (_k, _v), tables, ns)
         assert np.asarray(ns2.lengths).tolist()[0] == lengths1[0]
         assert int(np.asarray(tok2)[0]) == tokens1[0]
 
@@ -202,7 +202,7 @@ class TestGreedyParity:
         padded = np.zeros((1, 8), np.int32)
         padded[0, :len(prompt)] = prompt
         from horovod_tpu.models.transformer import PagedCache
-        cache = PagedCache(k, v, jnp.asarray(row),
+        cache = PagedCache((k, v), jnp.asarray(row),
                            jnp.zeros((1,), jnp.int32),
                            jnp.asarray([len(prompt)], jnp.int32))
         logits, _cache = raw(params, cache, jnp.asarray(padded))

@@ -79,17 +79,17 @@ def _programs(model, k, v, lanes=2, chunk=8, max_blocks=MAX_BLOCKS, beam=2):
     state = _state([0] * lanes, [0] * lanes, [0] * lanes, [0] * lanes)
     return {
         "raw": (kvc.build_program(model), (
-            PagedCache(k, v, tables, _i32(lanes), _i32(lanes)),
+            PagedCache((k, v), tables, _i32(lanes), _i32(lanes)),
             _i32(lanes, DECODE_WIDTH))),
         "prefill": (kvc.build_prefill_program(model), (
-            PagedCache(k, v, _i32(1, max_blocks), _i32(1), _i32(1)),
+            PagedCache((k, v), _i32(1, max_blocks), _i32(1), _i32(1)),
             _i32(1, chunk), _greedy(1))),
         "decode": (kvc.build_decode_program(model, DECODE_WIDTH),
-                   (k, v, tables, state)),
+                   ((k, v), tables, state)),
         "verify": (kvc.build_verify_program(model, SPEC),
-                   (k, v, tables, state, _i32(lanes, SPEC), _i32(lanes))),
+                   ((k, v), tables, state, _i32(lanes, SPEC), _i32(lanes))),
         "beam": (kvc.build_beam_program(model, beam, DECODE_WIDTH),
-                 (k, v, tables, _i32(lanes), _i32(lanes), _i32(lanes))),
+                 ((k, v), tables, _i32(lanes), _i32(lanes), _i32(lanes))),
     }
 
 
@@ -237,7 +237,7 @@ def _run_prefill(model, params, k, v):
     # written in part, three pad tokens
     tables, lengths, live = TABLES[:1], [6], [5]
     tokens = np.arange(3, 11, dtype=np.int32)[None, :]
-    cache = PagedCache(jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables),
+    cache = PagedCache((jnp.asarray(k), jnp.asarray(v)), jnp.asarray(tables),
                        jnp.asarray(lengths, jnp.int32),
                        jnp.asarray(live, jnp.int32))
     token, logprob, cache = kvc.build_prefill_program(model)(
@@ -246,7 +246,7 @@ def _run_prefill(model, params, k, v):
         params, jnp.asarray(tokens), jnp.asarray(k), jnp.asarray(v),
         jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(live))
     at = np.asarray(logits)[:, live[0] - 1]
-    return ((cache.k, cache.v), (want_k, want_v),
+    return (cache.pools, (want_k, want_v),
             _told_to_write(tables, lengths, live),
             (np.asarray(token), at.argmax(-1)),
             (np.asarray(logprob), np.asarray(
@@ -257,8 +257,8 @@ def _run_decode(model, params, k, v):
     # the second column of every lane is a pad token
     lengths, live = LENGTHS, LIVE
     state = _state(FIRST, lengths, live, [5, 5, 5, 5])
-    new_k, new_v, _, token, logprob = kvc.build_decode_program(
-        model, DECODE_WIDTH)(params, jnp.asarray(k), jnp.asarray(v),
+    (new_k, new_v), _, token, logprob = kvc.build_decode_program(
+        model, DECODE_WIDTH)(params, (jnp.asarray(k), jnp.asarray(v)),
                              jnp.asarray(TABLES), state)
     tokens = np.zeros((4, DECODE_WIDTH), np.int32)
     tokens[:, 0] = FIRST
@@ -306,8 +306,8 @@ def _run_verify(model, params, k, v):
             n_emit[b] = hit + 1
     assert n_emit[0] == 1 and n_emit[1] >= 2 and n_emit[3] == 1, n_emit
     state = _state(first, lengths, live, [9, 9, 9, 9])
-    new_k, new_v, _, got_pred, _, got_emit = kvc.build_verify_program(
-        model, SPEC)(params, jnp.asarray(k), jnp.asarray(v),
+    (new_k, new_v), _, got_pred, _, got_emit = kvc.build_verify_program(
+        model, SPEC)(params, (jnp.asarray(k), jnp.asarray(v)),
                      jnp.asarray(TABLES), state, jnp.asarray(draft),
                      jnp.asarray(draft_len))
     np.testing.assert_array_equal(np.asarray(got_emit), n_emit)
@@ -386,3 +386,54 @@ def test_v5e_program_has_no_pool_shaped_copy(one_v5e_chip, no_compile_cache,
     aliased, pool_params, offenders = _pool_structure(hlo, k.shape)
     assert aliased == pool_params and len(pool_params) == 2
     assert not offenders, offenders[:6]
+
+
+@pytest.mark.parametrize("name", ["prefill", "decode"])
+def test_v5e_latent_pool_is_row_major_and_uncopied(one_v5e_chip,
+                                                   no_compile_cache, name):
+    """The latent row of LongCat-Flash (ISSUE 27) at its published
+    widths and the benchmark's lanes, chunk, block and table: 576 values
+    pad to a 640-wide row, a multiple of 128, so the device stores the
+    ``(planes, blocks, 64, 640)`` pool row-major, aliases it input to
+    output and copies neither a plane nor the pool. One double layer
+    (two planes, two attentions, sixteen held experts) keeps the compile
+    short; the pool keeps the four layers' size a plane."""
+    from horovod_tpu.models import LongcatFlash, LongcatFlashConfig
+
+    cfg = LongcatFlashConfig(vocab_size=16384, num_layers=1,
+                             max_position_embeddings=16896,
+                             held_experts=(0, 16))
+    model = LongcatFlash(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_v5e_chip), tree)
+
+    with jax.enable_x64(False):     # the chip runs without x64
+        params = on_chip(jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), _i32(1, 8))))
+        pools = on_chip(jax.eval_shape(
+            lambda: kvc.make_pools(cfg, 3072, 64)))
+        (pool,) = pools
+        assert pool.shape == (2, 3072, 64, 640)
+        tables, lanes = _i32(32, 16896 // 64), 32
+        program, args = {
+            "prefill": (kvc.build_prefill_program(model), (
+                PagedCache(pools, _i32(1, 16896 // 64), _i32(1), _i32(1)),
+                _i32(1, 512), _greedy(1))),
+            "decode": (kvc.build_decode_program(model, DECODE_WIDTH), (
+                pools, tables, _state([0] * lanes, [0] * lanes,
+                                      [0] * lanes, [0] * lanes))),
+        }[name]
+        compiled = program.lower(params, *on_chip(args)).compile()
+    hlo = compiled.as_text()
+    aliased, pool_params, offenders = _pool_structure(hlo, pool.shape)
+    assert aliased == pool_params and len(pool_params) == 1
+    assert not offenders, offenders[:6]
+    layouts = set(re.findall(
+        r"bf16\[2,3072,64,640\]\{([\d,]*)", hlo.splitlines()[0]))
+    assert layouts == {"3,2,1,0"}, layouts
+    # both programs fit beside each other's arguments with room to spare
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
