@@ -7,7 +7,10 @@ random weights:
 
 * **kernel** — the Pallas flash-attention kernel, compiled by Mosaic,
   against the plain-XLA reference: forward and backward at the shape the
-  sequence-parallel train step hands it, forward at a long-context shape.
+  sequence-parallel train step hands it, forward at a long-context shape;
+  and the paged-attention decode kernel against the gather path it
+  replaces, at GPT-2 XL's decode shape (32 lanes, 25 x 64 heads, blocks
+  of 16) over ragged lengths with dead lanes.
 * **trainer** — ``hvd.init()`` -> ``hvd.DistributedOptimizer(optax.sgd)``
   -> the donated train step of ``horovod_tpu.benchmark._Rig``: ResNet-50,
   224x224, bf16, batch 256 per chip, batch sharded over a ``dp`` mesh of
@@ -20,7 +23,8 @@ random weights:
   ``POST /v1/generate`` requests, greedy and seeded-sampled, answer 200
   with the asked number of tokens; greedy tokens and their logprobs agree
   with ``jax.jit(model.apply)`` on the same prompt within ``LOGIT_TOL``;
-  no KV block leaks.
+  no KV block leaks; the decode program's lowered text holds the TPU
+  custom call (its attention is the paged kernel, not the gather path).
 * with four chips or more, also **ring_train** —
   ``make_transformer_train_step(TransformerConfig(), mesh)`` over
   ``MeshConfig(dp=-1, sp=2)``: ring attention on the compiled kernel (the
@@ -71,6 +75,10 @@ KERNEL_TOL = 0.02
 #: long-context shape (forward only: the reference's scores are 1 GiB)
 KERNEL_TRAIN_SHAPE = (2, 1024, 12, 64)
 KERNEL_LONG_SHAPE = (1, 8192, 4, 128)
+#: the paged decode kernel's case: (lanes, chunk columns, heads, head_dim,
+#: block_size, table blocks a lane, pool blocks): the ``gpt2-xl`` serving
+#: cells' decode step, two planes of their pool
+KERNEL_PAGED_SHAPE = (32, 2, 25, 64, 16, 64, 576)
 
 #: (prompt length, new tokens, sampled?) — at least one prompt spans more
 #: than one prefill chunk (64) and the burst outnumbers the 8 decode lanes
@@ -101,12 +109,60 @@ def _rel_err(got, want) -> float:
 
 # ---------------------------------------------------------------- phases
 
-def kernel_phase(train_shape=KERNEL_TRAIN_SHAPE, long_shape=KERNEL_LONG_SHAPE,
-                 dtype=None, interpret: bool = False) -> dict:
-    import jax
+def _paged_case(shape, dtype, seed: int = 2):
+    """A decode step's attention inputs at ``shape``: seeded pools, a
+    table a lane over distinct blocks (null-padded past its length),
+    ragged lengths from empty to a full table, every fifth lane dead
+    with a stale length."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.serving.generation.kv_cache import _row
+
+    lanes, chunk, heads, head_dim, bs, max_blocks, num_blocks = shape
+    rng = np.random.RandomState(seed)
+    pool = (2, num_blocks, bs, _row(heads * head_dim))
+    k_pool, v_pool = (jnp.asarray(rng.standard_normal(pool), dtype)
+                      for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((lanes, chunk, heads, head_dim)),
+                    dtype)
+    top = max_blocks * bs - chunk
+    lengths = rng.randint(0, top + 1, (lanes,))
+    lengths[:4] = (0, bs - 1, bs, top)[:lanes]
+    live = (np.arange(lanes) % 5 != 4).astype(np.int32)
+    tables = np.zeros((lanes, max_blocks), np.int32)
+    for b in range(lanes):
+        held = -(-(int(lengths[b]) + chunk) // bs)
+        tables[b, :held] = rng.choice(np.arange(1, num_blocks), held,
+                                      replace=False)
+    return (q, k_pool, v_pool, 1, jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32), jnp.asarray(live)), live
+
+
+def _paged_gather_path(q, k_pool, v_pool, layer, tables, lengths, live):
+    """What the kernel replaces: ``Attention``'s gather path with the
+    paged forward's mask (``live`` is not consulted: a dead lane's
+    output is never read)."""
     import jax.numpy as jnp
 
+    from horovod_tpu.models.transformer import (_gathered_attention,
+                                                _table_mask)
+
+    positions = lengths[:, None] + jnp.arange(q.shape[1])[None, :]
+    mask = _table_mask(positions, tables.shape[1] * k_pool.shape[2])
+    return _gathered_attention(q, k_pool, v_pool, layer, tables, mask,
+                               k_pool.dtype)
+
+
+def kernel_phase(train_shape=KERNEL_TRAIN_SHAPE, long_shape=KERNEL_LONG_SHAPE,
+                 paged_shape=KERNEL_PAGED_SHAPE, dtype=None,
+                 interpret: bool = False) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
     from horovod_tpu.ops.flash_attention import flash_attention, mha_reference
+    from horovod_tpu.ops.paged_attention import paged_attention
 
     dtype = dtype or jnp.bfloat16
 
@@ -131,18 +187,31 @@ def kernel_phase(train_shape=KERNEL_TRAIN_SHAPE, long_shape=KERNEL_LONG_SHAPE,
     fwd = jax.jit(flash)
     errs["long_fwd"] = _rel_err(
         fwd(ql, kl, vl), jax.jit(mha_reference)(ql, kl, vl))
+    paged_args, live = _paged_case(paged_shape, dtype)
+    paged = functools.partial(paged_attention, interpret=interpret)
+    gathered = jax.jit(_paged_gather_path)
+    got, want = paged(*paged_args), gathered(*paged_args)
+    errs["paged"] = max(_rel_err(got[b], want[b])
+                        for b in range(len(live)) if live[b])
+    if np.any(np.asarray(got, np.float32)[live == 0]):
+        raise AssertionError("paged kernel: a dead lane's output is not 0")
     setup_s = time.perf_counter() - t0
     t1 = time.perf_counter()
     jax.block_until_ready(fwd(ql, kl, vl))
     steady_s = time.perf_counter() - t1
+    paged_s = {}
+    for name, fn in (("kernel", paged), ("gather_path", gathered)):
+        t2 = time.perf_counter()
+        jax.block_until_ready([fn(*paged_args) for _ in range(8)])
+        paged_s[name] = round((time.perf_counter() - t2) / 8, 6)
     bad = {n: e for n, e in errs.items() if not e <= KERNEL_TOL}
     if bad:
         raise AssertionError(
-            f"flash kernel disagrees with the reference beyond "
+            f"a kernel disagrees with its reference beyond "
             f"{KERNEL_TOL}: {bad}")
     return {"setup_s": setup_s, "steady_s": steady_s, "interpret": interpret,
             "rel_err": {n: round(e, 5) for n, e in errs.items()},
-            "tolerance": KERNEL_TOL}
+            "tolerance": KERNEL_TOL, "paged_call_s": paged_s}
 
 
 def trainer_phase(model_name: str = "resnet50", image_size: int = 224,
@@ -194,6 +263,32 @@ def _post(url: str, doc: dict, timeout: float = 900.0):
         return e.code, {"error": e.read().decode(errors="replace")}
 
 
+def _decode_program_text(engine) -> str:
+    """The engine's decode program lowered at the shapes its scheduler
+    calls it with (abstract arguments: nothing runs, nothing is
+    donated)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.serving.generation.kv_cache import (DecodeState,
+                                                         SampleParams)
+
+    b = engine.batcher
+    lanes = lambda dtype, *rest: jax.ShapeDtypeStruct(  # noqa: E731
+        (b.max_seqs, *rest), dtype)
+    i32, f32 = lanes(jnp.int32), lanes(jnp.float32)
+    state = DecodeState(
+        tokens=i32, lengths=i32, live=i32, remaining=i32, eos=i32,
+        sample=SampleParams(temperature=f32, top_k=i32, top_p=f32,
+                            key=lanes(jnp.uint32, 2), emitted=i32))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), engine.params)
+    pools = tuple(jax.ShapeDtypeStruct(shape, dtype)
+                  for shape, dtype in b._pool_shapes)
+    return b._decode_prog.lower(
+        params, pools, lanes(jnp.int32, b.max_blocks), state).as_text()
+
+
 def server_phase(cfg=None, requests=REQUESTS, **engine_kwargs) -> dict:
     import jax
     import jax.numpy as jnp
@@ -229,6 +324,14 @@ def server_phase(cfg=None, requests=REQUESTS, **engine_kwargs) -> dict:
                                 "deadline_ms": 900_000})
         if code != 200:
             raise AssertionError(f"warm-up request answered {code}: {doc}")
+        # a TPU's decode step attends through the paged kernel, every
+        # other backend through the gather path: never an interpreter
+        compiled_kernel = "tpu_custom_call" in _decode_program_text(engine)
+        if compiled_kernel != (jax.default_backend() == "tpu"):
+            raise AssertionError(
+                f"the decode program's lowered text "
+                f"{'holds' if compiled_kernel else 'holds no'} "
+                f"tpu_custom_call on backend {jax.default_backend()!r}")
         setup_s = time.perf_counter() - t0
 
         answers = [None] * len(requests)
@@ -296,7 +399,7 @@ def server_phase(cfg=None, requests=REQUESTS, **engine_kwargs) -> dict:
             "greedy_tokens_checked": total, "greedy_exact_argmax": exact,
             "worst_logit_gap": round(worst_gap, 5),
             "worst_logprob_gap": round(worst_lp, 5), "tolerance": LOGIT_TOL,
-            **placement}
+            "decode_compiled_kernel": compiled_kernel, **placement}
 
 
 def ring_train_phase(devices, cfg=None, steps: int = 2,

@@ -553,10 +553,14 @@ GEN_BLOCK_SIZE = _register(
     help="Tokens per KV-cache block in the paged generation cache. "
          "Smaller blocks track live tokens tighter (less padding waste "
          "per sequence, at most block_size-1 slots); larger blocks mean "
-         "fewer allocator operations and block-table entries. The "
-         "compiled decode program gathers max_seq_len/block_size blocks "
-         "per sequence, so the product with HVD_TPU_GEN_NUM_BLOCKS is "
-         "the pool's token capacity.")
+         "fewer allocator operations and block-table entries. A "
+         "sequence's block table holds max_seq_len/block_size entries; "
+         "on a TPU the decode step reads the blocks a lane holds "
+         "through the paged-attention kernel when a block is whole "
+         "tiles of the cache dtype and divides 128 (16, 32, 64 or 128 "
+         "for bfloat16), and gathers every table otherwise. The "
+         "product with HVD_TPU_GEN_NUM_BLOCKS is the pool's token "
+         "capacity.")
 GEN_NUM_BLOCKS = _register(
     "GEN_NUM_BLOCKS", 512, int,
     help="KV-cache blocks in the generation pool (block 0 is reserved "

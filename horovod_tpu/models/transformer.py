@@ -34,22 +34,39 @@ incremental forward against a **paged KV cache** when ``__call__`` is
 given a :class:`PagedCache`. One code path covers both phases of
 autoregressive generation: a *prefill chunk* (``tokens`` is ``(B, C)``
 with ``C`` prompt tokens, of which ``live`` are real) and a *decode
-step* (``C == 1``). New K/V are scattered into fixed-size cache blocks
-through each sequence's block table, then attention gathers the whole
-table back — so live KV memory scales with live tokens, not
-``max_len × batch``. The pools are written **in place**: one live
-``(layers, blocks, block_size, row)`` pool is threaded
-through the layers, layer ``i`` scatters at ``[i, blocks, offsets]``
-and gathers ``[i, block_tables]`` from what it has just written, and
+step* (a chunk of a few columns). New K/V are scattered into
+fixed-size cache blocks through each sequence's block table, then
+attention reads them back through the table — so live KV memory scales
+with live tokens, not ``max_len × batch``. The pools are written **in
+place**: one live ``(layers, blocks, block_size, row)`` pool is
+threaded through the layers, layer ``i`` scatters at ``[i, blocks,
+offsets]``, attends over plane ``i`` of what it has just written, and
 hands the pool on — no layer's slab is sliced out or put back, so a
 program that donates the pools holds a single version of each.
 Block 0 is the **null block**: padded slots and
 dead batch lanes write there (and only there), which keeps every shape
 static across steps — the jit cache sees exactly two programs, one per
-phase. The paged read path deliberately reuses
-:func:`_default_attention` so decode logits are bit-identical to the
-full-sequence forward (``attention_fn`` injection is a training-side
-hook and is not consulted during paged decode). The paged path also
+phase.
+
+**Which attention the paged read runs** is decided by what the code can
+see (:func:`horovod_tpu.ops.paged_attention.kernel_applies`), not by an
+option. On a TPU, for a chunk of a few query columns (the decode,
+verify and beam programs) over a pool of whole tiles, it is the Pallas
+paged-attention kernel: the pools stay in HBM, a lane's blocks are read
+where they lie and only as far as ``lengths + C``, a dead lane costs no
+copy, and a step's cost follows the live tokens. Everywhere else — any
+other backend, the prefill program's chunk, a toy pool of 4-token
+blocks — it is the gather path (:func:`_gathered_attention`): every
+slot of every table gathered into one ``(B, T, H, D)`` view, then
+:func:`_default_attention` over it; the kernel's oracle. The gather
+path deliberately reuses :func:`_default_attention`, so on XLA-CPU
+decode logits are bit-identical to the full-sequence forward (tests pin
+it); a TPU promises no bit identity between differently shaped
+programs, and there the contract is a tolerance (the kernel within
+``chip_smoke.KERNEL_TOL`` of the gather path, a served token's
+reference logit within ``LOGIT_TOL`` of the best).
+(``attention_fn`` injection is a training-side
+hook and is not consulted on the paged path.) The paged path also
 takes an optional ``logits_at`` ``(B,)`` position index: the vocab
 projection then runs only at that position per row and returns
 ``(B, vocab)`` logits — the serving sampling programs use it so the
@@ -63,6 +80,8 @@ from typing import Any, Callable, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ..ops import paged_attention
 
 Dtype = Any
 
@@ -89,6 +108,14 @@ class TransformerConfig:
         return CacheSpec(planes=self.num_layers,
                          rows=(("k", width), ("v", width)),
                          dtype=self.dtype)
+
+    def paged_query_rows(self, chunk: int) -> int:
+        """Query vectors a ``chunk``-column step of the paged forward
+        brings to :mod:`horovod_tpu.ops.paged_attention`, one a head a
+        column: what its path rule (``kernel_applies``) is asked about,
+        by :class:`Attention` and by whoever counts what a program
+        reads."""
+        return chunk * self.num_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,6 +181,29 @@ def _default_attention(q, k, v, mask, dtype):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _table_mask(positions, slots: int):
+    """The gather path's causal mask ``(B, 1, C, slots)``: gathered slot
+    ``t`` holds absolute position ``t``, and a chunk query at absolute
+    position ``p`` attends to every ``t <= p``."""
+    return (jnp.arange(slots)[None, None, None, :]
+            <= positions[:, None, :, None])
+
+
+def _gathered_attention(q, k_pool, v_pool, layer, block_tables, mask, dtype):
+    """The paged read as plain XLA: gather every slot of every lane's
+    table from plane ``layer`` of the pools into one contiguous
+    ``(B, T, H, D)`` view (``T = max_blocks * block_size``, position
+    ``t`` at index ``t``) and run :func:`_default_attention` over all of
+    it. What a prefill chunk and every run off a TPU take, and the
+    oracle :mod:`~horovod_tpu.ops.paged_attention` is held to."""
+    B, _, H, D = q.shape
+    with jax.named_scope("kv_gather"):
+        kc = k_pool[layer, block_tables][..., :H * D].reshape(B, -1, H, D)
+        vc = v_pool[layer, block_tables][..., :H * D].reshape(B, -1, H, D)
+    with jax.named_scope("attention"):
+        return _default_attention(q, kc, vc, mask, dtype)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -212,16 +262,17 @@ class Attention(nn.Module):
                 jnp.pad(k.reshape(B, C, H * D), pad))
             v_pool = v_pool.at[layer, blocks, offsets].set(
                 jnp.pad(v.reshape(B, C, H * D), pad))
-        with jax.named_scope("kv_gather"):
-            # gather every table slot back, from the pool just written,
-            # as one contiguous (B, T, H, D) view — T = max_blocks *
-            # block_size, position t lives at index t
-            kc = k_pool[layer, block_tables][..., :H * D].reshape(
-                B, -1, H, D)
-            vc = v_pool[layer, block_tables][..., :H * D].reshape(
-                B, -1, H, D)
-        with jax.named_scope("attention"):
-            out = _default_attention(q, kc, vc, mask, dt)
+        if paged_attention.kernel_applies(cfg.paged_query_rows(C), block_size,
+                                          k_pool.shape[3], k_pool.dtype):
+            # a few query columns on a TPU: the kernel walks each live
+            # lane's blocks where they lie, as far as the lane has rows
+            with jax.named_scope("attention"):
+                out = paged_attention.paged_attention(
+                    q, k_pool, v_pool, layer, block_tables,
+                    positions[:, 0], live)
+        else:
+            out = _gathered_attention(q, k_pool, v_pool, layer,
+                                      block_tables, mask, dt)
         return (jnp.einsum("bshd,hde->bse", out, wo.astype(dt)),
                 (k_pool, v_pool))
 
@@ -290,11 +341,9 @@ class Transformer(nn.Module):
             safe_pos = jnp.clip(positions, 0, cfg.max_seq_len - 1)
             x = emb.astype(cfg.dtype)[tokens] \
                 + pos.astype(cfg.dtype)[safe_pos]
-            # gathered cache slot t holds absolute position t; a chunk
-            # query at absolute position p attends to every t <= p
-            t_max = cache.block_tables.shape[1] * cache.pools[0].shape[2]
-            mask = (jnp.arange(t_max)[None, None, None, :]
-                    <= positions[:, None, :, None])
+            mask = _table_mask(
+                positions,
+                cache.block_tables.shape[1] * cache.pools[0].shape[2])
         k_pool, v_pool = (None, None) if cache is None else cache.pools
         layer_cls = DecoderLayer
         if cfg.remat and cache is None:
@@ -304,7 +353,7 @@ class Transformer(nn.Module):
             if cache is None:
                 x = layer(x, mask, None)
             else:
-                # one live pool: layer i scatters into and gathers from
+                # one live pool: layer i scatters into and attends over
                 # plane i of what layer i-1 returned
                 x, (k_pool, v_pool) = layer(
                     x, mask, (k_pool, v_pool, i, cache.block_tables,

@@ -57,6 +57,7 @@ from ... import _locks
 from ... import config as _config
 from ... import metrics as _metrics
 from ...models.transformer import PagedCache
+from ...ops import paged_attention
 
 _M_BLOCKS = _metrics.gauge(
     "hvd_tpu_gen_kv_blocks_in_use",
@@ -523,6 +524,29 @@ def _paged_apply(model, params, tokens, cache, logits_at=None):
     return logits, cache, (jax.numpy.concatenate([first, counts]),)
 
 
+def _query_rows(model, chunk: int):
+    """Query rows the model's paged attention brings to
+    :mod:`horovod_tpu.ops.paged_attention` for a ``chunk``-column step
+    (``cfg.paged_query_rows``), or None for a model whose attention
+    never calls it. The decode-side builders hang it on their program
+    as ``query_rows`` for :func:`reads_live_blocks`."""
+    rows = getattr(model.cfg, "paged_query_rows", None)
+    return None if rows is None else int(rows(chunk))
+
+
+def reads_live_blocks(program, pools) -> bool:
+    """Whether ``program``'s attention over ``pools`` runs on the paged
+    kernel, which reads the blocks the live lanes hold and no others
+    (else: the gather path, every table). The forward's own rule
+    (:func:`~horovod_tpu.ops.paged_attention.kernel_applies`) on the
+    shapes this program was built for, so the scheduler counts what the
+    program does without lowering it."""
+    rows = getattr(program, "query_rows", None)
+    pool = pools[0]
+    return rows is not None and paged_attention.kernel_applies(
+        rows, pool.shape[2], pool.shape[3], pool.dtype)
+
+
 @functools.lru_cache(maxsize=8)
 def build_program(model):
     """The raw-logits jitted incremental forward.
@@ -752,7 +776,9 @@ def build_decode_program(model, decode_width: int = 2):
                 state.sample, emitted=state.sample.emitted + live))
         return (cache.pools, new_state, token, logprob, *stats)
 
-    return jax.jit(_decode, donate_argnums=(1, 3))
+    program = jax.jit(_decode, donate_argnums=(1, 3))
+    program.query_rows = _query_rows(model, decode_width)
+    return program
 
 
 @functools.lru_cache(maxsize=8)
@@ -885,7 +911,9 @@ def build_verify_program(model, spec_tokens: int):
                 state.sample, emitted=state.sample.emitted + n_emit))
         return new_pools, new_state, pred, logp, n_emit
 
-    return jax.jit(_verify, donate_argnums=(1, 3))
+    program = jax.jit(_verify, donate_argnums=(1, 3))
+    program.query_rows = _query_rows(model, C)
+    return program
 
 
 @functools.lru_cache(maxsize=8)
@@ -925,4 +953,6 @@ def build_beam_program(model, beam_k: int, decode_width: int = 2):
             jax.nn.log_softmax(logits, axis=-1), K)
         return cache.pools, top_tok.astype(jnp.int32), top_lp
 
-    return jax.jit(_beam_step, donate_argnums=(1,))
+    program = jax.jit(_beam_step, donate_argnums=(1,))
+    program.query_rows = _query_rows(model, decode_width)
+    return program

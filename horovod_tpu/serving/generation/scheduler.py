@@ -138,11 +138,12 @@ from ... import faults as _faults
 from ... import metrics as _metrics
 from ... import tracing as _tracing
 from ...models.transformer import PagedCache
+from ...ops import paged_attention
 from ...parallel.moe import STATS_FIELDS
 from ..batcher import DeadlineExceededError, QueueFullError
 from .kv_cache import (BlockAllocator, BlocksExhaustedError, DecodeState,
                        SampleParams, chain_hash, gather_blocks,
-                       scatter_blocks)
+                       reads_live_blocks, scatter_blocks)
 
 _M_TOKENS = _metrics.counter(
     "hvd_tpu_gen_tokens_total",
@@ -187,6 +188,18 @@ _M_MOE_CALLS = _metrics.counter(
     "Prefill chunks and decode steps whose routing counts were read "
     "(verify and beam steps are not counted).",
     labels=("phase",))
+_M_PAGED_BLOCKS = _metrics.counter(
+    "hvd_tpu_gen_paged_attn_blocks_total",
+    "KV blocks one attention sublayer of a decode, verify or beam step "
+    "had before it, summed over dispatches: kind='table' every entry of "
+    "every lane's block table (lanes x max_blocks, what the gather path "
+    "reads whatever the lanes hold), kind='read' the blocks the "
+    "program's attention reads: on the paged-attention kernel each live "
+    "lane's ceil((length + chunk) / block_size) rounded up to the "
+    "kernel's group of blocks, on the gather path the whole table. "
+    "read/table is the share of the table a step pays for: 1 means the "
+    "kernel did not engage (not a TPU, or shapes it does not take).",
+    labels=("kind",))
 _M_RUNNING = _metrics.gauge(
     "hvd_tpu_gen_running_seqs",
     "Sequences currently in the running set (prefilling or decoding). "
@@ -603,6 +616,14 @@ class ContinuousBatcher:
         #: table width: every sequence's block table is padded to the
         #: worst-case block count, so the compiled shapes never move
         self.max_blocks = allocator.blocks_for(self.max_seq_len)
+        #: the decode-side programs whose attention is the paged kernel
+        #: (it reads a live lane's blocks; the gather path reads every
+        #: table): what hvd_tpu_gen_paged_attn_blocks_total counts by
+        self._reads_live = {
+            kind for kind, prog in (("decode", self._decode_prog),
+                                    ("verify", verify_program),
+                                    ("beam", beam_program))
+            if prog is not None and reads_live_blocks(prog, self._pools)}
         self._ids = itertools.count()
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
         # scheduler-thread-private state (never touched off-thread):
@@ -1426,6 +1447,12 @@ class ContinuousBatcher:
             try:
                 with self._spans.span("gen.decode.dispatch",
                                       program="decode", lanes=len(batch)):
+                    # the device's lengths run ahead of the host's
+                    # mirror by the steps in flight
+                    ahead = len(self._inflight)
+                    self._count_attention_blocks(
+                        "decode", [x.cache_len + ahead for x in batch],
+                        DECODE_WIDTH)
                     out = self._decode_prog(self._params(), self._pools,
                                             self._dtables, self._dstate)
             except Exception:  # noqa: BLE001
@@ -1439,6 +1466,18 @@ class ContinuousBatcher:
         limit = self.async_depth if batch else 0
         while len(self._inflight) > limit:
             self._process_flight(now)
+
+    def _count_attention_blocks(self, kind: str, lengths, chunk: int) -> None:
+        """One dispatch of program ``kind`` over live lanes holding
+        ``lengths`` tokens, ``chunk`` columns wide, into
+        ``hvd_tpu_gen_paged_attn_blocks_total``."""
+        table = self.max_seqs * self.max_blocks
+        read = table if kind not in self._reads_live else \
+            paged_attention.blocks_read(lengths, chunk,
+                                        self._alloc.block_size,
+                                        self.max_blocks)
+        _M_PAGED_BLOCKS.labels(kind="table").inc(table)
+        _M_PAGED_BLOCKS.labels(kind="read").inc(read)
 
     def _prepare_decode(self, span) -> List[GenSequence]:
         """What the plain and the speculative step prepare alike, under
@@ -1640,6 +1679,9 @@ class ContinuousBatcher:
         try:
             with spans.span("gen.decode.dispatch", program="verify",
                             lanes=len(batch)):
+                self._count_attention_blocks(
+                    "verify", [x.cache_len for x in batch],
+                    self.spec_tokens + 1)
                 out = self._verify_prog(self._params(), self._pools,
                                         self._dtables, self._dstate,
                                         draft_d, dlen_d)
@@ -1785,6 +1827,9 @@ class ContinuousBatcher:
             try:
                 with spans.span("gen.decode.dispatch", program="beam",
                                 lanes=len(active)):
+                    self._count_attention_blocks(
+                        "beam", [h["cache_len"] for h in active],
+                        DECODE_WIDTH)
                     out = self._beam_prog(self._params(), self._pools,
                                           *args)
             except Exception:  # noqa: BLE001

@@ -39,8 +39,10 @@ def test_refuses_to_run_without_a_chip():
 def test_kernel_phase_tiny_interpreted():
     out = chip_smoke.kernel_phase(train_shape=(1, 32, 2, 16),
                                   long_shape=(1, 64, 2, 16),
+                                  paged_shape=(5, 2, 2, 16, 8, 3, 16),
                                   dtype=jnp.float32, interpret=True)
     assert out["interpret"] and max(out["rel_err"].values()) < 1e-4
+    assert "paged" in out["rel_err"]
 
 
 def test_trainer_phase_tiny(hvd_world):
@@ -60,6 +62,7 @@ def test_server_phase_tiny(hvd_world):
     assert out["greedy_tokens_checked"] == 12
     assert out["worst_logit_gap"] < 1e-4 and out["worst_logprob_gap"] < 1e-4
     assert len(out["params_devices"]) == len(out["kv_pool_devices"]) == 1
+    assert out["decode_compiled_kernel"] is False     # off the TPU: gather
 
 
 def test_ring_train_phase_tiny_interpreted(hvd_world):

@@ -14,7 +14,10 @@ Three pins, all on compiled programs:
   and return equals a slab-at-a-time oracle kept in this file;
 * **the device's layout** — the same programs at GPT-2 XL's widths,
   compiled for a described TPU v5e (no chip needed), hold no pool- or
-  slab-shaped copy either. On the CPU any row-major pool passes the
+  slab-shaped copy either; and the decode, verify and beam programs
+  there attend through the paged-attention kernel (ISSUE 28): a
+  ``tpu_custom_call`` a layer, no gathered ``(32, 1024, 1664)`` table
+  and no ``(32, 1024, 25, 64)`` re-tiling of one. On the CPU any row-major pool passes the
   first pin; a TPU tiles the two minor axes ``(8, 128)``, stores a
   ``(..., heads, head_dim)`` pool blocks-minor to avoid padding
   ``(25, 64)``, and then relayouts every slab it scatters into or
@@ -357,14 +360,27 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("name", ["prefill", "decode", "verify"])
+#: arrays the gather path makes of the tables at these widths: the
+#: gathered K or V of every lane, flat and per block, and its re-tiling
+#: for the attention einsums
+_TABLE_SHAPED = ("32,1024,25,64", "32,1024,1664", "32,64,16,1664",
+                 "2048,16,1664")
+
+
+@pytest.mark.parametrize("name", ["prefill", "decode", "verify", "beam"])
 def test_v5e_program_has_no_pool_shaped_copy(one_v5e_chip, no_compile_cache,
-                                             name):
+                                             monkeypatch, name):
     """GPT-2 XL's widths and the benchmark's lanes, chunk and tables:
     what the TPU compiler makes of the pool's layout. Two layers keep
     the compile short; they hold the 48 layers' 576 blocks each, since
     a pool small enough for the chip's fast memory is prefetched there
-    whole, which is no copy the real program makes."""
+    whole, which is no copy the real program makes.
+
+    The programs are traced as on the chip (the path rule asks for the
+    default backend, which is a TPU there): a few query columns go
+    through the kernel, which leaves the pools in HBM and gathers no
+    table; the prefill chunk's 1600 query rows keep the gather path."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = TransformerConfig(vocab_size=50257, num_layers=2, d_model=1600,
                             num_heads=25, head_dim=64, max_seq_len=1024,
                             dtype=jnp.bfloat16)
@@ -386,6 +402,13 @@ def test_v5e_program_has_no_pool_shaped_copy(one_v5e_chip, no_compile_cache,
     aliased, pool_params, offenders = _pool_structure(hlo, k.shape)
     assert aliased == pool_params and len(pool_params) == 2
     assert not offenders, offenders[:6]
+    kernels = len(re.findall(r'custom_call_target="tpu_custom_call"', hlo))
+    if name == "prefill":
+        assert kernels == 0
+        return
+    assert kernels == cfg.num_layers, kernels
+    made = [shape for shape in _TABLE_SHAPED if f"[{shape}]" in hlo]
+    assert not made, made
 
 
 @pytest.mark.parametrize("name", ["prefill", "decode"])
