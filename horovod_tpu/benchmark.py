@@ -13,7 +13,7 @@ in-place across steps (HBM-friendly).
 
 import dataclasses
 import time
-from typing import Any, Callable, Optional, Tuple
+from typing import Optional
 
 import numpy as np
 
@@ -30,7 +30,6 @@ class BenchResult:
     device_kind: str = "unknown"
     flops_per_step: Optional[float] = None
     mfu: Optional[float] = None
-    stem: Optional[str] = "conv"   # None: model has no stem knob
 
 
 # Peak dense bf16 FLOP/s per chip by device kind (public spec-sheet numbers;
@@ -43,17 +42,6 @@ _TPU_PEAK_BF16_FLOPS = (
     ("v3", 123e12),
     ("v2", 45e12),
 )
-
-
-def _resolve_stem(model_name: str, stem: Optional[str]) -> Optional[str]:
-    """The stem knob exists only on the ResNet family; resolution order
-    is per-stage override > env knob > canonical conv. Shared by _Rig and
-    the ladder so the ladder's rebuild check can never disagree with what
-    the rig actually built."""
-    import os
-    if not model_name.startswith("resnet"):
-        return None
-    return stem or os.environ.get("HVD_TPU_BENCH_STEM", "conv")
 
 
 def peak_flops_per_chip(device_kind: str) -> Optional[float]:
@@ -73,17 +61,13 @@ def peak_flops_per_chip(device_kind: str) -> Optional[float]:
 
 
 class _Rig:
-    """Compiled benchmark state for one (model, batch) configuration.
-
-    Built once per batch size; ``run_stage`` can then be called repeatedly
-    (e.g. a quick low-iteration measurement followed by a longer one)
-    without recompiling — the jit cache lives on the ``train_step`` object
-    held here.
-    """
+    """Compiled benchmark state for one (model, batch) configuration:
+    the synthetic batch, the parameters and optimizer state on every
+    chip, and the donated train step, compiled once ahead of time and
+    held as ``train_step``."""
 
     def __init__(self, batch_per_chip: int, image_size: int,
-                 model_name: str, optimizer_name: str,
-                 stem: Optional[str] = None):
+                 model_name: str, optimizer_name: str):
         import jax
         import jax.numpy as jnp
         import optax
@@ -106,21 +90,14 @@ class _Rig:
         batch_sharding = NamedSharding(mesh, P("dp"))
         replicated = NamedSharding(mesh, P())
 
-        # Math-equivalent MXU-friendly stem (models/resnet.py
-        # SpaceToDepthStem); numerics-tested equal, so using it is a
-        # layout optimization, not a model change. A stem-less model
-        # records None so results never claim an A/B that did not happen
-        # and the ladder never rebuilds over a no-op stem change.
-        self.stem = _resolve_stem(model_name, stem)
         # the benchmark trio of the reference's scaling table
         # (docs/benchmarks.rst:13-14): ResNet, VGG (dropout off for a
         # deterministic throughput workload; BN-free, exercising the
         # no-batch-stats path)
         builders = {
-            "resnet18": lambda: ResNet18(num_classes=1000, stem=self.stem),
-            "resnet50": lambda: ResNet50(num_classes=1000, stem=self.stem),
-            "resnet101": lambda: ResNet101(num_classes=1000,
-                                           stem=self.stem),
+            "resnet18": lambda: ResNet18(num_classes=1000),
+            "resnet50": lambda: ResNet50(num_classes=1000),
+            "resnet101": lambda: ResNet101(num_classes=1000),
             "vgg16": lambda: VGG16(num_classes=1000, dropout_rate=0.0),
             # tf_cnn_benchmarks' name for it; canonical input is 299px
             # but any size >= 75 runs
@@ -188,38 +165,7 @@ class _Rig:
                 "step, so no MFU can be derived from this run")
         self.flops_per_step = float(flops)
 
-        # Scanned k-step program: the whole timed iteration is ONE XLA
-        # call (lax.fori_loop over steps), eliminating per-step host
-        # dispatch from the measurement — how a real TPU input pipeline
-        # drives the chip, and the reference has no equivalent (its
-        # benchmark loops in Python around session.run).
-        def _multi(k):
-            def body(_, carry):
-                p, bs, s, _loss = carry
-                return _step(p, bs, s, self.images, self.labels)
-
-            def f(p, bs, s):
-                import jax.lax as lax
-                return lax.fori_loop(
-                    0, k, body, (p, bs, s,
-                                 jnp.zeros((), jnp.float32)))
-            return jax.jit(f, donate_argnums=(0, 1, 2))
-
-        self._multi_step_cache = {}
-        self._make_multi = _multi
-
-        self._warmed_up = 0
-
-    def _run_batches(self, k, scanned: bool = False):
-        if scanned and k > 1:
-            fn = self._multi_step_cache.get(k)
-            if fn is None:
-                fn = self._multi_step_cache[k] = self._make_multi(k)
-            p, bs, s, loss = fn(self.params, self.batch_stats,
-                                self.opt_state)
-            float(loss)
-            self.params, self.batch_stats, self.opt_state = p, bs, s
-            return
+    def _run_batches(self, k):
         p, bs, s = self.params, self.batch_stats, self.opt_state
         loss = None
         for _ in range(k):
@@ -233,29 +179,14 @@ class _Rig:
         self.params, self.batch_stats, self.opt_state = p, bs, s
 
     def run_stage(self, num_warmup_batches: int, num_batches_per_iter: int,
-                  num_iters: int, scanned: bool = False,
-                  verbose: bool = False) -> BenchResult:
-        # Warmup counts accumulate: a second stage on an already-warm rig
-        # only runs whatever extra warmup it asked for beyond the first's.
-        if scanned and num_batches_per_iter > 1:
-            # The k-step pre-warm IS the warmup for a scanned stage: using
-            # the plain path first would compile the single-step program a
-            # fresh rig never measures (one full extra XLA compile).
-            k = num_batches_per_iter
-            if k not in self._multi_step_cache \
-                    or self._warmed_up < num_warmup_batches:
-                self._run_batches(k, scanned=True)
-                self._warmed_up = max(self._warmed_up, num_warmup_batches)
-        else:
-            extra = max(0, num_warmup_batches - self._warmed_up)
-            if extra:
-                self._run_batches(extra)
-                self._warmed_up += extra
+                  num_iters: int, verbose: bool = False) -> BenchResult:
+        if num_warmup_batches:
+            self._run_batches(num_warmup_batches)
 
         durations = []
         for i in range(num_iters):
             t0 = time.perf_counter()
-            self._run_batches(num_batches_per_iter, scanned=scanned)
+            self._run_batches(num_batches_per_iter)
             dt = time.perf_counter() - t0
             durations.append(dt)
             if verbose:
@@ -283,7 +214,6 @@ class _Rig:
             device_kind=self.device_kind,
             flops_per_step=self.flops_per_step,
             mfu=mfu,
-            stem=self.stem,
         )
 
 
@@ -299,48 +229,3 @@ def synthetic_resnet50_benchmark(
     rig = _Rig(batch_per_chip, image_size, model_name, optimizer_name)
     return rig.run_stage(num_warmup_batches, num_batches_per_iter,
                          num_iters, verbose=verbose)
-
-
-def synthetic_resnet50_ladder(stages, image_size: int = 224,
-                              model_name: str = "resnet50",
-                              optimizer_name: str = "sgd"):
-    """Generator: run ``stages`` cheapest-first, yielding
-    ``(BenchResult | None, error | None)`` per stage. Stages with the same
-    ``batch_per_chip`` share one compiled rig (no recompilation); changing
-    batch size frees the previous rig before building the next (HBM
-    hygiene).
-
-    Per-stage failures (e.g. a larger batch OOMing) are yielded as
-    ``(None, exc)`` rather than raised — raising out of a generator
-    exhausts it, which would silently cancel every remaining stage. A
-    failed stage also drops the rig (a fault mid-step can leave donated
-    buffers invalidated), so the next stage rebuilds from scratch.
-
-    Each stage is a dict with keys ``batch_per_chip``,
-    ``num_warmup_batches``, ``num_batches_per_iter``, ``num_iters``.
-    The caller decides whether to pull the next stage — checking its
-    remaining wall-clock budget before paying the next compile.
-    """
-    import os
-    rig = None
-    for st in stages:
-        b = st["batch_per_chip"]
-        # a stage without an explicit stem resolves to the env default —
-        # the SAME resolution _Rig applies — so a default stage after a
-        # stem-overridden one correctly rebuilds instead of silently
-        # measuring the previous stage's stem
-        want_stem = _resolve_stem(model_name, st.get("stem"))
-        try:
-            if rig is None or rig.batch_per_chip != b \
-                    or want_stem != rig.stem:
-                # free donated buffers before allocating the next batch
-                rig = None
-                rig = _Rig(b, image_size, model_name, optimizer_name,
-                           stem=want_stem)
-            yield rig.run_stage(st["num_warmup_batches"],
-                                st["num_batches_per_iter"],
-                                st["num_iters"],
-                                scanned=st.get("scanned", False)), None
-        except Exception as e:  # noqa: BLE001 — caller triages per stage
-            rig = None
-            yield None, e

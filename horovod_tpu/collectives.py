@@ -249,9 +249,8 @@ def _stage_input(t):
     through the host: a fully-addressable jax array is used as-is
     (``device_put`` in ``_global_from_local`` moves it device-to-device if
     needed), everything else becomes numpy. ``np.asarray`` on a jax array
-    would read it back to the host only to ship it straight back — the
-    round-4 microbenchmark exists to catch exactly this class of staging
-    waste (reference analogue: the CudaOnCPU staging fallback vs the
+    would read it back to the host only to ship it straight back
+    (reference analogue: the CudaOnCPU staging fallback vs the
     direct-GPU path, torch/mpi_ops_v2.cc:92)."""
     jax = _jax()
     if isinstance(t, jax.Array) and t.is_fully_addressable:
@@ -565,11 +564,10 @@ def _allreduce_impl(w, values, op, prescale_factor, postscale_factor,
     # relocated to where the bytes actually live at eager staging time.
     # Members that are already device-resident jax arrays stay separate
     # program args: host-packing those would force the readback
-    # _stage_input exists to avoid. The round-4 microbenchmark measured
-    # the per-member-staged grouped program at ~2x the latency of a
-    # single allreduce of the same payload below 128 KB — per-member
-    # device_put + N-ary dispatch, exactly the cost pre-packing
-    # amortizes (MICROBENCH.json, docs/tensor-fusion.md).
+    # _stage_input exists to avoid. Staging each member on its own
+    # costs a device_put per member and an N-ary dispatch, which is what
+    # pre-packing amortizes (docs/tensor-fusion.md; not measured on the
+    # chip).
     #
     # The PLAN (scales, member sizes, pack-vs-separate routing, program
     # signature) depends only on the group's metadata, which is identical
@@ -1497,8 +1495,7 @@ def _resolve_op(average, op) -> ReduceOp:
 #
 # A collective verb called with JAX tracers is already inside a compiled
 # program — routing it through the dispatcher would stage tracers to the
-# host (an error) and pay the eager plane's round trip, which
-# MICROBENCH.json measures at 2-11x an in-jit reduce. Instead the verb
+# host (an error) and pay the eager plane's round trip. Instead the verb
 # lowers AT TRACE TIME to the XLA collective over the mapped axes in
 # scope (shard_map/pmap): zero dispatcher hops, zero host staging, and
 # no consistency exchange — every device runs the same compiled SPMD

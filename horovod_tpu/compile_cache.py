@@ -9,7 +9,7 @@ directory next to the package — never a temp name, a pid or a timestamp.
 
 Every entry point that compiles calls :func:`ensure_compile_cache` before
 its first compile: ``hvd.init()``, ``ParamsLifecycle`` (both serving
-engines), ``bench.py``'s worker and ``chip_smoke.py``. The same call
+engines) and ``chip_smoke.py``. The same call
 starts the program's own count of what jax builds
 (``hvd_tpu_compile_total``, ``hvd_tpu_compile_seconds_total``,
 ``hvd_tpu_compile_cache_misses_total``), so an operator sees a recompile

@@ -22,8 +22,8 @@ never a partial grant) and :meth:`BlockAllocator.free` rejects
 double-frees and foreign ids — a leak or a tangle fails the test that
 caused it, instead of surfacing as silent cache corruption under load.
 ``hvd_tpu_gen_kv_blocks_in_use`` tracks the live block count;
-:attr:`BlockAllocator.peak_in_use` is the high-water mark the
-microbench compares against a dense reservation.
+:attr:`BlockAllocator.peak_in_use` is the high-water mark, to set
+against what a dense reservation would hold.
 
 **Automatic prefix caching** (``HVD_TPU_GEN_PREFIX_CACHE``, default
 on) adds SGLang/vLLM-style block reuse on top. Every *full* block can
@@ -182,8 +182,8 @@ class BlockAllocator:
     def in_use(self) -> int:
         """Blocks referenced by at least one live sequence. Cached-free
         blocks are *not* in use — the leak checks throughout the tests
-        and microbench rely on this returning 0 once every sequence has
-        retired, cache or no cache."""
+        and the benchmark rely on this returning 0 once every sequence
+        has retired, cache or no cache."""
         with self._lock:
             return len(self._ref)
 
@@ -567,9 +567,8 @@ def build_program(model):
     The scheduler's hot path no longer runs this program — it drives
     :func:`build_prefill_program` / :func:`build_decode_program`, which
     sample on device and never ship logits to the host. This one stays
-    as the reference surface: the bit-parity tests pin the sampling
-    programs' greedy tokens against its host-side ``argmax``, and the
-    microbench's static baseline drives it directly.
+    as the reference surface: the parity tests pin the sampling
+    programs' greedy tokens against its host-side ``argmax``.
     """
     import jax
 

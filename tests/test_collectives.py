@@ -263,8 +263,8 @@ def test_injit_ppermute_ring(hvd_world, mesh8):
 
 def test_jax_array_inputs_stay_on_device(hvd_world):
     """allreduce/allgather/broadcast accept jax arrays without a host
-    round trip (_stage_input keeps fully-addressable jax arrays as-is;
-    the r4 microbench exists to catch staging waste)."""
+    round trip (_stage_input keeps fully-addressable jax arrays
+    as-is)."""
     import jax.numpy as jnp
     from horovod_tpu import collectives as _c
 

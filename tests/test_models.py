@@ -112,24 +112,6 @@ def test_peak_flops_table_rejects_an_unknown_tpu():
         peak_flops_per_chip("TPU v9 mystery")
 
 
-def test_benchmark_scanned_stage(hvd_world):
-    """The scanned k-step program (one XLA call per timed iteration)
-    produces a valid measurement and shares the rig with plain stages."""
-    from horovod_tpu.benchmark import synthetic_resnet50_ladder
-    stages = [
-        dict(batch_per_chip=2, num_warmup_batches=1,
-             num_batches_per_iter=2, num_iters=1),
-        dict(batch_per_chip=2, num_warmup_batches=1,
-             num_batches_per_iter=3, num_iters=2, scanned=True),
-    ]
-    results = list(synthetic_resnet50_ladder(
-        stages, image_size=32, model_name="resnet18"))
-    assert all(err is None for _, err in results), results
-    for r, _ in results:
-        assert r.images_per_sec_per_chip > 0
-        assert r.batch_per_chip == 2
-
-
 def test_space_to_depth_stem_matches_conv_stem():
     """The space_to_depth stem must be EXACTLY the 7x7/s2 conv stem's
     math (zero-padded kernel regrouping) — same params, same outputs.

@@ -34,7 +34,6 @@ ALLOWLIST = {
 
 #: prefix families exempt wholesale (self-contained harness contracts)
 ALLOW_PREFIXES = (
-    "HVD_TPU_BENCH_",       # bench.py harness, not a runtime subsystem
     "HVD_TPU_FAULT_SPEC_",  # (reserved)
 )
 
