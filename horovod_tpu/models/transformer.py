@@ -23,6 +23,16 @@ build's parallelism strategies (TP/SP/PP/EP/ring attention — SURVEY.md §2.3,
 * flax ``nn.with_logical_partitioning`` names every parameter axis
   ('embed', 'heads', 'kv', 'mlp', 'vocab'); horovod_tpu.parallel maps those
   logical names onto mesh axes (dp/fsdp/tp/sp) — the pjit idiom.
+* the training path names its activations' axes the same way
+  (:func:`_constrain`: 'act_batch', 'act_seq', 'act_embed', 'act_heads',
+  'act_kv', 'act_mlp', 'act_vocab' on the residual stream, q/k/v, the MLP
+  hidden and the logits). Under a mesh and its rules
+  (``parallel.train.make_transformer_train_step``) the names say where
+  each activation lives, so parameters sharded over 'fsdp' are gathered
+  for use and every chip computes its own rows of the batch; with no
+  mesh or rules in scope (every serving program, every one-chip caller;
+  the MLP block is the paged path's too) they are the identity and
+  leave nothing in the lowered program.
 * causal attention runs through :func:`attention_fn` injection so context
   parallelism (ring attention over 'sp' via ppermute) and Pallas
   flash-attention kernels plug in without touching the model.
@@ -169,6 +179,12 @@ jax.tree_util.register_dataclass(
     meta_fields=[])
 
 
+def _constrain(x, *names):
+    """Say where activation ``x`` lives, one logical name an axis. The
+    identity unless a mesh and flax logical-axis rules are in scope."""
+    return nn.with_logical_constraint(x, names)
+
+
 def _default_attention(q, k, v, mask, dtype):
     """Plain softmax attention: (B, S, H, D) inputs, causal mask applied.
     Softmax in fp32 (TPU recipe: keep reductions out of bf16)."""
@@ -228,6 +244,8 @@ class Attention(nn.Module):
         k = jnp.einsum("bse,ehd->bshd", x, wk.astype(dt))
         v = jnp.einsum("bse,ehd->bshd", x, wv.astype(dt))
         if layer_cache is None:
+            q, k, v = (_constrain(a, "act_batch", "act_seq", "act_heads",
+                                  "act_kv") for a in (q, k, v))
             attn = cfg.attention_fn or _default_attention
             out = attn(q, k, v, mask, dt)
             return jnp.einsum("bshd,hde->bse", out, wo.astype(dt))
@@ -292,8 +310,13 @@ class MlpBlock(nn.Module):
             (hidden, cfg.d_model), jnp.float32)
         dt = cfg.dtype
         h = jnp.einsum("bse,em->bsm", x, wi.astype(dt))
+        h = _constrain(h, "act_batch", "act_seq", "act_mlp")
         h = nn.gelu(h)
         return jnp.einsum("bsm,me->bse", h, wo.astype(dt))
+
+
+#: the residual stream (batch, sequence, width)
+_RESIDUAL = ("act_batch", "act_seq", "act_embed")
 
 
 class DecoderLayer(nn.Module):
@@ -305,9 +328,11 @@ class DecoderLayer(nn.Module):
         ln = lambda name: nn.LayerNorm(  # noqa: E731
             dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
         if layer_cache is None:
+            x = _constrain(x, *_RESIDUAL)
             x = x + Attention(cfg, name="attn")(ln("ln1")(x), mask)
+            x = _constrain(x, *_RESIDUAL)
             x = x + MlpBlock(cfg, name="mlp")(ln("ln2")(x))
-            return x
+            return _constrain(x, *_RESIDUAL)
         attn_out, kv = Attention(cfg, name="attn")(
             ln("ln1")(x), mask, layer_cache=layer_cache)
         x = x + attn_out
@@ -331,6 +356,7 @@ class Transformer(nn.Module):
         if cache is None:
             x = emb.astype(cfg.dtype)[tokens] \
                 + pos.astype(cfg.dtype)[None, :S]
+            x = _constrain(x, *_RESIDUAL)
             mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
         else:
             # incremental: S == chunk length C; absolute positions come
@@ -375,7 +401,7 @@ class Transformer(nn.Module):
             logits = jnp.einsum("bse,ve->bsv", x.astype(jnp.float32),
                                 emb.astype(jnp.float32))
         if cache is None:
-            return logits
+            return _constrain(logits, "act_batch", "act_seq", "act_vocab")
         if logits_at is not None:
             return logits[:, 0], dataclasses.replace(
                 cache, pools=(k_pool, v_pool))

@@ -20,7 +20,8 @@ framework supplies the full set as first-class, mesh-native components:
 """
 
 from .mesh_utils import (MeshConfig, make_training_mesh,  # noqa: F401
-                         TRANSFORMER_RULES, fsdp_sharded_leaves,
+                         ACTIVATION_RULES, TRANSFORMER_RULES,
+                         collective_census, fsdp_sharded_leaves,
                          require_axes)
 from .hierarchical import hierarchical_allreduce, hierarchical_pmean  # noqa: F401
 from .ring_attention import (  # noqa: F401

@@ -10,6 +10,7 @@ ride DCN — so put tp/sp (latency-critical, per-layer) innermost and dp
 """
 
 import dataclasses
+import re
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -242,6 +243,24 @@ TRANSFORMER_RULES: Tuple[Tuple[str, Optional[str]], ...] = (
 )
 
 
+# Where the training path's activations live (models/transformer.py names
+# them with nn.with_logical_constraint). The batch divides over dp AND fsdp:
+# that is what makes 'fsdp' ZeRO-3 — a parameter sharded over it is gathered
+# for use, its gradient reduce-scattered, and every chip computes only its
+# own rows. The activations' width is never sharded: left unnamed, the
+# partitioner would carry the parameters' 'embed' -> 'fsdp' into every
+# contraction and all-reduce whole-batch partial sums instead.
+ACTIVATION_RULES: Tuple[Tuple[str, object], ...] = (
+    ("act_batch", ("dp", "fsdp")),
+    ("act_seq", "sp"),
+    ("act_heads", "tp"),
+    ("act_mlp", "tp"),
+    ("act_vocab", "tp"),
+    ("act_embed", None),
+    ("act_kv", None),
+)
+
+
 def require_axes(mesh, *axis_names: str):
     """Fail fast when an axis name is not on ``mesh``.
 
@@ -261,8 +280,9 @@ def require_axes(mesh, *axis_names: str):
 
 
 def batch_spec():
-    """PartitionSpec for a (batch, ...) input: batch shards over dp and fsdp
-    (fsdp acts as extra data parallelism for the forward pass)."""
+    """PartitionSpec for a (batch, ...) input: batch shards over dp and
+    fsdp. Each fsdp member computes its own rows end to end, forward and
+    backward, on parameters gathered for use (ACTIVATION_RULES)."""
     from jax.sharding import PartitionSpec as P
     return P(("dp", "fsdp"))
 
@@ -293,3 +313,135 @@ def param_shardings(mesh, abstract_variables, rules=TRANSFORMER_RULES):
     return jax.tree_util.tree_map(
         lambda spec: NamedSharding(mesh, spec), mesh_specs,
         is_leaf=lambda x: isinstance(x, P))
+
+
+# -- what a compiled step communicates ----------------------------------------
+
+COLLECTIVE_KINDS = ("all-gather", "all-reduce", "reduce-scatter",
+                    "all-to-all", "collective-permute")
+
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
+                "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
+                "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8}
+_HLO_ARRAY = re.compile(r"\b([a-z][a-z0-9]*)\[([0-9,]*)\]")
+_HLO_COLLECTIVE = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.-]+ = (.*?) (" + "|".join(COLLECTIVE_KINDS)
+    + r")(-start|-done)?\(")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.-]+) \(.*\) -> (.*) \{$")
+_HLO_CHANNEL = re.compile(r"channel_id=(\d+)")
+
+
+@dataclasses.dataclass(frozen=True)
+class Collective:
+    """One collective of a compiled program: its kind, the arrays it
+    hands back as ``(dtype, shape)``, their bytes (on one device), and
+    how many ``batch x sequence`` rows its widest array spans (0: none,
+    or the census was not told the token shape)."""
+    kind: str
+    arrays: Tuple[Tuple[str, Tuple[int, ...]], ...]
+    bytes: int
+    rows: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class CollectiveCensus:
+    """:func:`collective_census`'s answer: the program's collectives in
+    the order it prints them."""
+    collectives: Tuple[Collective, ...]
+
+    @property
+    def by_kind(self) -> Dict[str, Dict[str, int]]:
+        """Every kind of COLLECTIVE_KINDS -> ``{"count", "bytes"}``."""
+        out = {k: {"count": 0, "bytes": 0} for k in COLLECTIVE_KINDS}
+        for c in self.collectives:
+            out[c.kind]["count"] += 1
+            out[c.kind]["bytes"] += c.bytes
+        return out
+
+    @property
+    def batch_seq(self) -> Tuple[Collective, ...]:
+        """The collectives whose ``rows`` is not 0."""
+        return tuple(c for c in self.collectives if c.rows)
+
+    def shapes(self, kind: str) -> set:
+        """The distinct array shapes the collectives of ``kind`` return."""
+        return {shape for c in self.collectives if c.kind == kind
+                for _, shape in c.arrays}
+
+
+def _hlo_arrays(type_text: str):
+    return tuple(
+        (dtype, tuple(int(d) for d in dims.split(",") if d))
+        for dtype, dims in _HLO_ARRAY.findall(type_text)
+        if dtype in _DTYPE_BYTES)
+
+
+def _batch_seq_rows(shape, batches, seqs) -> int:
+    """The most ``batch x sequence`` rows ``shape`` can be read to span:
+    a batch extent with a sequence extent after it, or both merged into
+    one axis."""
+    rows = [b * s for i, b in enumerate(shape) if b in batches
+            for s in shape[i + 1:] if s in seqs]
+    rows += [d for d in shape for b in batches for s in seqs if d == b * s]
+    return max(rows, default=0)
+
+
+def collective_census(compiled_or_text, tokens_shape=None,
+                      mesh=None) -> CollectiveCensus:
+    """Count the collectives of a compiled program by kind.
+
+    A step's layout is fixed when it is compiled, so its collectives say
+    what the mesh axes do in it: an 'fsdp' axis that is ZeRO-3 shows
+    all-gathers (and reduce-scatters or all-reduces) at parameter shapes
+    and nothing at the activations' ``batch x sequence x width``.
+    ``compiled_or_text``: a ``jax.stages.Compiled`` or its ``as_text()``.
+    Reads the optimized HLO as the CPU and the TPU compilers print it: a
+    collective an asynchronous pair carries is counted at its ``-done``;
+    the operations one TPU collective is split into share a
+    ``channel_id`` and count once; the TPU's ``all-reduce-scatter``
+    fusion is a reduce-scatter of the fusion's result. Bytes are those
+    of the arrays handed back on one device, unpadded.
+
+    ``tokens_shape`` ``(batch, sequence)`` with ``mesh``: also give each
+    collective its ``rows``, reading a shape's axes as the batch (whole,
+    or divided over dp, fsdp or both) and the sequence (whole or over
+    sp). A step whose chips each compute their own rows has no
+    collective wider than a token (the targets, the loss's scalars)
+    above ``batch x sequence / (dp * fsdp * sp)`` rows.
+    """
+    text = (compiled_or_text if isinstance(compiled_or_text, str)
+            else compiled_or_text.as_text())
+    batches, seqs = (), ()
+    if tokens_shape is not None and mesh is not None:
+        batch, seq = tokens_shape
+        dp, fsdp, sp = (mesh.shape.get(a, 1) for a in ("dp", "fsdp", "sp"))
+        batches = {batch, batch // dp, batch // fsdp, batch // (dp * fsdp)}
+        seqs = {seq, seq // sp}
+    found: List[Collective] = []
+    seen = set()
+    scatter_result = None       # inside an all-reduce-scatter fusion
+    for line in text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            scatter_result = (head.group(2) if head.group(1).startswith(
+                "all-reduce-scatter") else None)
+            continue
+        m = _HLO_COLLECTIVE.match(line)
+        if not m or m.group(3) == "-start":
+            continue
+        result, kind = m.group(1), m.group(2)
+        channel = _HLO_CHANNEL.search(line)
+        if channel and m.group(3) is None:
+            if (kind, channel.group(1)) in seen:
+                continue
+            seen.add((kind, channel.group(1)))
+        if scatter_result is not None and kind == "all-reduce":
+            result, kind = scatter_result, "reduce-scatter"
+        arrays = _hlo_arrays(result)
+        found.append(Collective(
+            kind, arrays,
+            sum(_DTYPE_BYTES[d] * int(np.prod(s, dtype=np.int64))
+                for d, s in arrays),
+            max((_batch_seq_rows(s, batches, seqs) for _, s in arrays),
+                default=0)))
+    return CollectiveCensus(tuple(found))

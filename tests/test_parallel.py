@@ -6,6 +6,8 @@ test/test_torch.py) — full attention for ring/Ulysses, sequential layer
 application for the pipeline, dense routing for MoE.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -82,6 +84,168 @@ def test_fsdp_shards_params_and_matches_dp():
         losses[name] = float(loss)
 
     np.testing.assert_allclose(losses["fsdp"], losses["dp"], rtol=1e-5)
+
+
+# -- the step's layout: each chip its own rows, parameters gathered ----------
+
+# Toy widths no batch or sequence extent equals (8, 4, 2 rows; 16, 8
+# positions; their products), so that collective_census reads a shape's
+# axes as rows only where they are rows.
+_CENSUS_B, _CENSUS_S = 8, 16
+
+
+def _census_cfg():
+    from horovod_tpu.models import TransformerConfig
+    return TransformerConfig(vocab_size=50, num_layers=2, d_model=24,
+                             num_heads=2, head_dim=12, max_seq_len=_CENSUS_S,
+                             dtype=jnp.float32, remat=True)
+
+
+def _census_batch():
+    rng = np.random.RandomState(11)
+    return (rng.randint(0, 50, (_CENSUS_B, _CENSUS_S)).astype(np.int32),
+            rng.randint(0, 50, (_CENSUS_B, _CENSUS_S)).astype(np.int32))
+
+
+@pytest.fixture(scope="module")
+def census_dp_loss():
+    from horovod_tpu.parallel.train import make_transformer_train_step
+    bundle = make_transformer_train_step(
+        _census_cfg(), par.make_training_mesh(par.MeshConfig(dp=-1)))
+    tok, tgt = (jax.device_put(a, bundle.batch_sharding)
+                for a in _census_batch())
+    return float(bundle.step(bundle.params, bundle.opt_state, tok, tgt)[2])
+
+
+@pytest.mark.parametrize("axes", [
+    dict(dp=1, fsdp=4), dict(dp=2, fsdp=2), dict(dp=2, fsdp=2, tp=2),
+    dict(dp=2, sp=2, tp=2)], ids=lambda a: "x".join(
+        f"{k}{v}" for k, v in a.items()))
+def test_step_census_rows_stay_home_and_params_are_gathered(
+        axes, census_dp_loss):
+    """What the compiled step communicates, on four meshes: no collective
+    wider than a token spans more rows than one device's share of the
+    batch (the MLP hidden, q/k/v and the logits of the whole batch are
+    summed or gathered nowhere); with fsdp > 1 the parameters are
+    all-gathered at their own shapes and, without tp or sp, nothing
+    activation-sized moves but the embedding lookup's re-layout (at most
+    two all-to-alls of one device's rows); and the loss is the dp-only
+    loss, the layout changing no mathematics."""
+    from horovod_tpu.parallel.train import make_transformer_train_step
+
+    mc = par.MeshConfig(**axes)
+    n = int(np.prod(list(axes.values())))
+    mesh = par.make_training_mesh(mc, jax.devices()[:n])
+    cfg = _census_cfg()
+    bundle = make_transformer_train_step(cfg, mesh, interpret=True)
+    tok, tgt = (jax.device_put(a, bundle.batch_sharding)
+                for a in _census_batch())
+    compiled = bundle.step.lower(bundle.params, bundle.opt_state, tok,
+                                 tgt).compile()
+    census = par.collective_census(compiled, (_CENSUS_B, _CENSUS_S), mesh)
+
+    own_rows = _CENSUS_B * _CENSUS_S // (mc.dp * mc.fsdp * mc.sp)
+    token_bytes = 4 * _CENSUS_B * _CENSUS_S   # targets, per-token scalars
+    wide = [c for c in census.batch_seq if c.bytes > token_bytes]
+    assert all(c.rows <= own_rows for c in wide), [
+        c for c in wide if c.rows > own_rows]
+    if mc.tp == mc.sp == 1:
+        assert all(c.kind == "all-to-all" for c in wide) and len(wide) <= 2, \
+            wide
+    if mc.fsdp > 1:
+        H, D, E = cfg.num_heads // mc.tp, cfg.head_dim, cfg.d_model
+        M = E * cfg.mlp_ratio // mc.tp
+        gathered = census.shapes("all-gather")
+        assert {(E, H, D), (H, D, E), (E, M), (M, E)} <= gathered, gathered
+        assert census.by_kind["all-gather"]["count"] >= 6 * cfg.num_layers
+
+    loss = float(compiled(bundle.params, bundle.opt_state, tok, tgt)[2])
+    np.testing.assert_allclose(loss, census_dp_loss, rtol=1e-5)
+
+
+@pytest.mark.parametrize("program", ["train_path", "prefill", "decode"])
+def test_activation_names_leave_nothing_off_a_mesh(program, monkeypatch):
+    """The model's activation names resolve against a mesh and rules in
+    scope. With neither (every serving program, every one-chip caller of
+    ``Transformer.apply``) they are the identity: the lowered program
+    holds no sharding operation, and the training path's text is the
+    text the same call gives with the naming helper stubbed out."""
+    from horovod_tpu.models import Transformer, transformer
+    from horovod_tpu.models.transformer import PagedCache
+    from horovod_tpu.serving.generation import kv_cache
+    from horovod_tpu.serving.generation.scheduler import DECODE_WIDTH
+
+    cfg = dataclasses.replace(_census_cfg(), num_heads=3)  # its own traces
+    model = Transformer(cfg)
+    toks = jnp.zeros((2, _CENSUS_S), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), toks))
+
+    def lowered_text():
+        if program == "train_path":
+            return jax.jit(model.apply).lower(params, toks).as_text()
+        lanes, width = (1, 8) if program == "prefill" else (2, DECODE_WIDTH)
+        cache = PagedCache(
+            kv_cache.make_pools(cfg, num_blocks=9, block_size=4),
+            jnp.zeros((lanes, 4), jnp.int32), jnp.zeros((lanes,), jnp.int32),
+            jnp.ones((lanes,), jnp.int32))
+        return kv_cache.build_program(model).lower(
+            params, cache, jnp.zeros((lanes, width), jnp.int32)).as_text()
+
+    text = lowered_text()
+    assert "sharding" not in text.lower()
+    if program == "train_path":
+        monkeypatch.setattr(transformer, "_constrain", lambda x, *names: x)
+        assert lowered_text() == text
+
+
+_TPU_HLO = """\
+HloModule jit__step, is_scheduled=true
+
+%all-reduce-scatter.1 (input.1: bf16[1600,6400]) -> bf16[448,6400] {
+  %input.1 = bf16[1600,6400]{1,0} parameter(0)
+  %pad.7 = bf16[1792,6400]{1,0} pad(%input.1, %c), padding=0_192x0_0
+  %all-reduce.38 = bf16[1792,6400]{1,0:T(8,128)(2,1)} all-reduce(%pad.7), channel_id=103, replica_groups={{0,1,2,3}}, to_apply=%add
+  ROOT %slice = bf16[448,6400]{1,0} dynamic-slice(%all-reduce.38, %i, %z)
+}
+
+%fused_gather.a (p: bf16[400,25,64]) -> bf16[1600,25,64] {
+  %all-gather.186 = bf16[1600,25,64]{2,1,0:T(8,128)(2,1)} all-gather(%p), channel_id=22, replica_groups=[1,4]<=[4], dimensions={0}
+}
+
+%fused_gather.b (p: bf16[400,25,64]) -> bf16[1600,25,64] {
+  %all-gather.188 = bf16[1600,25,64]{2,1,0:T(8,128)(2,1)S(1)} all-gather(%q), channel_id=22, replica_groups=[1,4]<=[4], dimensions={0}
+}
+
+ENTRY %main (a: bf16[400,25,64]) -> f32[] {
+  %fusion.6 = bf16[448,6400]{1,0} fusion(%all-gather.186), kind=kCustom, calls=%all-reduce-scatter.1
+  %all-reduce.44 = (f32[1600]{0}, /*index=1*/bf16[25,64,1600]{2,1,0}, f32[]) all-reduce(%x, %y, %z), channel_id=7, to_apply=%add
+  %collective-permute-start.2 = (bf16[144,6400]{1,0}, bf16[144,6400]{1,0}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%s), channel_id=108, source_target_pairs={{0,1}}
+  %collective-permute-done.2 = bf16[144,6400]{1,0} collective-permute-done(%collective-permute-start.2)
+  %all-to-all = bf16[4,2,1024,400]{2,3,1,0} all-to-all(%copy.164), channel_id=5, dimensions={0}
+  %all-reduce.9 = bf16[8,1024,6400]{2,1,0} all-reduce(%h), channel_id=9, to_apply=%add
+}
+"""
+
+
+def test_collective_census_reads_tpu_hlo_text():
+    """The TPU compiler's spelling: one collective split over several
+    operations with one channel_id, the all-reduce-scatter fusion, an
+    asynchronous pair, a tuple result with index comments."""
+    mesh = par.make_training_mesh(par.MeshConfig(dp=1, fsdp=4),
+                                  jax.devices()[:4])
+    census = par.collective_census(_TPU_HLO, (8, 1024), mesh)
+    assert {k: v["count"] for k, v in census.by_kind.items()} == {
+        "all-gather": 1, "all-reduce": 2, "reduce-scatter": 1,
+        "all-to-all": 1, "collective-permute": 1}
+    assert census.by_kind["reduce-scatter"]["bytes"] == 448 * 6400 * 2
+    assert census.by_kind["all-gather"]["bytes"] == 1600 * 25 * 64 * 2
+    assert census.by_kind["all-reduce"]["bytes"] == (
+        1600 * 4 + 25 * 64 * 1600 * 2 + 4 + 8 * 1024 * 6400 * 2)
+    assert census.by_kind["collective-permute"]["bytes"] == 144 * 6400 * 2
+    assert [(c.kind, c.rows) for c in census.batch_seq] == [
+        ("all-to-all", 2 * 1024), ("all-reduce", 8 * 1024)]
+    # without the token shape nothing is read as rows
+    assert par.collective_census(_TPU_HLO).batch_seq == ()
 
 
 # -- hierarchical allreduce --------------------------------------------------
