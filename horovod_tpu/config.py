@@ -653,6 +653,19 @@ GEN_BEAMS = _register(
          "the refcounted prefix-cache substrate and copy-on-extend "
          "only the divergent tail block; num_beams=1 output is "
          "bit-identical to plain greedy decode.")
+GEN_STATE_SNAPSHOTS = _register(
+    "GEN_STATE_SNAPSHOTS", 48, int,
+    help="Snapshot slots of the generation plane, for a served model "
+         "that declares per-sequence state (CacheSpec.state: a linear "
+         "attention's recurrent matrices); a model that declares none "
+         "allocates nothing. A snapshot is a copy of one sequence's "
+         "state taken at a prefill-chunk boundary and owned by the "
+         "prefix-cache block whose last token it follows; a prefix hit "
+         "reaches no deeper than the deepest block that owns one. "
+         "Memory is this times the model's state bytes a sequence, "
+         "allocated once at engine start; when all are taken the least "
+         "recently used is evicted "
+         "(hvd_tpu_gen_state_snapshots_total{event=\"evicted\"}).")
 
 # -- Serving fleet (no reference equivalent — serving/fleet/: the router
 #    tier over N replica servers: health-aware balancing, per-tenant

@@ -10,6 +10,7 @@ that tile onto the 128x128 MXU, and no data-dependent Python control flow.
 from .resnet import ResNet, ResNet18, ResNet34, ResNet50, ResNet101, ResNet152  # noqa: F401
 from .mlp import MLP  # noqa: F401
 from .longcat_flash import LongcatFlash, LongcatFlashConfig  # noqa: F401
+from .olmo_hybrid import OlmoHybrid, OlmoHybridConfig  # noqa: F401
 from .transformer import (CacheSpec, PagedCache, Transformer,  # noqa: F401
                           TransformerConfig)
 from .vgg import VGG, VGG11, VGG13, VGG16, VGG19  # noqa: F401

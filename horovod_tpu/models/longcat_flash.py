@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.moe import STATS_COLLECTION, held_experts_mlp, route_topk
+from .blocks import GatedMlp, normal_init as _init, rms_norm, untied_head
 from .transformer import CacheSpec, PagedCache
 
 Dtype = Any
@@ -111,18 +112,6 @@ class LongcatFlashConfig:
         """Whether a chunk of ``chunk`` queries takes the expanded form."""
         r, dn, dv = self.kv_lora_rank, self.qk_nope_head_dim, self.v_head_dim
         return chunk * (2 * r - dn - dv) > r * (dn + dv)
-
-
-def _init(std=0.02):
-    return nn.initializers.normal(std)
-
-
-def rms_norm(x, weight, eps):
-    """Normalise in float32, weigh in the activations' dtype."""
-    x32 = x.astype(jnp.float32)
-    x32 = x32 * jax.lax.rsqrt(
-        jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
-    return x32.astype(x.dtype) * weight.astype(x.dtype)
 
 
 def rotary_interleaved(x, positions, theta):
@@ -277,21 +266,6 @@ def _masked_softmax(scores, mask):
     return jax.nn.softmax(scores, axis=-1)
 
 
-class GatedMlp(nn.Module):
-    """``W_down(silu(W_gate x) * (W_up x))``."""
-
-    cfg: LongcatFlashConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        D, F, pd = cfg.hidden_size, cfg.ffn_hidden_size, cfg.param_dtype
-        gate = self.param("gate_proj", _init(), (D, F), pd)
-        up = self.param("up_proj", _init(), (D, F), pd)
-        down = self.param("down_proj", _init(), (F, D), pd)
-        return (jax.nn.silu(x @ gate) * (x @ up)) @ down
-
-
 class ExpertLayer(nn.Module):
     """The router over every output of the model and this chip's held
     experts. Returns ``M(u)`` as far as this chip computes it."""
@@ -356,10 +330,11 @@ class DoubleLayer(nn.Module):
         h = h + attend(0, norm("input_layernorm_0", h))
         u = norm("post_attention_layernorm_0", h)
         m = ExpertLayer(cfg, name="moe")(u, valid)
-        h = h + GatedMlp(cfg, name="mlp_0")(u)
+        mlp = lambda name: GatedMlp(  # noqa: E731
+            cfg.hidden_size, cfg.ffn_hidden_size, cfg.param_dtype, name=name)
+        h = h + mlp("mlp_0")(u)
         h = h + attend(1, norm("input_layernorm_1", h))
-        h = h + GatedMlp(cfg, name="mlp_1")(
-            norm("post_attention_layernorm_1", h)) + m
+        h = h + mlp("mlp_1")(norm("post_attention_layernorm_1", h)) + m
         return h if layer_cache is None else (h, pool)
 
 
@@ -401,14 +376,9 @@ class LongcatFlash(nn.Module):
         h = rms_norm(h, self.param("norm", nn.initializers.ones,
                                    (cfg.hidden_size,), cfg.param_dtype),
                      cfg.rms_norm_eps)
-        if cache is not None and logits_at is not None:
-            # the caller samples one position a row: project only that
-            h = jnp.take_along_axis(
-                h, logits_at.astype(jnp.int32)[:, None, None], axis=1)
-        with jax.named_scope("head"):
-            # float32 logits from the weights as they lie
-            logits = jnp.einsum("bse,ev->bsv", h, head,
-                                preferred_element_type=jnp.float32)
+        # the caller samples one position a row: project only that
+        logits = untied_head(h, head,
+                             None if cache is None else logits_at)
         if cache is None:
             return logits
         cache = dataclasses.replace(cache, pools=(pool,))

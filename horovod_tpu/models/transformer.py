@@ -2,14 +2,16 @@
 
 Which models this package serves: :class:`Transformer` here is the
 GPT-2 block (multi-head attention, learned positions, LayerNorm, GELU,
-tied head; ``gpt2-xl`` is the published architecture it matches) and
+tied head; ``gpt2-xl`` is the published architecture it matches),
 :mod:`.longcat_flash` is the LongCat-Flash block (latent attention,
-the shortcut-connected double layer, routed and zero-compute experts).
-Both enter :class:`~horovod_tpu.serving.generation.GenerationEngine`
+the shortcut-connected double layer, routed and zero-compute experts)
+and :mod:`.olmo_hybrid` the Olmo-Hybrid block (gated-delta-rule linear
+attention with a per-sequence recurrent state, full attention every
+fourth layer). All enter :class:`~horovod_tpu.serving.generation.GenerationEngine`
 through the same ``apply(params, tokens, cache=PagedCache,
 logits_at=...)`` contract, and each declares the cache it keeps with a
 :class:`CacheSpec` (``cfg.cache_spec()``); :class:`PagedCache` and
-:class:`CacheSpec` live in this file for both. ``models/`` also holds
+:class:`CacheSpec` live in this file for all of them. ``models/`` also holds
 ResNet, Inception, VGG and an MLP, which are trained, not served by
 the generation plane.
 
@@ -132,20 +134,33 @@ class TransformerConfig:
 class CacheSpec:
     """What a served model keeps in the paged cache: its declaration.
 
-    ``planes``: how many attention sublayers keep state (a pool's
-    leading axis). ``rows``: ``(name, width)`` for each array a token
-    leaves behind in one plane: one pool a row, ``width`` values of
-    ``dtype`` at the head of a pool row.
+    **Per-token rows.** ``planes``: how many attention sublayers keep
+    rows (a pool's leading axis). ``rows``: ``(name, width)`` for each
+    array a token leaves behind in one plane: one pool a row, ``width``
+    values of ``dtype`` at the head of a pool row.
+
+    **Per-sequence state.** ``state``: ``(name, planes, shape, dtype)``
+    for each array a *sequence* keeps whatever its length (a linear
+    attention's recurrent matrix, a convolution's window): one pool
+    each, ``(planes, slots, *shape)``, a running sequence's entry at its
+    **state slot**. A model that declares state cannot be served from
+    its per-token rows alone: a prefix of cached blocks is worth nothing
+    without the state the sequence had after the prefix's last token, so
+    the scheduler keeps **snapshots** of it (``docs/serving_models.md``).
+    Empty for a model whose cache is a function of token positions.
+
     :func:`~horovod_tpu.serving.generation.kv_cache.make_pools`,
     ``block_bytes``, ``gather_blocks``/``scatter_blocks``, the disagg
-    wire codec and the five programs read nothing about a model's cache
-    but this; the model's paged forward gets the pools in the order of
-    ``rows`` and hands them back in it.
+    wire codec, the scheduler, the allocator and the five programs read
+    nothing about a model's cache but this; the model's paged forward
+    gets the pools in the order of ``rows``, then of ``state``, and
+    hands them back in it.
     """
 
     planes: int
     rows: Tuple[Tuple[str, int], ...]
     dtype: Dtype
+    state: Tuple[Tuple[str, int, Tuple[int, ...], Dtype], ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,18 +179,27 @@ class PagedCache:
     ``(B,)`` tokens already in each sequence's cache (the chunk starts
     there). ``live``: ``(B,)`` how many of this chunk's ``C`` tokens are
     real; pad tokens (and dead lanes, ``live == 0``) write to the null
-    block. All leaves are arrays, so the dataclass flattens cleanly
-    through ``jax.jit`` argument trees.
+    block. For a model that declares per-sequence state the state pools
+    follow the row pools in ``pools`` and ``slots`` ``(B,)`` int32 names
+    each batch row's state slot; ``slots=None`` says row ``i`` is slot
+    ``i`` (the decode program: its lanes are the slots, so a layer takes
+    its whole plane and nothing is gathered). A state has no null block:
+    the forward itself leaves the state of a dead lane, and of a live
+    one past its ``live`` columns, bit-identical. All leaves are arrays
+    (``None`` has none), so the dataclass flattens cleanly through
+    ``jax.jit`` argument trees.
     """
 
     pools: Tuple[Any, ...]
     block_tables: Any
     lengths: Any
     live: Any
+    slots: Any = None
 
 
 jax.tree_util.register_dataclass(
-    PagedCache, data_fields=["pools", "block_tables", "lengths", "live"],
+    PagedCache,
+    data_fields=["pools", "block_tables", "lengths", "live", "slots"],
     meta_fields=[])
 
 
@@ -218,6 +242,29 @@ def _gathered_attention(q, k_pool, v_pool, layer, block_tables, mask, dtype):
         vc = v_pool[layer, block_tables][..., :H * D].reshape(B, -1, H, D)
     with jax.named_scope("attention"):
         return _default_attention(q, kc, vc, mask, dtype)
+
+
+def write_kv_rows(k_pool, v_pool, layer, block_tables, positions, live,
+                  k, v):
+    """Scatter a chunk's K and V (``(B, C, ...)``, a token's values
+    flattened to its row) into plane ``layer`` of the pools through the
+    block tables; pad tokens (and dead lanes) route to the null block 0.
+    A token's row is its values, zero-padded to the pool's lane-aligned
+    width."""
+    B, C = k.shape[0], k.shape[1]
+    block_size = k_pool.shape[2]
+    blk_idx = positions // block_size                       # (B, C)
+    offsets = positions % block_size                        # (B, C)
+    blocks = jnp.take_along_axis(
+        block_tables, blk_idx.astype(jnp.int32), axis=1)    # (B, C)
+    valid = jnp.arange(C)[None, :] < live[:, None]
+    blocks = jnp.where(valid, blocks, 0)
+    pad = ((0, 0), (0, 0), (0, k_pool.shape[3] - k[0, 0].size))
+    k_pool = k_pool.at[layer, blocks, offsets].set(
+        jnp.pad(k.reshape(B, C, -1), pad))
+    v_pool = v_pool.at[layer, blocks, offsets].set(
+        jnp.pad(v.reshape(B, C, -1), pad))
+    return k_pool, v_pool
 
 
 class Attention(nn.Module):
@@ -265,21 +312,8 @@ class Attention(nn.Module):
         B, C = x.shape[0], x.shape[1]
         block_size = k_pool.shape[2]
         with jax.named_scope("kv_write"):
-            # scatter the chunk's K/V through the block tables; pad
-            # tokens (and dead lanes) route to the null block 0
-            blk_idx = positions // block_size                   # (B, C)
-            offsets = positions % block_size                    # (B, C)
-            blocks = jnp.take_along_axis(
-                block_tables, blk_idx.astype(jnp.int32), axis=1)  # (B, C)
-            valid = jnp.arange(C)[None, :] < live[:, None]
-            blocks = jnp.where(valid, blocks, 0)
-            # a token's row is its H*D values, zero-padded to the
-            # pool's lane-aligned width
-            pad = ((0, 0), (0, 0), (0, k_pool.shape[3] - H * D))
-            k_pool = k_pool.at[layer, blocks, offsets].set(
-                jnp.pad(k.reshape(B, C, H * D), pad))
-            v_pool = v_pool.at[layer, blocks, offsets].set(
-                jnp.pad(v.reshape(B, C, H * D), pad))
+            k_pool, v_pool = write_kv_rows(
+                k_pool, v_pool, layer, block_tables, positions, live, k, v)
         if paged_attention.kernel_applies(cfg.paged_query_rows(C), block_size,
                                           k_pool.shape[3], k_pool.dtype):
             # a few query columns on a TPU: the kernel walks each live

@@ -25,9 +25,16 @@ cache=PagedCache, logits_at=None) -> (logits, PagedCache)``, and a
 and widths, dtype; pools, block bytes, the disagg wire and the programs
 are driven by it) and gives ``max_seq_len`` and ``vocab_size``. A model
 with routed experts also gives ``cfg.held_experts``; its programs then
-return routing counts.
-:class:`~horovod_tpu.models.transformer.Transformer` and
-:class:`~horovod_tpu.models.longcat_flash.LongcatFlash` are the two.
+return routing counts. A model whose declaration names per-sequence
+``state`` gets a state slot a lane and ``HVD_TPU_GEN_STATE_SNAPSHOTS``
+snapshot slots beside the block pool; it is served with the prefix cache
+on like any other, and refused by what cannot carry a state: a
+``spec_mode`` other than off raises here, no beam program is built and
+``num_beams > 1`` is rejected at submit, and the disagg KV transfer
+refuses its cache (:class:`~.kv_cache.PerSequenceStateError`).
+:class:`~horovod_tpu.models.transformer.Transformer`,
+:class:`~horovod_tpu.models.longcat_flash.LongcatFlash` and
+:class:`~horovod_tpu.models.olmo_hybrid.OlmoHybrid` are the three.
 """
 
 from typing import Any, List, Optional, Sequence
@@ -36,7 +43,8 @@ from ... import config as _config
 from ..engine import ParamsLifecycle
 from .kv_cache import (BlockAllocator, build_beam_program,
                        build_decode_program, build_prefill_program,
-                       build_verify_program, make_pools)
+                       build_verify_program, make_pools, make_state_pools,
+                       refuse_state, state_bytes)
 from .scheduler import DECODE_WIDTH, ContinuousBatcher, GenSequence
 from .spec import make_proposer
 
@@ -71,6 +79,9 @@ class GenerationEngine:
       max_beams: widest ``num_beams`` this engine accepts; the beam
         step program is compiled for this top-K. 1 disables beam
         search entirely (None reads ``HVD_TPU_GEN_BEAMS``).
+      state_snapshots: snapshot slots for a model that declares
+        per-sequence state (None reads
+        ``HVD_TPU_GEN_STATE_SNAPSHOTS``); ignored for any other.
       draft_model / draft_params / draft_checkpoint_dir: the small
         draft transformer for ``spec_mode='draft'`` and its params
         plumbing (restored through its own :class:`ParamsLifecycle`).
@@ -99,6 +110,7 @@ class GenerationEngine:
                  spec_mode: Optional[str] = None,
                  spec_tokens: Optional[int] = None,
                  max_beams: Optional[int] = None,
+                 state_snapshots: Optional[int] = None,
                  draft_model=None, draft_params: Any = None,
                  draft_checkpoint_dir: Optional[str] = None,
                  on_step=None, role: Optional[str] = None):
@@ -118,13 +130,32 @@ class GenerationEngine:
             checkpoint_dir=checkpoint_dir, params=params, sharding=sharding,
             step=step, reload_poll_seconds=reload_poll_seconds,
             plane="generation")
-        self.allocator = BlockAllocator(num_blocks, block_size,
-                                        prefix_cache=prefix_cache)
-        pools = make_pools(model.cfg, num_blocks, block_size)
+        spec_off = spec_mode in ("", "off", "0", "false", "none")
+        stateful = bool(model.cfg.cache_spec().state)
+        snapshots, state_slots, snapshot_slots = (), 0, 0
+        if stateful:
+            # a state slot a decode lane, and the snapshot pools; what
+            # cannot carry a state is refused before anything is built
+            if not spec_off:
+                refuse_state(model.cfg, f"spec_mode={spec_mode!r}: "
+                             f"speculative decoding")
+            state_slots = max_seqs = int(
+                cfg.get(_config.GEN_MAX_SEQS)
+                if max_seqs is None else max_seqs)
+            snapshot_slots = int(cfg.get(_config.GEN_STATE_SNAPSHOTS)
+                                 if state_snapshots is None
+                                 else state_snapshots)
+            snapshots = make_state_pools(model.cfg, snapshot_slots + 1)
+            max_beams = 1
+        self.allocator = BlockAllocator(
+            num_blocks, block_size, prefix_cache=prefix_cache,
+            state_slots=state_slots, snapshot_slots=snapshot_slots,
+            state_bytes=state_bytes(model.cfg))
+        pools = make_pools(model.cfg, num_blocks, block_size,
+                           state_slots=state_slots)
         self._proposer = make_proposer(
             spec_mode, draft_model=draft_model, params=draft_params,
-            checkpoint_dir=draft_checkpoint_dir) \
-            if spec_mode not in ("", "off", "0", "false", "none") else None
+            checkpoint_dir=draft_checkpoint_dir) if not spec_off else None
         verify_prog = (build_verify_program(model, spec_tokens)
                        if self._proposer is not None else None)
         beam_prog = (build_beam_program(model, max_beams, DECODE_WIDTH)
@@ -141,7 +172,7 @@ class GenerationEngine:
             verify_program=verify_prog, proposer=self._proposer,
             spec_mode=spec_mode, spec_tokens=spec_tokens,
             beam_program=beam_prog, max_beams=max_beams,
-            on_step=on_step, role=role)
+            snapshots=snapshots, on_step=on_step, role=role)
         self._lifecycle.start_poller()    # last: nothing can fail past here
 
     # -- generation ----------------------------------------------------------
@@ -281,7 +312,9 @@ class GenerationEngine:
     def kv_export(self, hashes: Sequence[str], timeout: float = 30.0):
         """Serve ``POST /v1/kv/fetch``: read the requested blocks'
         contents off the pools (scheduler-thread control op). Returns
-        ``(served_hashes, rows)``, one array for each cache pool."""
+        ``(served_hashes, rows)``, one array for each cache pool.
+        Raises :class:`~.kv_cache.PerSequenceStateError` for a model
+        that declares per-sequence state, as :meth:`kv_import` does."""
         return self.batcher.execute(
             lambda: self.batcher.export_kv_blocks(hashes), timeout=timeout)
 

@@ -42,6 +42,23 @@ a cached chain from its tail and the head prefix stays matchable.
 Sharing is full-block-only — the partial tail block is always private
 to one sequence — so no write ever lands in a shared block and
 cached-prefix decode is bit-identical to cold decode.
+
+**Per-sequence state** (a model whose
+:class:`~horovod_tpu.models.transformer.CacheSpec` declares ``state``: a
+linear attention's recurrent matrices, a convolution's window) lives
+beside the blocks in the same manager. Every running sequence holds one
+**state slot** (:meth:`BlockAllocator.take_state_slot`; the decode
+program's lane ``i`` is slot ``i``). A cached block is worth nothing to
+such a model without the state its sequence had after the block's last
+token, so the allocator also keeps **snapshot slots**: a snapshot is
+claimed for an indexed block (:meth:`BlockAllocator.claim_snapshot`; the
+scheduler copies the state into it at a prefill-chunk boundary), is
+evicted with that block or, when every slot is taken, least recently
+used first, and :meth:`BlockAllocator.match` attaches no prefix deeper
+than the deepest block that owns one. Snapshot 0 is the **null
+snapshot**: zeros, never written, what a sequence with no hit starts
+from. A model that declares no state has neither kind of slot and the
+allocator behaves as it always did.
 """
 
 import collections
@@ -80,6 +97,57 @@ _M_EVICTIONS = _metrics.counter(
     "allocation (free list empty, LRU cached block recycled). A high "
     "rate relative to hits means the pool is too small for the working "
     "set of shared prefixes.")
+_M_STATE_SLOTS = _metrics.gauge(
+    "hvd_tpu_gen_state_slots_in_use",
+    "Per-sequence state slots held by running sequences (a model that "
+    "declares CacheSpec.state: one slot a running sequence, as many "
+    "slots as decode lanes). Zero for a model that declares none.")
+_M_SNAPSHOT_SLOTS = _metrics.gauge(
+    "hvd_tpu_gen_state_snapshot_slots_in_use",
+    "State snapshots currently held (of HVD_TPU_GEN_STATE_SNAPSHOTS), "
+    "each owned by one indexed prefix-cache block. Pinned at the pool "
+    "size with a rising evicted count means hits are being cut short "
+    "for want of snapshot slots, not of KV blocks.")
+_M_SNAPSHOTS = _metrics.counter(
+    "hvd_tpu_gen_state_snapshots_total",
+    "State snapshots by event: 'taken' (a sequence's state copied into "
+    "a snapshot slot at a prefill-chunk boundary), 'restored' (a "
+    "snapshot copied into an admitted sequence's state slot on a prefix "
+    "hit; a sequence with no hit starts from the null snapshot and "
+    "counts nothing) and 'evicted' (a snapshot dropped with its block, "
+    "or least recently used first when every slot was taken).",
+    labels=("event",))
+_M_SNAPSHOT_BYTES = _metrics.counter(
+    "hvd_tpu_gen_state_snapshot_bytes_total",
+    "Bytes of per-sequence state copied into ('taken') or out of "
+    "('restored') a snapshot slot, or dropped with one ('evicted').",
+    labels=("event",))
+
+
+def count_snapshots(event: str, n: int, state_bytes: int) -> None:
+    """``n`` snapshots ``event`` (taken | restored | evicted)."""
+    if n:
+        _M_SNAPSHOTS.labels(event=event).inc(n)
+        _M_SNAPSHOT_BYTES.labels(event=event).inc(n * state_bytes)
+
+
+class PerSequenceStateError(ValueError):
+    """A path that cannot carry **per-sequence recurrent state**
+    (``CacheSpec.state``) was asked to serve a model that declares it:
+    the speculative verify step rolls back K/V rows only, a beam fork
+    shares and copies blocks only, and the disagg wire ships blocks
+    only. Each refuses instead of serving tokens from a wrong state."""
+
+
+def refuse_state(model_cfg, what: str) -> None:
+    """Raise :class:`PerSequenceStateError` if ``model_cfg`` declares
+    per-sequence state; ``what`` names the path that cannot carry it."""
+    state = getattr(model_cfg.cache_spec(), "state", ())
+    if state:
+        raise PerSequenceStateError(
+            f"{what} cannot carry per-sequence recurrent state, and this "
+            f"model's cache declares it (CacheSpec.state: "
+            f"{', '.join(name for name, *_ in state)})")
 
 
 def chain_hash(parent: Optional[str], tokens: Sequence[int]) -> str:
@@ -113,7 +181,9 @@ class BlockAllocator:
     """
 
     def __init__(self, num_blocks: int, block_size: int,
-                 prefix_cache: Optional[bool] = None):
+                 prefix_cache: Optional[bool] = None,
+                 state_slots: int = 0, snapshot_slots: int = 0,
+                 state_bytes: int = 0):
         if num_blocks < 2:
             raise ValueError(
                 f"HVD_TPU_GEN_NUM_BLOCKS={num_blocks}: need at least 2 "
@@ -151,6 +221,22 @@ class BlockAllocator:
         #: registered after one
         self.cache_gen = 0
         self.peak_in_use = 0
+        # -- per-sequence state (module docstring): all empty, and every
+        # path below unchanged, for a model that declares none
+        #: slots of the state pools, one a running sequence
+        self.state_slots = int(state_slots)
+        #: usable snapshot slots (slot 0, the null snapshot, excluded)
+        self.snapshot_slots = int(snapshot_slots) if state_slots else 0
+        #: bytes of one sequence's state, for the byte counters
+        self.state_bytes = int(state_bytes)
+        self._state_free = list(range(self.state_slots - 1, -1, -1))
+        self._state_held: set = set()
+        self._snap_free = list(range(self.snapshot_slots, 0, -1))
+        # content hash of the owning block -> snapshot slot, least
+        # recently taken or restored first
+        self._snap_of: "collections.OrderedDict[str, int]" = \
+            collections.OrderedDict()
+        self.snapshot_peak = 0
 
     def blocks_for(self, tokens: int) -> int:
         """Blocks needed to hold ``tokens`` cache slots."""
@@ -224,7 +310,7 @@ class BlockAllocator:
         scheduler ever considers preempting a running sequence."""
         if n <= 0:
             return []
-        evicted = 0
+        evicted = dropped = 0
         with self._lock:
             if n > len(self._free_list) + len(self._cached):
                 raise BlocksExhaustedError(
@@ -241,6 +327,8 @@ class BlockAllocator:
                     h = self._hash_of.pop(b)
                     if self._index.get(h) == b:
                         del self._index[h]
+                        # a snapshot goes with the block that owns it
+                        dropped += self._drop_snapshot_locked(h)
                     self._remote.discard(b)
                     evicted += 1
                 self._ref[b] = 1
@@ -249,8 +337,12 @@ class BlockAllocator:
             if in_use > self.peak_in_use:
                 self.peak_in_use = in_use
             stats = self._stats_locked()
+            held = len(self._snap_of)
         if evicted:
             _M_EVICTIONS.inc(evicted)
+        if dropped:
+            count_snapshots("evicted", dropped, self.state_bytes)
+            _M_SNAPSHOT_SLOTS.set(held)
         self._publish(in_use, stats)
         return out
 
@@ -347,30 +439,38 @@ class BlockAllocator:
         the cached-free pool (they would leave it on a real
         :meth:`match`, so admissibility math must not double-count them
         as evictable)."""
-        matched = cached = 0
         with self._lock:
-            for h in hashes:
-                b = self._index.get(h)
-                if b is None:
-                    break
-                matched += 1
-                if b in self._cached:
-                    cached += 1
-        return matched, cached
+            found = self._indexed_prefix_locked(hashes)
+            return len(found), sum(1 for b in found if b in self._cached)
+
+    def _indexed_prefix_locked(self, hashes: Sequence[str]) -> List[int]:
+        """Blocks of the longest indexed prefix of ``hashes`` that a
+        sequence may attach: for a model with per-sequence state, cut
+        back to the deepest block that owns a snapshot (none: nothing),
+        since the blocks past it cannot be continued from."""
+        found: List[int] = []
+        deepest = 0
+        for h in hashes:
+            b = self._index.get(h)
+            if b is None:
+                break
+            found.append(b)
+            if h in self._snap_of:
+                deepest = len(found)
+        return found[:deepest] if self.state_slots else found
 
     def match(self, hashes: Sequence[str]) -> List[int]:
         """Attach the longest indexed prefix of ``hashes``: cached-free
         blocks revive with refcount 1, live blocks bump their refcount
         (becoming shared). Returns the matched block ids in chain
-        order; the caller owns one reference to each."""
+        order; the caller owns one reference to each. For a model with
+        per-sequence state the prefix ends at the deepest block that
+        owns a snapshot (:meth:`snapshot_of` the last block returned)."""
         out: List[int] = []
         if not self.prefix_cache:
             return out
         with self._lock:
-            for h in hashes:
-                b = self._index.get(h)
-                if b is None:
-                    break
+            for b in self._indexed_prefix_locked(hashes):
                 if b in self._cached:
                     del self._cached[b]
                     self._ref[b] = 1
@@ -428,9 +528,116 @@ class BlockAllocator:
             self._hash_of.clear()
             self._remote.clear()
             self.cache_gen += 1
+            dropped = len(self._snap_of)
+            self._snap_free.extend(self._snap_of.values())
+            self._snap_of.clear()
             in_use = len(self._ref)
             stats = self._stats_locked()
+        if dropped:
+            count_snapshots("evicted", dropped, self.state_bytes)
+            _M_SNAPSHOT_SLOTS.set(0)
         self._publish(in_use, stats)
+
+    # -- per-sequence state ----------------------------------------------
+
+    def take_state_slot(self) -> int:
+        """A state slot for a sequence entering the running set. There
+        are as many as decode lanes, so a sequence that found a lane
+        finds a slot; the caller zeroes or restores it."""
+        with self._lock:
+            if not self._state_free:
+                raise RuntimeError(
+                    f"no free state slot (of {self.state_slots}): more "
+                    f"running sequences than decode lanes")
+            slot = self._state_free.pop()
+            self._state_held.add(slot)
+            held = len(self._state_held)
+        _M_STATE_SLOTS.set(held)
+        return slot
+
+    def release_state_slot(self, slot: int) -> None:
+        with self._lock:
+            if slot not in self._state_held:
+                raise ValueError(f"release of state slot {slot}, not held")
+            self._state_held.discard(slot)
+            self._state_free.append(slot)
+            held = len(self._state_held)
+        _M_STATE_SLOTS.set(held)
+
+    @property
+    def state_slots_in_use(self) -> int:
+        with self._lock:
+            return len(self._state_held)
+
+    @property
+    def snapshot_slots_in_use(self) -> int:
+        with self._lock:
+            return len(self._snap_of)
+
+    def snapshots_orphaned(self) -> int:
+        """Snapshot slots that no indexed block owns, or that are
+        neither held nor free: 0 unless the accounting leaked."""
+        with self._lock:
+            lost = self.snapshot_slots - len(self._snap_of) \
+                - len(self._snap_free)
+            return lost + sum(1 for h in self._snap_of
+                              if h not in self._index)
+
+    def claim_snapshot(self, block: int) -> Optional[int]:
+        """A snapshot slot owned by indexed block ``block``, for the
+        state its sequence has after the block's last token; the caller
+        copies the state in. None where the block is not the indexed
+        holder of its hash (the prefix cache is off, or an equal block
+        was registered first) or already owns one. With every slot
+        taken the least recently used snapshot is evicted."""
+        evicted = 0
+        with self._lock:
+            h = self._hash_of.get(block)
+            if not self.snapshot_slots or h is None \
+                    or self._index.get(h) != block:
+                return None
+            if h in self._snap_of:
+                self._snap_of.move_to_end(h)
+                return None
+            if not self._snap_free:
+                _, freed = self._snap_of.popitem(last=False)
+                self._snap_free.append(freed)
+                evicted = 1
+            slot = self._snap_of[h] = self._snap_free.pop()
+            held = len(self._snap_of)
+            self.snapshot_peak = max(self.snapshot_peak, held)
+        count_snapshots("evicted", evicted, self.state_bytes)
+        count_snapshots("taken", 1, self.state_bytes)
+        _M_SNAPSHOT_SLOTS.set(held)
+        return slot
+
+    def snapshot_of(self, block: int) -> int:
+        """The snapshot slot ``block`` owns (0, the null snapshot, for
+        none), marked recently used: what an admission restores after
+        :meth:`match` returned ``block`` last."""
+        with self._lock:
+            h = self._hash_of.get(block)
+            if h is None or h not in self._snap_of:
+                return 0
+            self._snap_of.move_to_end(h)
+            return self._snap_of[h]
+
+    def drop_snapshot(self, block: int) -> bool:
+        """Evict the snapshot ``block`` owns, keeping the block."""
+        with self._lock:
+            h = self._hash_of.get(block)
+            dropped = self._drop_snapshot_locked(h) if h else 0
+            held = len(self._snap_of)
+        count_snapshots("evicted", dropped, self.state_bytes)
+        _M_SNAPSHOT_SLOTS.set(held)
+        return bool(dropped)
+
+    def _drop_snapshot_locked(self, content_hash: str) -> int:
+        slot = self._snap_of.pop(content_hash, None)
+        if slot is None:
+            return 0
+        self._snap_free.append(slot)
+        return 1
 
 
 #: a TPU's lane count: the minor axis of an array is tiled in 128s
@@ -442,7 +649,8 @@ def _row(width: int) -> int:
     return -(-int(width) // _LANES) * _LANES
 
 
-def make_pools(model_cfg, num_blocks: int, block_size: int):
+def make_pools(model_cfg, num_blocks: int, block_size: int,
+               state_slots: int = 0):
     """Zeroed cache pools for ``model_cfg``, read off its declaration
     (``model_cfg.cache_spec()``, a
     :class:`~horovod_tpu.models.transformer.CacheSpec`): a tuple with
@@ -461,17 +669,63 @@ def make_pools(model_cfg, num_blocks: int, block_size: int):
     128 — and the paged programs then relayout every slab they scatter
     into or gather from, or the whole pool. A row that is a multiple of
     128 pads nothing, row-major wins for any ``N``, and a token's row is
-    contiguous; the device would have padded 1600 to 1664 itself."""
+    contiguous; the device would have padded 1600 to 1664 itself.
+
+    For a model that declares per-sequence state the tuple goes on with
+    one ``(planes, state_slots, *shape)`` array for each declared state,
+    in its own dtype (:func:`make_state_pools`): the programs thread,
+    donate and update them in place like the row pools."""
     import jax.numpy as jnp
     spec = model_cfg.cache_spec()
     return tuple(
         jnp.zeros((spec.planes, num_blocks, block_size, _row(width)),
-                  spec.dtype) for _, width in spec.rows)
+                  spec.dtype) for _, width in spec.rows) \
+        + make_state_pools(model_cfg, state_slots)
+
+
+def make_state_pools(model_cfg, slots: int):
+    """Zeroed pools of ``slots`` entries for each per-sequence state
+    ``model_cfg`` declares: the tail of :func:`make_pools` (a running
+    sequence's slot) and, with ``snapshots + 1`` entries, the snapshot
+    pools (entry 0 the null snapshot). ``()`` for a model without."""
+    import jax.numpy as jnp
+    spec = model_cfg.cache_spec()
+    if spec.state and slots < 1:
+        raise ValueError(
+            f"the model's cache declares per-sequence state "
+            f"({', '.join(name for name, *_ in spec.state)}): its pools "
+            f"need at least one slot, got {slots}")
+    return tuple(jnp.zeros((planes, slots) + tuple(shape), dtype)
+                 for _, planes, shape, dtype in spec.state)
+
+
+def state_bytes(model_cfg) -> int:
+    """Bytes of per-sequence state one sequence holds (one snapshot)."""
+    import jax.numpy as jnp
+    return sum(planes * math.prod(shape) * jnp.dtype(dtype).itemsize
+               for _, planes, shape, dtype in model_cfg.cache_spec().state)
+
+
+@functools.lru_cache(maxsize=None)
+def build_state_copy_program():
+    """``(dst_pools, src_pools, dst_slot, src_slot) -> dst_pools``:
+    entry ``src_slot`` of every state pool in ``src_pools`` copied over
+    entry ``dst_slot`` of its partner in ``dst_pools`` (donated, so in
+    place). One function serves both directions: the state pools and
+    the snapshot pools differ only in how many entries they have."""
+    import jax
+
+    def _copy_state(dst, src, dst_slot, src_slot):
+        return tuple(d.at[:, dst_slot].set(s[:, src_slot])
+                     for d, s in zip(dst, src))
+
+    return jax.jit(_copy_state, donate_argnums=(0,))
 
 
 def block_bytes(model_cfg, block_size: int) -> int:
     """Bytes of cache one block holds: every declared row, padded as
-    :func:`make_pools` allocates it, in every plane."""
+    :func:`make_pools` allocates it, in every plane (per-sequence state
+    is no part of a block: :func:`state_bytes`)."""
     import jax.numpy as jnp
     spec = model_cfg.cache_spec()
     return (spec.planes * block_size * jnp.dtype(spec.dtype).itemsize
@@ -818,6 +1072,8 @@ def build_verify_program(model, spec_tokens: int):
     import jax
     import jax.numpy as jnp
 
+    refuse_state(model.cfg, "the speculative verify step (it rolls back "
+                 "rejected K/V rows, not a state)")
     S = int(spec_tokens)
     if S < 1:
         raise ValueError(f"spec_tokens={spec_tokens}: must be >= 1")
@@ -935,6 +1191,8 @@ def build_beam_program(model, beam_k: int, decode_width: int = 2):
     import jax
     import jax.numpy as jnp
 
+    refuse_state(model.cfg, "beam search (a fork shares and copies "
+                 "blocks, not a state)")
     K = int(beam_k)
     if K < 1:
         raise ValueError(f"beam_k={beam_k}: must be >= 1")

@@ -109,6 +109,25 @@ full prefix blocks through the refcounted allocator
 tail block at divergence. ``num_beams=1`` is bit-identical to plain
 greedy decode.
 
+**Per-sequence state** (a model whose cache declaration names
+``state``: a linear attention's recurrent matrices) rides the same
+paths; the scheduler reads only the allocator's ``state_slots``. A
+sequence takes a **state slot** when it is admitted and gives it back
+when it retires or is preempted; the decode batch puts a sequence on the
+lane of its slot, so the decode program updates each state pool plane
+in place and gathers nothing. Admission *restores* the slot: from the
+snapshot owned by the last block the prefix match attached, else from
+the null snapshot (zeros), which is also what a preempted sequence
+resumes from before its recompute. After a prefill chunk that ends on a
+block boundary the state is *snapshotted* into a slot claimed for that
+block (:meth:`~.kv_cache.BlockAllocator.claim_snapshot`), so a later
+prompt that shares the prefix can start there; the match never reaches
+past a snapshot. Both copies are dispatched on the device in program
+order (``gen.state.snapshot`` / ``gen.state.restore`` loop spans) and
+wait for nothing. Speculative verify, beam search and the disagg KV
+wire cannot carry a state and refuse such a model
+(:class:`~.kv_cache.PerSequenceStateError`).
+
 Fault sites: ``serving.prefill`` (each prefill chunk — an ``error``
 fails only that sequence), ``serving.decode`` (each decode-step
 enqueue — an ``error`` fails only the sequences in that step's batch;
@@ -142,8 +161,10 @@ from ...ops import paged_attention
 from ...parallel.moe import STATS_FIELDS
 from ..batcher import DeadlineExceededError, QueueFullError
 from .kv_cache import (BlockAllocator, BlocksExhaustedError, DecodeState,
-                       SampleParams, chain_hash, gather_blocks,
-                       reads_live_blocks, scatter_blocks)
+                       PerSequenceStateError, SampleParams,
+                       build_state_copy_program, chain_hash,
+                       count_snapshots, gather_blocks, reads_live_blocks,
+                       scatter_blocks)
 
 _M_TOKENS = _metrics.counter(
     "hvd_tpu_gen_tokens_total",
@@ -416,7 +437,8 @@ class GenSequence:
                  "done_event", "arrived_at", "temperature", "top_k",
                  "top_p", "seed", "key", "sample_offset", "prefix_hashes",
                  "block_hashes", "cache_gen", "request_id", "trace",
-                 "num_beams", "first_dispatch_at", "first_token_at")
+                 "num_beams", "first_dispatch_at", "first_token_at",
+                 "state_slot")
 
     def __init__(self, seq_id: int, prompt: List[int], max_tokens: int,
                  eos_id: Optional[int], deadline_s: float,
@@ -462,6 +484,9 @@ class GenSequence:
         self.generated: List[int] = []
         self.logprobs: List[float] = []
         self.blocks: List[int] = []
+        #: this sequence's slot of the per-sequence state pools while it
+        #: runs (None: not running, or the model declares no state)
+        self.state_slot: Optional[int] = None
         #: tokens whose K/V must be in the cache before decoding resumes
         #: (the prompt; after a preemption, prompt + regenerated history)
         self.prefill_tokens: List[int] = list(prompt)
@@ -552,7 +577,8 @@ class ContinuousBatcher:
                  spec_mode: Optional[str] = None,
                  spec_tokens: Optional[int] = None,
                  beam_program: Optional[Callable] = None,
-                 max_beams: Optional[int] = None):
+                 max_beams: Optional[int] = None,
+                 snapshots=()):
         cfg = _config.live_config()
         #: disaggregated operating mode (HVD_TPU_DISAGG_ROLE):
         #: 'colocated' runs prefill + decode as always; 'prefill'
@@ -574,6 +600,23 @@ class ContinuousBatcher:
         #: execution leaves self._pools pointing at deleted buffers
         self._pool_shapes = [(tuple(p.shape), p.dtype) for p in pools]
         self._alloc = allocator
+        #: per-sequence state, read off the allocator alone: the state
+        #: pools are the last ``len(snapshots)`` of ``pools``, and
+        #: ``snapshots`` their partners with one entry a snapshot slot
+        #: (entry 0 the null snapshot)
+        self._stateful = bool(getattr(allocator, "state_slots", 0))
+        self._snaps = tuple(snapshots)
+        self._snap_shapes = [(tuple(p.shape), p.dtype) for p in snapshots]
+        #: how many of ``pools`` are row pools; the state pools follow
+        self._row_pools = len(self._pools) - len(self._snaps)
+        if self._stateful:
+            # (a verify or beam program cannot exist for such a model:
+            # their builders refuse it)
+            if not self._snaps:
+                raise ValueError(
+                    "the allocator has state slots but no snapshot pools "
+                    "were given (kv_cache.make_state_pools)")
+            self._copy_state = build_state_copy_program()
         self._prefix_cache = bool(getattr(allocator, "prefix_cache", False))
         #: identity of the params object the last device call used —
         #: a hot-swap means cached K/V no longer matches what a cold
@@ -582,6 +625,10 @@ class ContinuousBatcher:
         self.max_seq_len = int(max_seq_len)
         self.max_seqs = int(cfg.get(_config.GEN_MAX_SEQS)
                             if max_seqs is None else max_seqs)
+        if self._stateful and allocator.state_slots != self.max_seqs:
+            raise ValueError(
+                f"{allocator.state_slots} state slots for {self.max_seqs} "
+                f"decode lanes: a sequence decodes on the lane of its slot")
         self.prefill_chunk = int(cfg.get(_config.GEN_PREFILL_CHUNK)
                                  if prefill_chunk is None else prefill_chunk)
         depth = int(cfg.get(_config.GEN_QUEUE_DEPTH)
@@ -733,6 +780,11 @@ class ContinuousBatcher:
         if num_beams < 1:
             raise ValueError(f"num_beams={num_beams}: must be >= 1")
         if num_beams > 1:
+            if self._stateful:
+                raise PerSequenceStateError(
+                    f"num_beams={num_beams}: beam search cannot carry "
+                    f"per-sequence recurrent state (a fork shares and "
+                    f"copies KV blocks, not a state)")
             if self._beam_prog is None:
                 raise ValueError(
                     "beam search is disabled on this engine (no beam "
@@ -899,6 +951,14 @@ class ContinuousBatcher:
         every replica with the same block size)."""
         return self._prefix_hashes_for([int(t) for t in tokens])
 
+    def _refuse_transfer(self) -> None:
+        if self._stateful:
+            raise PerSequenceStateError(
+                "the disagg KV transfer cannot carry per-sequence "
+                "recurrent state: the wire ships KV blocks, and a block "
+                "of this model's cache is worth nothing without the "
+                "state that follows it")
+
     def export_kv_blocks(self, hashes: Sequence[str]):
         """Scheduler-thread body of ``POST /v1/kv/fetch`` (call via
         :meth:`execute`): pin the longest indexed prefix of ``hashes``,
@@ -907,6 +967,7 @@ class ContinuousBatcher:
         prefix of the request (the
         tail may have evicted since the manifest was minted; the decode
         side re-prefills whatever is missing)."""
+        self._refuse_transfer()
         hashes = [str(h) for h in hashes]
         if not self._prefix_cache:
             return [], None
@@ -934,6 +995,7 @@ class ContinuousBatcher:
         blocks are registered ``remote=True`` and parked cached —
         a double-import of the same hash dedups via first-registration-
         wins and the duplicate simply recycles."""
+        self._refuse_transfer()
         hashes = [str(h) for h in hashes]
         if not self._prefix_cache or not hashes:
             return 0, 0
@@ -1209,6 +1271,11 @@ class ContinuousBatcher:
                     s.prefilled - transfer)
                 _M_PREFIX_MISS.inc(len(s.prefill_tokens) - s.prefilled)
             s.cache_gen = self._alloc.cache_gen
+            if self._stateful:
+                s.state_slot = self._alloc.take_state_slot()
+                self._restore_state(
+                    s.state_slot, self._alloc.snapshot_of(s.blocks[-1])
+                    if s.blocks else 0)
             self._running.append(s)
 
     # -- prefill -------------------------------------------------------------
@@ -1261,7 +1328,9 @@ class ContinuousBatcher:
             args = (
                 PagedCache(self._pools, jnp.asarray(row),
                            jnp.asarray(np.asarray([s.prefilled], np.int32)),
-                           jnp.asarray(np.asarray([live], np.int32))),
+                           jnp.asarray(np.asarray([live], np.int32)),
+                           None if s.state_slot is None else jnp.asarray(
+                               np.asarray([s.state_slot], np.int32))),
                 jnp.asarray(tokens),
                 SampleParams(
                     # the resume path discards the sampled token (it was
@@ -1311,6 +1380,8 @@ class ContinuousBatcher:
         s.prefilled += live
         s.cache_len = s.prefilled
         self._register_full_blocks(s)
+        if self._stateful:
+            self._snapshot_state(s)
         if s.prefilled == total and self.role == "prefill":
             # prefill-only operating mode: the prompt's KV is resident
             # and its full blocks are registered — retiring now parks
@@ -1409,6 +1480,43 @@ class ContinuousBatcher:
         if len(self._moe_pending) >= 64:
             self._readback(())      # no token is read in this mode
         return tok, logp
+
+    # -- per-sequence state --------------------------------------------------
+
+    def _restore_state(self, slot: int, snapshot: int) -> None:
+        """State slot ``slot`` := snapshot ``snapshot`` (0: the null
+        snapshot, zeros). Dispatched after every program already
+        dispatched, so a step in flight that still counts the slot's
+        lane live is overwritten, not raced."""
+        with self._spans.span("gen.state.restore", slot=slot,
+                              snapshot=snapshot):
+            n = self._row_pools
+            self._pools = self._pools[:n] + tuple(self._copy_state(
+                self._pools[n:], self._snaps, slot, snapshot))
+        if snapshot:
+            count_snapshots("restored", 1, self._alloc.state_bytes)
+
+    def _snapshot_state(self, s: GenSequence) -> None:
+        """After a prefill chunk of ``s``: where the chunk ended on a
+        block boundary and that block is indexed, copy the state into a
+        snapshot the block owns."""
+        bs = self._alloc.block_size
+        j = s.prefilled // bs - 1
+        if s.prefilled % bs or not 0 <= j < len(s.block_hashes):
+            return
+        slot = self._alloc.claim_snapshot(s.blocks[j])
+        if slot is None:
+            return
+        with self._spans.span("gen.state.snapshot", slot=slot, seq=s.id,
+                              tokens=s.prefilled):
+            self._snaps = tuple(self._copy_state(
+                self._snaps, self._pools[self._row_pools:], slot,
+                s.state_slot))
+
+    def _release_state(self, s: GenSequence) -> None:
+        if s.state_slot is not None:
+            self._alloc.release_state_slot(s.state_slot)
+            s.state_slot = None
 
     # -- decode --------------------------------------------------------------
 
@@ -1541,7 +1649,14 @@ class ContinuousBatcher:
     def _build_dstate(self, batch: List[GenSequence]) -> None:
         self._flush_inflight()      # invariant, not just optimization
         B = self.max_seqs
-        self._lanes = list(batch) + [None] * (B - len(batch))
+        if self._stateful:
+            # a sequence decodes on the lane of its state slot: the
+            # program then takes each state plane whole, in place
+            self._lanes = [None] * B
+            for s in batch:
+                self._lanes[s.state_slot] = s
+        else:
+            self._lanes = list(batch) + [None] * (B - len(batch))
         tokens = np.zeros((B,), np.int32)
         lengths = np.zeros((B,), np.int32)
         live = np.zeros((B,), np.int32)
@@ -1552,7 +1667,9 @@ class ContinuousBatcher:
         top_p = np.ones((B,), np.float32)
         key = np.zeros((B, 2), np.uint32)
         emitted = np.zeros((B,), np.int32)
-        for i, s in enumerate(batch):
+        for i, s in enumerate(self._lanes):
+            if s is None:
+                continue
             tokens[i] = s.next_input
             lengths[i] = s.cache_len
             live[i] = 1
@@ -2015,6 +2132,8 @@ class ContinuousBatcher:
             self._deliver_error(s, err)
         self._pools = tuple(jnp.zeros(shape, dtype)
                             for shape, dtype in self._pool_shapes)
+        self._snaps = tuple(jnp.zeros(shape, dtype)
+                            for shape, dtype in self._snap_shapes)
         self._moe_pending.clear()
         # the rebuilt pools are zeroed: every indexed block's contents
         # are gone, so the content index must go with them
@@ -2058,6 +2177,8 @@ class ContinuousBatcher:
         self._alloc.free(s.blocks)
         s.blocks = []
         s.block_hashes = []
+        # recompute: the readmission restores a snapshot or zeros
+        self._release_state(s)
         if s.state == "decode" and s.generated:
             # cache must be rebuilt up to (but not including) the newest
             # generated token — it is the resumed decode's input
@@ -2125,6 +2246,7 @@ class ContinuousBatcher:
         if s.blocks:
             self._alloc.free(s.blocks)
             s.blocks = []
+        self._release_state(s)
         if s in self._running:
             self._running.remove(s)
         for i, x in enumerate(self._lanes):
