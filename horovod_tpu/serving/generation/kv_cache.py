@@ -898,6 +898,32 @@ def _register_pytrees():
 _register_pytrees()
 
 
+#: ``hvd_tpu_gen_sample_steps_total``'s ``cut`` by (a sampling lane has
+#: ``top_k > 0``) + 2 x (a sampling lane has ``top_p < 1``)
+SAMPLE_CUTS = ("none", "top_k", "top_p", "both")
+
+
+def _cuts_asked(xp, temperature, top_k, top_p):
+    """``(a lane samples, a sampling lane has top_k > 0, one has
+    top_p < 1)`` of one batch: what decides which of
+    :func:`sample_tokens`' branches run. One predicate for the program
+    (``xp`` is ``jax.numpy``) and for the scheduler's counter
+    (``numpy``, :func:`sample_cut`)."""
+    sampled = ~(temperature <= 0.0)
+    return (xp.any(sampled), xp.any(sampled & (top_k > 0)),
+            xp.any(sampled & (top_p < 1.0)))
+
+
+def sample_cut(temperature, top_k, top_p) -> str:
+    """The ``cut`` label of ``hvd_tpu_gen_sample_steps_total`` for a
+    dispatch whose :class:`SampleParams` hold these host vectors:
+    ``greedy`` (the program takes ``argmax`` and nothing else), or which
+    threshold searches it runs (:data:`SAMPLE_CUTS`)."""
+    sampled, k, p = _cuts_asked(np, np.asarray(temperature),
+                                np.asarray(top_k), np.asarray(top_p))
+    return SAMPLE_CUTS[int(k) + 2 * int(p)] if sampled else "greedy"
+
+
 def sample_tokens(logits, sample: SampleParams):
     """Select one token per row from ``(B, vocab)`` logits, on device.
 
@@ -907,6 +933,11 @@ def sample_tokens(logits, sample: SampleParams):
     ``(token (B,) int32, logprob (B,) float32)`` — the logprob is under
     the *unmodified* distribution, so observability reads the model's
     actual confidence, not the truncated one.
+
+    Neither restriction sorts: each threshold is found by a bitwise
+    search over the value's bit pattern that counts (top-k) or sums
+    (top-p) what lies at or above a candidate, and a restriction no
+    sampling lane asked for is skipped (:func:`_cuts_asked`).
     """
     import jax
 
@@ -914,43 +945,117 @@ def sample_tokens(logits, sample: SampleParams):
         return _sample_tokens(logits, sample)
 
 
+def _ordered_int(x):
+    """float32 <-> an int32 whose signed order is the floats' order
+    (``-0.0`` just below ``+0.0``). Its own inverse."""
+    import jax
+    import jax.numpy as jnp
+
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits < 0, bits ^ 0x7fffffff, bits)
+
+
+def _largest_accepted(accept, rows: int):
+    """Per row the largest int32 ``t`` with ``accept(t)``, for an
+    ``accept`` that holds at ``INT32_MIN`` and, once false, stays false
+    as ``t`` grows: ``t`` is built a bit a pass from the sign down, each
+    pass keeping the bit if ``accept`` still holds with it set."""
+    import jax
+    import jax.numpy as jnp
+
+    def _pass(i, t):
+        # int32 wraps: INT32_MIN + 2**31 is 0, the sign bit's candidate
+        cand = t + jnp.left_shift(jnp.int32(1), 31 - i)
+        return jnp.where(accept(cand), cand, t)
+
+    return jax.lax.fori_loop(
+        0, 32, _pass,
+        jnp.full((rows,), jnp.iinfo(jnp.int32).min, jnp.int32))
+
+
+def _kth_largest(x, k):
+    """Row ``i``'s ``k[i]``-th largest value of ``x`` ``(B, V)``
+    float32, exactly (ties are one value): the largest ``t`` that at
+    least ``k`` values reach."""
+    import jax
+    import jax.numpy as jnp
+
+    key = _ordered_int(x)
+    t = _largest_accepted(
+        lambda t: jnp.sum(key >= t[:, None], axis=-1, dtype=jnp.int32) >= k,
+        x.shape[0])
+    return jax.lax.bitcast_convert_type(_ordered_int(t), jnp.float32)
+
+
+def _nucleus_threshold(probs, top_p):
+    """Row ``i``'s largest ``t`` with ``sum(probs[probs >= t]) >=
+    top_p[i]``, never above the row's maximum: ``probs >= t`` is the
+    smallest set of the most probable tokens that holds ``top_p`` mass
+    (whole ties), and the top token is always in it. Probabilities are
+    non-negative, so their bit patterns order as integers."""
+    import jax
+    import jax.numpy as jnp
+
+    bits = jax.lax.bitcast_convert_type(probs, jnp.int32)
+    t = _largest_accepted(
+        lambda t: jnp.sum(jnp.where(bits >= t[:, None], probs, 0.0),
+                          axis=-1) >= top_p,
+        probs.shape[0])
+    return jax.lax.bitcast_convert_type(
+        jnp.minimum(t, jnp.max(bits, axis=-1)), jnp.float32)
+
+
+def _restrict(scaled, sample: SampleParams, top_k_asked, top_p_asked):
+    """``scaled`` ``(B, V)`` with ``-inf`` where a row's top-k then
+    top-p restriction drops the token; a restriction nobody asked for
+    is not computed (it would drop nothing)."""
+    import jax
+    import jax.numpy as jnp
+
+    vocab = scaled.shape[-1]
+
+    def _top_k(x):
+        # threshold at the k-th highest score (k <= 0 keeps all)
+        k_eff = jnp.clip(jnp.where(sample.top_k <= 0, vocab,
+                                   sample.top_k), 1, vocab)
+        kth = _kth_largest(x, k_eff)
+        return jnp.where(x < kth[:, None], -jnp.inf, x)
+
+    def _top_p(x):
+        # the smallest set of the most probable survivors holding >= p
+        # mass; the top token always stays
+        probs = jax.nn.softmax(x, axis=-1)
+        thresh = _nucleus_threshold(probs, sample.top_p)
+        return jnp.where(
+            (sample.top_p < 1.0)[:, None] & (probs < thresh[:, None]),
+            -jnp.inf, x)
+
+    limited = jax.lax.cond(top_k_asked, _top_k, lambda x: x, scaled)
+    return jax.lax.cond(top_p_asked, _top_p, lambda x: x, limited)
+
+
 def _sample_tokens(logits, sample: SampleParams):
     import jax
     import jax.numpy as jnp
 
-    vocab = logits.shape[-1]
     greedy = sample.temperature <= 0.0
     argmax_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampled, top_k_asked, top_p_asked = _cuts_asked(
+        jnp, sample.temperature, sample.top_k, sample.top_p)
 
     def _draw(_):
-        scaled = logits / jnp.where(greedy, 1.0,
-                                    sample.temperature)[:, None]
-        # top-k: threshold at the k-th highest score (k <= 0 keeps all)
-        srt = jnp.sort(scaled, axis=-1)[:, ::-1]
-        k_eff = jnp.clip(jnp.where(sample.top_k <= 0, vocab,
-                                   sample.top_k), 1, vocab)
-        kth = jnp.take_along_axis(srt, (k_eff - 1)[:, None], axis=-1)
-        limited = jnp.where(scaled < kth, -jnp.inf, scaled)
-        # top-p: smallest prefix of the sorted survivors holding >= p
-        # mass; the exclusive cumsum always keeps the top token
-        probs = jax.nn.softmax(limited, axis=-1)
-        psort = jnp.sort(probs, axis=-1)[:, ::-1]
-        csum = jnp.cumsum(psort, axis=-1)
-        keep = jnp.sum((csum - psort) < sample.top_p[:, None], axis=-1)
-        thresh = jnp.take_along_axis(
-            psort, (jnp.maximum(keep, 1) - 1)[:, None], axis=-1)
-        limited = jnp.where(
-            (sample.top_p < 1.0)[:, None] & (probs < thresh),
-            -jnp.inf, limited)
+        # float32 whatever the caller's dtypes: the searches read its bits
+        scaled = (logits / jnp.where(greedy, 1.0, sample.temperature)
+                  [:, None]).astype(jnp.float32)
+        limited = _restrict(scaled, sample, top_k_asked, top_p_asked)
         keys = jax.vmap(jax.random.fold_in)(sample.key, sample.emitted)
         drawn = jax.vmap(jax.random.categorical)(keys, limited)
         return drawn.astype(jnp.int32)
 
-    # all-greedy batches skip the two vocab sorts + categorical draw at
+    # all-greedy batches skip the restriction + categorical draw at
     # runtime; sampled lanes run the identical ops either way, so the
     # per-seed draw is unchanged by the branch
-    drawn = jax.lax.cond(jnp.any(~greedy), _draw,
-                         lambda _: argmax_tok, operand=None)
+    drawn = jax.lax.cond(sampled, _draw, lambda _: argmax_tok, operand=None)
     token = jnp.where(greedy, argmax_tok, drawn)
     logprob = jnp.take_along_axis(
         jax.nn.log_softmax(logits, axis=-1), token[:, None], axis=-1)[:, 0]

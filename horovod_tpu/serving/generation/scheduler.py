@@ -164,7 +164,7 @@ from .kv_cache import (BlockAllocator, BlocksExhaustedError, DecodeState,
                        PerSequenceStateError, SampleParams,
                        build_state_copy_program, chain_hash,
                        count_snapshots, gather_blocks, reads_live_blocks,
-                       scatter_blocks)
+                       sample_cut, scatter_blocks)
 
 _M_TOKENS = _metrics.counter(
     "hvd_tpu_gen_tokens_total",
@@ -221,6 +221,19 @@ _M_PAGED_BLOCKS = _metrics.counter(
     "read/table is the share of the table a step pays for: 1 means the "
     "kernel did not engage (not a TPU, or shapes it does not take).",
     labels=("kind",))
+_M_SAMPLE_STEPS = _metrics.counter(
+    "hvd_tpu_gen_sample_steps_total",
+    "Dispatches of a prefill chunk, a decode step or a verify step by "
+    "the branch their sampling epilogue takes, from the temperature, "
+    "top_k and top_p the scheduler put in the step's SampleParams (the "
+    "predicate the program itself evaluates, kv_cache.sample_cut): "
+    "cut='greedy' no lane samples (argmax only); 'none' a lane samples "
+    "and none asks for a restriction (the draw over the whole "
+    "vocabulary, no threshold search); 'top_k' / 'top_p' / 'both' the "
+    "threshold searches the step ran, because a sampling lane had "
+    "top_k > 0 / top_p < 1. A step runs a search for all its lanes if "
+    "one lane asks for it.",
+    labels=("cut",))
 _M_RUNNING = _metrics.gauge(
     "hvd_tpu_gen_running_seqs",
     "Sequences currently in the running set (prefilling or decoding). "
@@ -681,6 +694,8 @@ class ContinuousBatcher:
         #: _epoch (bumped on membership changes the device hasn't seen)
         #: outruns _state_epoch.
         self._dstate: Optional[DecodeState] = None
+        #: kv_cache.sample_cut of the sampling parameters in _dstate
+        self._dstate_cut = "greedy"
         self._dtables = None
         self._tables_dirty = True
         self._lanes: List[Optional[GenSequence]] = [None] * self.max_seqs
@@ -1325,6 +1340,12 @@ class ContinuousBatcher:
             tokens[0, :live] = chunk
             row = np.zeros((1, self.max_blocks), np.int32)
             row[0, :len(s.blocks)] = s.blocks
+            # the resume path discards the sampled token (it was emitted
+            # before the eviction): force the cheap greedy branch
+            temp = np.asarray([0.0 if s.resume_decode else s.temperature],
+                              np.float32)
+            top_k = np.asarray([s.top_k], np.int32)
+            top_p = np.asarray([s.top_p], np.float32)
             args = (
                 PagedCache(self._pools, jnp.asarray(row),
                            jnp.asarray(np.asarray([s.prefilled], np.int32)),
@@ -1333,14 +1354,8 @@ class ContinuousBatcher:
                                np.asarray([s.state_slot], np.int32))),
                 jnp.asarray(tokens),
                 SampleParams(
-                    # the resume path discards the sampled token (it was
-                    # emitted before the eviction): force the cheap
-                    # greedy branch
-                    temperature=jnp.asarray(
-                        [0.0 if s.resume_decode else s.temperature],
-                        jnp.float32),
-                    top_k=jnp.asarray([s.top_k], jnp.int32),
-                    top_p=jnp.asarray([s.top_p], jnp.float32),
+                    temperature=jnp.asarray(temp), top_k=jnp.asarray(top_k),
+                    top_p=jnp.asarray(top_p),
                     key=jnp.asarray(s.key[None, :]),
                     emitted=jnp.asarray([s.sample_offset], jnp.int32)))
         if s.request_id:
@@ -1357,6 +1372,8 @@ class ContinuousBatcher:
                                             "prefilled": s.prefilled,
                                             "total": total}):
                 _FP_PREFILL.fire()
+                _M_SAMPLE_STEPS.labels(
+                    cut=sample_cut(temp, top_k, top_p)).inc()
                 if s.first_dispatch_at is None:
                     self._first_dispatch(s)
                 tok, logp = self._run_prefill(*args)
@@ -1561,6 +1578,7 @@ class ContinuousBatcher:
                     self._count_attention_blocks(
                         "decode", [x.cache_len + ahead for x in batch],
                         DECODE_WIDTH)
+                    _M_SAMPLE_STEPS.labels(cut=self._dstate_cut).inc()
                     out = self._decode_prog(self._params(), self._pools,
                                             self._dtables, self._dstate)
             except Exception:  # noqa: BLE001
@@ -1688,6 +1706,7 @@ class ContinuousBatcher:
                 temperature=jnp.asarray(temp), top_k=jnp.asarray(top_k),
                 top_p=jnp.asarray(top_p), key=jnp.asarray(key),
                 emitted=jnp.asarray(emitted)))
+        self._dstate_cut = sample_cut(temp, top_k, top_p)
         self._state_epoch = self._epoch
         self._tables_dirty = True
 
@@ -1799,6 +1818,7 @@ class ContinuousBatcher:
                 self._count_attention_blocks(
                     "verify", [x.cache_len for x in batch],
                     self.spec_tokens + 1)
+                _M_SAMPLE_STEPS.labels(cut=self._dstate_cut).inc()
                 out = self._verify_prog(self._params(), self._pools,
                                         self._dtables, self._dstate,
                                         draft_d, dlen_d)
