@@ -1,7 +1,8 @@
-"""What the served blocks share: RMSNorm, the gated MLP, the untied head.
+"""What the served blocks share: RMSNorm, the gated MLP, interleaved
+rotary embedding, the untied head.
 
-:mod:`.longcat_flash` and :mod:`.olmo_hybrid` both import them from
-here. Weights are created and held in ``param_dtype`` and nothing casts
+:mod:`.longcat_flash`, :mod:`.olmo_hybrid` and :mod:`.command_a_plus`
+import them from here. Weights are created and held in ``param_dtype`` and nothing casts
 a weight inside a call: a matmul takes them as they lie.
 """
 
@@ -24,6 +25,22 @@ def rms_norm(x, weight, eps):
     x32 = x32 * jax.lax.rsqrt(
         jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
     return x32.astype(x.dtype) * weight.astype(x.dtype)
+
+
+def rotary_interleaved(x, positions, theta):
+    """Rotate the pairs ``(x[2i], x[2i+1])`` of the last axis by
+    ``position * theta ** (-2i / d)``. ``x``: ``(B, S, ..., d)``;
+    ``positions``: ``(B, S)``. Angles in float32."""
+    d = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[..., None] * freq     # (B, S, d/2)
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[2:])
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
+                    axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 class GatedMlp(nn.Module):

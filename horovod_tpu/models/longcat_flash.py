@@ -52,7 +52,8 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.moe import STATS_COLLECTION, held_experts_mlp, route_topk
-from .blocks import GatedMlp, normal_init as _init, rms_norm, untied_head
+from .blocks import (GatedMlp, normal_init as _init, rms_norm,
+                     rotary_interleaved, untied_head)
 from .transformer import CacheSpec, PagedCache
 
 Dtype = Any
@@ -112,22 +113,6 @@ class LongcatFlashConfig:
         """Whether a chunk of ``chunk`` queries takes the expanded form."""
         r, dn, dv = self.kv_lora_rank, self.qk_nope_head_dim, self.v_head_dim
         return chunk * (2 * r - dn - dv) > r * (dn + dv)
-
-
-def rotary_interleaved(x, positions, theta):
-    """Rotate the pairs ``(x[2i], x[2i+1])`` of the last axis by
-    ``position * theta ** (-2i / d)``. ``x``: ``(B, S, ..., d)``;
-    ``positions``: ``(B, S)``. Angles in float32."""
-    d = x.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = positions.astype(jnp.float32)[..., None] * freq     # (B, S, d/2)
-    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[2:])
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
-    even, odd = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
-                    axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
 
 
 class LatentAttention(nn.Module):

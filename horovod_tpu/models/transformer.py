@@ -7,7 +7,9 @@ tied head; ``gpt2-xl`` is the published architecture it matches),
 the shortcut-connected double layer, routed and zero-compute experts)
 and :mod:`.olmo_hybrid` the Olmo-Hybrid block (gated-delta-rule linear
 attention with a per-sequence recurrent state, full attention every
-fourth layer). All enter :class:`~horovod_tpu.serving.generation.GenerationEngine`
+fourth layer), :mod:`.command_a_plus` the Command A+ block (window and
+full attention layers in two plane groups, grouped-query heads, a
+sigmoid router). All enter :class:`~horovod_tpu.serving.generation.GenerationEngine`
 through the same ``apply(params, tokens, cache=PagedCache,
 logits_at=...)`` contract, and each declares the cache it keeps with a
 :class:`CacheSpec` (``cfg.cache_spec()``); :class:`PagedCache` and
@@ -131,6 +133,20 @@ class TransformerConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class PlaneGroup:
+    """Planes of a :class:`CacheSpec` that keep a token's rows equally
+    long: ``window=None`` as long as the sequence runs, ``window=W``
+    only while a later query may still read them (a sliding-window
+    attention: a query at position ``p`` reads the keys at ``p - W < t
+    <= p``). A group has its own pools and its own block allocator, and
+    a sequence one block table a group."""
+
+    name: str
+    planes: int
+    window: Optional[int] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class CacheSpec:
     """What a served model keeps in the paged cache: its declaration.
 
@@ -138,6 +154,17 @@ class CacheSpec:
     rows (a pool's leading axis). ``rows``: ``(name, width)`` for each
     array a token leaves behind in one plane: one pool a row, ``width``
     values of ``dtype`` at the head of a pool row.
+
+    **Plane groups.** ``groups``: empty for a model whose planes all
+    keep every token (one group, one pool a row, one block table a
+    sequence: every model but one that mixes window and full attention).
+    Otherwise the :class:`PlaneGroup` s that ``planes`` divide into, the
+    group that keeps every token first: each group has one pool a row,
+    ``(group.planes, its blocks, block_size, row)``, the pools of the
+    first group's rows first; ``PagedCache.block_tables`` is then a
+    tuple, one table a group, and a window group's table holds 0 (the
+    null block) where a block was given back
+    (``docs/serving_models.md``).
 
     **Per-sequence state.** ``state``: ``(name, planes, shape, dtype)``
     for each array a *sequence* keeps whatever its length (a linear
@@ -161,6 +188,11 @@ class CacheSpec:
     rows: Tuple[Tuple[str, int], ...]
     dtype: Dtype
     state: Tuple[Tuple[str, int, Tuple[int, ...], Dtype], ...] = ()
+    groups: Tuple[PlaneGroup, ...] = ()
+
+    def plane_groups(self) -> Tuple[PlaneGroup, ...]:
+        """``groups``, or the one group of a model that declares none."""
+        return self.groups or (PlaneGroup("full", self.planes),)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,7 +207,9 @@ class PagedCache:
     them out; the forward returns them updated in place of the ones it
     was given, every other row untouched. ``block_tables``:
     ``(B, max_blocks)`` int32 — each row maps a sequence's logical block
-    index to a pool block (0-padded past its allocation). ``lengths``:
+    index to a pool block (0-padded past its allocation); for a model
+    that declares plane groups a tuple of such tables, one a group.
+    ``lengths``:
     ``(B,)`` tokens already in each sequence's cache (the chunk starts
     there). ``live``: ``(B,)`` how many of this chunk's ``C`` tokens are
     real; pad tokens (and dead lanes, ``live == 0``) write to the null

@@ -35,6 +35,25 @@ the pool where it lies:
   costs ``max_blocks``. It spends ``H`` times the needed FLOPs on
   zeros, which a step bound by bytes does not feel.
 
+**Grouped queries.** Where a token's row holds fewer key-value heads
+than ``q`` has heads (``kv_heads``: grouped-query attention, 128 query
+heads over 8 key-value heads of 128), the block-diagonal row would be
+``C * H`` query rows wide for ``kv_heads * D`` columns: 256 FLOP a byte
+at those sizes, past what the chip can feed. The ``C * H / kv_heads``
+query vectors that share a key-value head are then scored against that
+head's ``D`` columns alone (``(C * rep, D) x (D, tokens)``, a lane-aligned
+slice of the copied group: ``D`` is a multiple of 128), a head after a
+head, 32 FLOP a byte. Which layout runs follows from the shapes
+(``kv_heads < H``), not from an option; a model whose rows hold one head
+a query head keeps the block-diagonal layout.
+
+**Windows.** With ``window`` a lane's walk starts at the group that
+holds position ``lengths - window + 1`` (a per-lane first group in SMEM
+beside the row count) and a slot is masked by its position
+(``last - window < t <= last``), so a table entry before the window is
+never read and may be the null block: what a window group's released
+blocks leave behind.
+
 Nothing here knows 25 x 64: the shapes come from the pools' ``(block_size,
 row)`` and from ``q``. What selects this kernel over the gather path is
 :func:`kernel_applies`, a rule over shapes and the backend; there is no
@@ -76,7 +95,8 @@ def shapes_fit(query_rows: int, block_size: int, row: int, dtype) -> bool:
     """Whether the kernel can take these shapes: the pool's
     ``(block_size, row)`` is whole tiles of its dtype (so a block is
     copied and scored without a relayout), whole blocks make up a group,
-    and the chunk's ``C * H`` query rows fit one pass."""
+    and the query rows one pass brings fit it: a chunk's ``C * H``, or,
+    grouped, the ``C * H / kv_heads`` that share a key-value head."""
     return (block_size % _sublanes(dtype) == 0
             and GROUP_TOKENS % block_size == 0
             and row % _LANES == 0
@@ -95,41 +115,68 @@ def kernel_applies(query_rows: int, block_size: int, row: int,
 
 
 def blocks_read(lengths, chunk: int, block_size: int,
-                max_blocks: int) -> int:
+                max_blocks: int, window=None) -> int:
     """Blocks the kernel copies in one call for live lanes holding
     ``lengths`` tokens before a chunk of ``chunk`` columns (a dead lane
     reads none and is not listed): each lane's ``length + chunk`` rows,
-    clipped to its table, rounded up to whole groups. Host arithmetic
+    clipped to its table, rounded up to whole groups; with a ``window``
+    from the group that holds the first key the chunk's first column
+    may read (``length - window + 1``) and not from 0. Host arithmetic
     for the scheduler's counter, the same walk as the kernel's."""
     group = GROUP_TOKENS // block_size
     top = max_blocks * block_size
-    return sum(-(-min(int(n) + chunk, top) // GROUP_TOKENS) * group
-               for n in lengths)
+    return sum((-(-min(int(n) + chunk, top) // GROUP_TOKENS)
+                - first_group(int(n), window)) * group for n in lengths)
 
 
-def _kernel(layer_ref, rows_ref, lengths_ref, next_ref, tables_ref,
-            q_ref, diag_ref, sel_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, acc_ref, slot_ref, *,
-            block_size, max_blocks, columns, heads, sm_scale):
+def first_group(length, window):
+    """The first group of ``GROUP_TOKENS`` rows a lane's walk reads: the
+    one that holds position ``length - window + 1``, the oldest key the
+    chunk's first column (at position ``length``) may read; 0 with no
+    window. Python ints or arrays."""
+    if not window:
+        return length * 0
+    over = length - window + 1
+    return (over + abs(over)) // 2 // GROUP_TOKENS      # max(over, 0)
+
+
+def _kernel(layer_ref, rows_ref, first_ref, lengths_ref, next_ref,
+            tables_ref, q_ref, *refs, block_size, max_blocks, columns,
+            heads, kv_heads, window, sm_scale):
     """One lane a grid step. Scalar prefetch: ``layer_ref`` (1,) the
     plane; ``rows_ref`` (B,) rows to read, 0 for a dead lane;
-    ``lengths_ref`` (B,); ``next_ref`` (B + 1,): entry 0 the first live
-    lane, entry ``b + 1`` the next live lane after ``b`` (``B`` for
-    none); ``tables_ref`` (B * max_blocks,). ``q_ref`` (1, Cp, row) the
+    ``first_ref`` (B,) the group its walk starts at
+    (:func:`first_group`); ``lengths_ref`` (B,); ``next_ref`` (B + 1,):
+    entry 0 the first live lane, entry ``b + 1`` the next live lane
+    after ``b`` (``B`` for none); ``tables_ref`` (B * max_blocks,).
+
+    Two query layouts, by ``kv_heads`` (module docstring). **One head a
+    key-value head** (``kv_heads == heads``): ``q_ref`` (1, Cp, row) the
     lane's ``columns`` query columns, a column's heads side by side as
     a token's row has them; ``diag_ref`` (R, row) and ``sel_ref``
     (Cp, R) the 0/1 masks that spread them block-diagonally over
     ``R >= columns * heads`` query rows and fold the output's diagonal
-    blocks back into ``o_ref`` (1, Cp, row). Scratch: two group buffers
-    each for K and V, their DMA semaphores, the float32 accumulator,
-    and which buffer holds the group the next live lane starts from."""
+    blocks back into ``o_ref`` (1, Cp, row). **Grouped**: ``q_ref``
+    (1, kv_heads, Rg, D), for each key-value head the ``columns x
+    heads / kv_heads`` query vectors that share it (row ``c * rep +
+    j``), scored against that head's ``D`` columns of a token's row;
+    ``o_ref`` the same shape. Scratch: two group buffers each for K and
+    V, their DMA semaphores, the float32 accumulator, and which buffer
+    holds the group the next live lane starts from."""
+    grouped = kv_heads != heads
+    if grouped:
+        diag_ref = sel_ref = None
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, acc_ref, slot_ref = refs
+    else:
+        (diag_ref, sel_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+         acc_ref, slot_ref) = refs
     b = pl.program_id(0)
     lanes = pl.num_programs(0)
     group = GROUP_TOKENS // block_size
     layer = layer_ref[0]
     rows = rows_ref[b]
-    groups = (rows + (GROUP_TOKENS - 1)) // GROUP_TOKENS
-    R, row = diag_ref.shape
+    first = first_ref[b]
+    groups = (rows + (GROUP_TOKENS - 1)) // GROUP_TOKENS - first
 
     def copies(lane, g, slot, lookup=True):
         """The 2 x group DMA descriptors of a lane's group ``g`` into
@@ -152,21 +199,106 @@ def _kernel(layer_ref, rows_ref, lengths_ref, next_ref, tables_ref,
     @pl.when(b == 0)
     def _prime():
         slot_ref[0] = 0
-        first = next_ref[0]
+        lane = next_ref[0]
 
-        @pl.when(first < lanes)
+        @pl.when(lane < lanes)
         def _():
-            for c in copies(first, 0, 0):
+            for c in copies(lane, first_ref[lane], 0):
                 c.start()
 
     @pl.when(rows == 0)
     def _dead():
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    def visible(g, last):
+        """Which of group ``g``'s slots a query row whose column sits at
+        position ``last`` may read: ``t <= last``, and inside the window
+        ``t > last - window``."""
+        t = g * GROUP_TOKENS + jax.lax.broadcasted_iota(
+            jnp.int32, last.shape, 1)
+        valid = t <= last
+        if window:
+            valid = jnp.logical_and(valid, t > last - window)
+        return valid
+
+    def softmax_step(s, valid, m, l):
+        """One group of the running softmax: ``(p, corr, m_new,
+        l_new)``."""
+        s = jnp.where(valid, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m - m_new)
+        return p, corr, m_new, l * corr + jnp.sum(p, axis=-1, keepdims=True)
+
+    def walk(score, carry):
+        """The lane's groups, double-buffered; ``score(k, v, g, carry)``
+        folds one group in."""
+        slot0 = slot_ref[0]
+        after = jnp.minimum(next_ref[b + 1], lanes - 1)
+        has_after = next_ref[b + 1] < lanes
+
+        def body(i, carry):
+            g = first + i
+            slot = (slot0 + i) % 2
+            more = i + 1 < groups
+
+            # the next group to score: this lane's, or the first of the
+            # next live lane
+            @pl.when(jnp.logical_or(more, has_after))
+            def _():
+                lane = jnp.where(more, b, after)
+                for c in copies(lane, jnp.where(more, g + 1,
+                                                first_ref[after]), 1 - slot):
+                    c.start()
+
+            for c in copies(b, g, slot, lookup=False):
+                c.wait()
+            return score(k_buf[slot], v_buf[slot], g, carry)
+
+        carry = jax.lax.fori_loop(0, groups, body, carry)
+        slot_ref[0] = (slot0 + groups) % 2
+        return carry
+
+    if grouped:
+        @pl.when(rows > 0)
+        def _live_grouped():
+            rep = heads // kv_heads
+            Rg, D = q_ref.shape[2], q_ref.shape[3]
+            # query row r = c * rep + j is column c's vector of the j-th
+            # head of its group, and sees the slots up to lengths + c
+            r = jax.lax.broadcasted_iota(jnp.int32, (Rg, GROUP_TOKENS), 0)
+            last = lengths_ref[b] + jnp.minimum(r // rep, columns - 1)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+            def score(k, v, g, carry):
+                valid = visible(g, last)
+                out = []
+                for h in range(kv_heads):
+                    m, l = carry[h]
+                    cols = slice(h * D, (h + 1) * D)
+                    s = jax.lax.dot_general(
+                        q_ref[0, h], k[:, cols], (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * sm_scale
+                    p, corr, m, l = softmax_step(s, valid, m, l)
+                    pv = jax.lax.dot_general(
+                        p.astype(v.dtype), v[:, cols],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)     # (Rg, D)
+                    acc_ref[h] = acc_ref[h] * corr + pv
+                    out.append((m, l))
+                return tuple(out)
+
+            m0 = jnp.full((Rg, 1), NEG_INF, jnp.float32)
+            l0 = jnp.zeros((Rg, 1), jnp.float32)
+            carry = walk(score, ((m0, l0),) * kv_heads)
+            for h in range(kv_heads):
+                o_ref[0, h] = (acc_ref[h] / jnp.maximum(
+                    carry[h][1], 1e-30)).astype(o_ref.dtype)
+        return
+
     @pl.when(rows > 0)
     def _live():
-        slot0 = slot_ref[0]
-        after = next_ref[b + 1]
+        R, row = diag_ref.shape
         # query row r = c * heads + h is column c's vector, kept only
         # where head h's values lie in a token's row, and sees the slots
         # t <= lengths + c
@@ -182,45 +314,22 @@ def _kernel(layer_ref, rows_ref, lengths_ref, next_ref, tables_ref,
                 column = column + (r >= c * heads).astype(jnp.int32)
         q = (q * diag.astype(jnp.float32)).astype(diag.dtype)
         last = lengths_ref[b] + column
-        t0 = jax.lax.broadcasted_iota(jnp.int32, (R, GROUP_TOKENS), 1)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        def body(g, carry):
-            m, l = carry
-            slot = (slot0 + g) % 2
-            more = g + 1 < groups
-
-            # the next group to score: this lane's, or the first of the
-            # next live lane
-            @pl.when(jnp.logical_or(more, after < lanes))
-            def _():
-                lane = jnp.where(more, b, jnp.minimum(after, lanes - 1))
-                for c in copies(lane, jnp.where(more, g + 1, 0), 1 - slot):
-                    c.start()
-
-            for c in copies(b, g, slot, lookup=False):
-                c.wait()
-            k = k_buf[slot]                             # (tokens, row)
-            v = v_buf[slot]
+        def score(k, v, g, carry):                      # (tokens, row)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
-            valid = g * GROUP_TOKENS + t0 <= last
-            s = jnp.where(valid, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            p, corr, m, l = softmax_step(s, visible(g, last), *carry)
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)     # (R, row)
             acc_ref[...] = acc_ref[...] * corr + pv
-            return m_new, l_new
+            return m, l
 
         m0 = jnp.full((R, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((R, 1), jnp.float32)
-        _, l = jax.lax.fori_loop(0, groups, body, (m0, l0))
-        slot_ref[0] = (slot0 + groups) % 2
+        _, l = walk(score, (m0, l0))
 
         # row c * heads + h holds head h's output in its own diagonal
         # block; everything off the diagonal is another head's values
@@ -245,43 +354,48 @@ def _fold_masks(C, H, D, R, Cp, row, dtype):
     return jnp.asarray(diag, dtype), jnp.asarray(sel, dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit,
+                   static_argnames=("kv_heads", "window", "interpret"))
 def paged_attention(q, k_pool, v_pool, layer, block_tables, lengths, live,
-                    *, interpret=False):
+                    *, kv_heads=None, window=None, interpret=False):
     """Attention of a chunk's ``q`` ``(B, C, H, D)`` over the paged
     cache: plane ``layer`` of ``k_pool`` / ``v_pool`` ``(planes,
     num_blocks, block_size, row)`` through ``block_tables`` ``(B,
     max_blocks)``. Lane ``b`` holds ``lengths[b]`` tokens before the
     chunk (whose own K and V the caller has already written), column
-    ``c`` attends to slots ``t <= lengths[b] + c``, and a lane with
-    ``live[b] == 0`` is dead: nothing of its table is read and its
-    output is zeros. Returns ``(B, C, H, D)`` in the pools' dtype.
+    ``c`` attends to slots ``t <= lengths[b] + c`` (with ``window``:
+    and ``t > lengths[b] + c - window``; the walk then starts at the
+    group of the first such slot, and a table entry before it may be
+    anything, the null block too), and a lane with ``live[b] == 0`` is
+    dead: nothing of its table is read and its output is zeros.
+    ``kv_heads`` (None: ``H``) is how many key-value heads a token's
+    row holds; with fewer than ``H`` the grouped layout runs. Returns
+    ``(B, C, H, D)`` in the pools' dtype.
 
-    The shapes have to satisfy :func:`shapes_fit`; the caller's rule is
-    :func:`kernel_applies`."""
+    The shapes have to satisfy :func:`shapes_fit` (asked about the
+    query rows one pass brings: ``C * H``, or grouped ``C * H /
+    kv_heads``); the caller's rule is :func:`kernel_applies`."""
     B, C, H, D = q.shape
     _, _, block_size, row = k_pool.shape
+    G = H if kv_heads is None else int(kv_heads)
     if v_pool.shape != k_pool.shape or v_pool.dtype != k_pool.dtype:
         raise ValueError(
             f"paged_attention: K pool {k_pool.shape} {k_pool.dtype} and V "
             f"pool {v_pool.shape} {v_pool.dtype} differ")
-    if not shapes_fit(C * H, block_size, row, k_pool.dtype) or H * D > row:
+    grouped = G != H
+    if H % G or not shapes_fit(C * H // G, block_size, row, k_pool.dtype) \
+            or G * D > row or (grouped and D % _LANES):
         raise ValueError(
-            f"paged_attention: {C} x {H} query rows over blocks of "
-            f"{block_size} x {row} {k_pool.dtype} do not fit the kernel "
-            f"(see shapes_fit)")
+            f"paged_attention: {C} x {H} query rows ({G} key-value heads "
+            f"of {D}) over blocks of {block_size} x {row} {k_pool.dtype} "
+            f"do not fit the kernel (see shapes_fit)")
     max_blocks = block_tables.shape[1]
     dtype = k_pool.dtype
-    R = -(-C * H // _QUERY_TILE) * _QUERY_TILE
-    Cp = -(-C // _QUERY_TILE) * _QUERY_TILE
-
-    q_columns = jnp.pad(q.astype(dtype).reshape(B, C, H * D),
-                        ((0, 0), (0, Cp - C), (0, row - H * D)))
-    diag, sel = _fold_masks(C, H, D, R, Cp, row, dtype)
 
     lengths = lengths.astype(jnp.int32)
     rows = jnp.where(live > 0,
                      jnp.minimum(lengths + C, max_blocks * block_size), 0)
+    first = first_group(lengths, window).astype(jnp.int32)
     # entry 0: the first live lane; entry b + 1: the next one after b
     lane_or_end = jnp.where(rows > 0, jnp.arange(B, dtype=jnp.int32), B)
     nxt = jnp.concatenate([
@@ -290,34 +404,57 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, lengths, live,
 
     kernel = functools.partial(
         _kernel, block_size=block_size, max_blocks=max_blocks, columns=C,
-        heads=H, sm_scale=1.0 / float(np.sqrt(D)))
+        heads=H, kv_heads=G, window=window,
+        sm_scale=1.0 / float(np.sqrt(D)))
     whole = lambda b, *_: (0, 0)                      # noqa: E731
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    if grouped:
+        rep = H // G
+        Rg = -(-C * rep // _QUERY_TILE) * _QUERY_TILE
+        # (B, C, G, rep, D) -> (B, G, C * rep, D): a key-value head's
+        # query vectors together, column-major
+        operands = (jnp.pad(
+            q.astype(dtype).reshape(B, C, G, rep, D).transpose(
+                0, 2, 1, 3, 4).reshape(B, G, C * rep, D),
+            ((0, 0), (0, 0), (0, Rg - C * rep), (0, 0))),)
+        lane_block = pl.BlockSpec((1, G, Rg, D), lambda b, *_: (b, 0, 0, 0))
+        in_specs = [lane_block, hbm, hbm]
+        out_shape = (B, G, Rg, D)
+        acc_shape = (G, Rg, D)
+    else:
+        R = -(-C * H // _QUERY_TILE) * _QUERY_TILE
+        Cp = -(-C // _QUERY_TILE) * _QUERY_TILE
+        operands = (jnp.pad(q.astype(dtype).reshape(B, C, H * D),
+                            ((0, 0), (0, Cp - C), (0, row - H * D))),
+                    *_fold_masks(C, H, D, R, Cp, row, dtype))
+        lane_block = pl.BlockSpec((1, Cp, row), lambda b, *_: (b, 0, 0))
+        in_specs = [lane_block, pl.BlockSpec((R, row), whole),
+                    pl.BlockSpec((Cp, R), whole), hbm, hbm]
+        out_shape = (B, Cp, row)
+        acc_shape = (R, row)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=6,
             grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, Cp, row), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec((R, row), whole),
-                pl.BlockSpec((Cp, R), whole),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, Cp, row), lambda b, *_: (b, 0, 0)),
+            in_specs=in_specs,
+            out_specs=lane_block,
             scratch_shapes=[
                 pltpu.VMEM((2, GROUP_TOKENS, row), dtype),
                 pltpu.VMEM((2, GROUP_TOKENS, row), dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((R, row), jnp.float32),
+                pltpu.VMEM(acc_shape, jnp.float32),
                 pltpu.SMEM((1,), jnp.int32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((B, Cp, row), dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, dtype),
         # a lane hands the next its first group already in flight
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(jnp.asarray(layer, jnp.int32).reshape(1), rows, lengths, nxt,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), rows, first, lengths, nxt,
       block_tables.astype(jnp.int32).reshape(-1),
-      q_columns, diag, sel, k_pool, v_pool)
+      *operands, k_pool, v_pool)
+    if grouped:
+        return out[:, :, :C * rep].reshape(B, G, C, rep, D).transpose(
+            0, 2, 1, 3, 4).reshape(B, C, H, D)
     return out[:, :C, :H * D].reshape(B, C, H, D)

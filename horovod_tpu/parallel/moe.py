@@ -10,7 +10,8 @@ Static shapes throughout (capacity fixed at trace time); overflowing tokens
 are dropped and their outputs fall back to zero (residual connections carry
 them), the standard capacity-factor semantics.
 
-The serving half (:func:`route_topk`, :func:`held_experts_mlp`) is another
+The serving half (:func:`route_topk` or :func:`route_sigmoid_topk`, then
+:func:`held_experts_mlp`) is another
 layer: top-k routing over every router output of the model, **dropless**,
 for a chip that is *told* which FFN experts it holds (``held_experts``, a
 range of expert ids: an argument, not a property of the weights' shape).
@@ -138,6 +139,18 @@ def route_topk(router_logits, bias, k: int, scale: float):
     s = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
     weights = jnp.take_along_axis(s, idx, axis=-1) * scale
+    return idx.astype(jnp.int32), weights
+
+
+def route_sigmoid_topk(router_logits, k: int):
+    """Top-``k`` routing by sigmoid scores, renormalised:
+    ``s = sigmoid(float32(router_logits))``, the ``k`` largest ``s`` are
+    chosen (no bias steers the choice), and the weights are ``s`` at the
+    chosen outputs divided by their sum over the ``k``. Returns what
+    :func:`route_topk` does, for :func:`held_experts_mlp`."""
+    s = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    picked, idx = jax.lax.top_k(s, k)
+    weights = picked / jnp.sum(picked, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), weights
 
 

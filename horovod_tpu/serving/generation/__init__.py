@@ -50,7 +50,8 @@ See docs/inference.md for architecture, knobs, metrics, and drills.
 
 from .engine import GenerationEngine                        # noqa: F401
 from .kv_cache import (BlockAllocator, BlocksExhaustedError,  # noqa: F401
-                       DecodeState, PerSequenceStateError, SampleParams,
+                       DecodeState, PerSequenceStateError, PlaneGroupsError,
+                       SampleParams,
                        block_bytes,
                        build_beam_program, build_decode_program,
                        build_prefill_program, build_program,

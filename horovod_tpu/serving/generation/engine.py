@@ -31,10 +31,18 @@ snapshot slots beside the block pool; it is served with the prefix cache
 on like any other, and refused by what cannot carry a state: a
 ``spec_mode`` other than off raises here, no beam program is built and
 ``num_beams > 1`` is rejected at submit, and the disagg KV transfer
-refuses its cache (:class:`~.kv_cache.PerSequenceStateError`).
+refuses its cache (:class:`~.kv_cache.PerSequenceStateError`). A model
+whose declaration names **plane groups** (window planes beside full
+ones) gets a second pool and allocator for the window group, sized from
+the declaration and the engine's own knobs (``num_blocks`` stays the
+size of the group that keeps every token; the window group gets, for
+every lane, the blocks of a window, a prefill chunk and two more, which
+no traffic can exhaust), and is refused by the same three paths
+(:class:`~.kv_cache.PlaneGroupsError`).
 :class:`~horovod_tpu.models.transformer.Transformer`,
-:class:`~horovod_tpu.models.longcat_flash.LongcatFlash` and
-:class:`~horovod_tpu.models.olmo_hybrid.OlmoHybrid` are the three.
+:class:`~horovod_tpu.models.longcat_flash.LongcatFlash`,
+:class:`~horovod_tpu.models.olmo_hybrid.OlmoHybrid` and
+:class:`~horovod_tpu.models.command_a_plus.CommandAPlus` are the four.
 """
 
 from typing import Any, List, Optional, Sequence
@@ -44,7 +52,7 @@ from ..engine import ParamsLifecycle
 from .kv_cache import (BlockAllocator, build_beam_program,
                        build_decode_program, build_prefill_program,
                        build_verify_program, make_pools, make_state_pools,
-                       refuse_state, state_bytes)
+                       refuse_groups, refuse_state, state_bytes)
 from .scheduler import DECODE_WIDTH, ContinuousBatcher, GenSequence
 from .spec import make_proposer
 
@@ -147,11 +155,28 @@ class GenerationEngine:
                                  else state_snapshots)
             snapshots = make_state_pools(model.cfg, snapshot_slots + 1)
             max_beams = 1
+        groups = model.cfg.cache_spec().groups
+        window, window_span, pool_sizes = None, 0, num_blocks
+        if groups:
+            # one more pool and allocator, for the window group; what
+            # keeps one block list a sequence is refused
+            if not spec_off:
+                refuse_groups(model.cfg, f"spec_mode={spec_mode!r}: "
+                              f"speculative decoding")
+            window, window_span = self._window_group(
+                groups, block_size, prefix_cache,
+                int(cfg.get(_config.GEN_MAX_SEQS)
+                    if max_seqs is None else max_seqs),
+                int(cfg.get(_config.GEN_PREFILL_CHUNK)
+                    if prefill_chunk is None else prefill_chunk))
+            pool_sizes = (num_blocks, window.num_blocks)
+            max_beams = 1
         self.allocator = BlockAllocator(
             num_blocks, block_size, prefix_cache=prefix_cache,
             state_slots=state_slots, snapshot_slots=snapshot_slots,
-            state_bytes=state_bytes(model.cfg))
-        pools = make_pools(model.cfg, num_blocks, block_size,
+            state_bytes=state_bytes(model.cfg), window=window,
+            window_span=window_span)
+        pools = make_pools(model.cfg, pool_sizes, block_size,
                            state_slots=state_slots)
         self._proposer = make_proposer(
             spec_mode, draft_model=draft_model, params=draft_params,
@@ -174,6 +199,31 @@ class GenerationEngine:
             beam_program=beam_prog, max_beams=max_beams,
             snapshots=snapshots, on_step=on_step, role=role)
         self._lifecycle.start_poller()    # last: nothing can fail past here
+
+    @staticmethod
+    def _window_group(groups, block_size, prefix_cache, max_seqs,
+                      prefill_chunk):
+        """The window group's allocator and its window in blocks. The
+        pool holds, for every lane, the most a running sequence keeps:
+        the window, a prefill chunk and two blocks of slack (the block
+        being filled, and a release that lags a step in flight); cached
+        blocks only ever take what lanes leave free."""
+        if len(groups) != 2 or groups[0].window is not None \
+                or not groups[1].window:
+            raise ValueError(
+                f"plane groups {tuple(g.name for g in groups)}: the "
+                f"engine serves one group that keeps every token and, "
+                f"after it, one window group")
+        window = int(groups[1].window)
+        if window % block_size:
+            raise ValueError(
+                f"window of {window} tokens is not whole blocks of "
+                f"{block_size} (HVD_TPU_GEN_BLOCK_SIZE)")
+        span = window // block_size
+        lane = span + -(-prefill_chunk // block_size) + 2
+        return BlockAllocator(max_seqs * lane + 1, block_size,
+                              prefix_cache=prefix_cache,
+                              group=groups[1].name), span
 
     # -- generation ----------------------------------------------------------
 

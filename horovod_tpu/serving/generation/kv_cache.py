@@ -59,6 +59,25 @@ than the deepest block that owns one. Snapshot 0 is the **null
 snapshot**: zeros, never written, what a sequence with no hit starts
 from. A model that declares no state has neither kind of slot and the
 allocator behaves as it always did.
+
+**Plane groups** (a model whose
+:class:`~horovod_tpu.models.transformer.CacheSpec` declares ``groups``:
+full-attention planes that keep every token beside sliding-window planes
+that need a token for ``window`` positions). Each group has pools and an
+allocator of its own over them; the first group's (it keeps every
+token) is the allocator the engine holds, and the window group's hangs
+on it as :attr:`BlockAllocator.window`. A sequence has one block list a
+group. The scheduler gives a window block back
+(:meth:`BlockAllocator.free`) as soon as its last position lies
+``window`` or more behind the position about to be written; a released
+block that is indexed parks in the window allocator's cached list and
+stays matchable until evicted. Both allocators index a block under the
+same chain hash, and :meth:`BlockAllocator.match` cuts a hit to the
+deepest block boundary at which the window group still holds the
+``window`` positions before it (:meth:`covered_depth`): a shallower
+prefix of the full chain is worth more than a deeper one whose window
+rows are gone. A model that declares no groups has no window allocator
+and nothing here runs.
 """
 
 import collections
@@ -82,6 +101,21 @@ _M_BLOCKS = _metrics.gauge(
     "(the null block excluded). Live KV memory is this times the "
     "per-block byte size; pinning near HVD_TPU_GEN_NUM_BLOCKS means "
     "admission is block-bound and preemptions are imminent.")
+_M_GROUP_BLOCKS = _metrics.gauge(
+    "hvd_tpu_gen_kv_group_blocks_in_use",
+    "KV-cache blocks held by live sequences, by plane group of the "
+    "model's cache (group='full': planes that keep every token, the one "
+    "group of most models, equal to hvd_tpu_gen_kv_blocks_in_use; "
+    "group='window': sliding-window planes, whose blocks a running "
+    "sequence gives back once the window has passed them). A window "
+    "group near the full group's count means nothing is being released.",
+    labels=("group",))
+_M_WINDOW_RELEASED = _metrics.counter(
+    "hvd_tpu_gen_window_blocks_released_total",
+    "Window-group blocks running sequences gave back because every "
+    "position in them lay a whole window behind the position being "
+    "written (retirement and preemption are not counted). Indexed "
+    "blocks among them park in the cached list and stay matchable.")
 _M_BLOCK_STATE = _metrics.gauge(
     "hvd_tpu_gen_kv_blocks",
     "KV-cache block pool split by state (the null block excluded): "
@@ -150,6 +184,27 @@ def refuse_state(model_cfg, what: str) -> None:
             f"{', '.join(name for name, *_ in state)})")
 
 
+class PlaneGroupsError(ValueError):
+    """A path that keeps **one block list a sequence** was asked to
+    serve a model whose cache declares plane groups
+    (``CacheSpec.groups``: window planes beside full ones): the
+    speculative verify step snapshots and rolls back one table's slots,
+    a beam fork shares and copies one list of blocks, and the disagg
+    wire ships one pool's blocks. Each refuses instead of serving from
+    half a cache."""
+
+
+def refuse_groups(model_cfg, what: str) -> None:
+    """Raise :class:`PlaneGroupsError` if ``model_cfg`` declares plane
+    groups; ``what`` names the path that keeps one block list."""
+    groups = getattr(model_cfg.cache_spec(), "groups", ())
+    if groups:
+        raise PlaneGroupsError(
+            f"{what} keeps one block list a sequence, and this model's "
+            f"cache declares plane groups (CacheSpec.groups: "
+            f"{', '.join(g.name for g in groups)})")
+
+
 def chain_hash(parent: Optional[str], tokens: Sequence[int]) -> str:
     """Content key for one full KV block: commits to the parent block's
     hash (hence the entire token prefix) plus this block's tokens, so
@@ -183,7 +238,9 @@ class BlockAllocator:
     def __init__(self, num_blocks: int, block_size: int,
                  prefix_cache: Optional[bool] = None,
                  state_slots: int = 0, snapshot_slots: int = 0,
-                 state_bytes: int = 0):
+                 state_bytes: int = 0, group: str = "full",
+                 window: Optional["BlockAllocator"] = None,
+                 window_span: int = 0):
         if num_blocks < 2:
             raise ValueError(
                 f"HVD_TPU_GEN_NUM_BLOCKS={num_blocks}: need at least 2 "
@@ -237,6 +294,26 @@ class BlockAllocator:
         self._snap_of: "collections.OrderedDict[str, int]" = \
             collections.OrderedDict()
         self.snapshot_peak = 0
+        # -- plane groups (module docstring): this allocator's group,
+        # and for a model with window planes their allocator and how
+        # many blocks a window spans; None and 0 for every other model
+        self.group = str(group)
+        self.window = window
+        self.window_span = int(window_span) if window is not None else 0
+        #: the allocator the engine holds publishes the unlabelled
+        #: gauges; a window group's only its own group's
+        self._primary = True
+        if window is not None:
+            if state_slots:
+                raise ValueError(
+                    "a cache with both per-sequence state and plane groups "
+                    "is not served: a prefix hit would need a snapshot at "
+                    "the depth the window group cuts it to")
+            if window.block_size != self.block_size or self.window_span < 1:
+                raise ValueError(
+                    f"window group: blocks of {window.block_size} against "
+                    f"{self.block_size}, a window of {window_span} blocks")
+            window._primary = False
 
     def blocks_for(self, tokens: int) -> int:
         """Blocks needed to hold ``tokens`` cache slots."""
@@ -296,6 +373,9 @@ class BlockAllocator:
     def _publish(self, in_use: int, stats: Dict[str, int]) -> None:
         # metric publication happens outside the lock: counts are
         # computed under it, cells are atomic
+        _M_GROUP_BLOCKS.labels(group=self.group).set(in_use)
+        if not self._primary:
+            return
         _M_BLOCKS.set(in_use)
         for state, count in stats.items():
             _M_BLOCK_STATE.labels(state=state).set(count)
@@ -388,6 +468,13 @@ class BlockAllocator:
             stats = self._stats_locked()
         self._publish(in_use, stats)
 
+    def release(self, blocks: List[int]) -> None:
+        """:meth:`free` for blocks a *running* sequence gives back
+        because its window has passed them, counted in
+        ``hvd_tpu_gen_window_blocks_released_total``."""
+        self.free(blocks)
+        _M_WINDOW_RELEASED.inc(len(blocks))
+
     # -- prefix-cache surface -----------------------------------------
 
     def register(self, block: int, content_hash: str,
@@ -441,7 +528,45 @@ class BlockAllocator:
         as evictable)."""
         with self._lock:
             found = self._indexed_prefix_locked(hashes)
+        found = found[:self._window_depth(hashes, len(found))]
+        with self._lock:
             return len(found), sum(1 for b in found if b in self._cached)
+
+    def _window_depth(self, hashes: Sequence[str], depth: int) -> int:
+        """``depth`` cut to what the window group can continue from
+        (no window group: as it is). Asked outside this allocator's
+        lock: the window group's is its own."""
+        if self.window is None:
+            return depth
+        return self.window.covered_depth(hashes[:depth], self.window_span)
+
+    def covered_depth(self, hashes: Sequence[str], span: int) -> int:
+        """The deepest ``d <= len(hashes)`` at which this (window)
+        group still holds what the token after block ``d - 1`` reads:
+        the ``span`` blocks before the boundary, or all ``d`` where the
+        prefix is shorter. 0 where no boundary is covered."""
+        with self._lock:
+            run, best = 0, 0
+            for d, h in enumerate(hashes, 1):
+                run = run + 1 if h in self._index else 0
+                if run >= min(d, span):
+                    best = d
+            return best
+
+    def match_tail(self, hashes: Sequence[str], span: int) -> List[int]:
+        """Attach this (window) group's blocks of a hit that the first
+        group's :meth:`match` cut to ``hashes``: the last ``span`` of
+        them (refcounts bumped, cached blocks revived), 0 in the place
+        of every earlier one, which the sequence never holds. One entry
+        a hash, in chain order."""
+        skip = max(0, len(hashes) - span)
+        held = self.match(hashes[skip:])
+        if len(held) != len(hashes) - skip:
+            self.free(held)
+            raise ValueError(
+                f"window group: {len(held)} of {len(hashes) - skip} blocks "
+                f"of a covered hit are indexed")
+        return [0] * skip + held
 
     def _indexed_prefix_locked(self, hashes: Sequence[str]) -> List[int]:
         """Blocks of the longest indexed prefix of ``hashes`` that a
@@ -465,12 +590,18 @@ class BlockAllocator:
         (becoming shared). Returns the matched block ids in chain
         order; the caller owns one reference to each. For a model with
         per-sequence state the prefix ends at the deepest block that
-        owns a snapshot (:meth:`snapshot_of` the last block returned)."""
+        owns a snapshot (:meth:`snapshot_of` the last block returned);
+        with a window group at the deepest boundary that group covers
+        (:meth:`covered_depth`; the caller attaches its blocks with
+        ``window.match_tail``)."""
         out: List[int] = []
         if not self.prefix_cache:
             return out
         with self._lock:
-            for b in self._indexed_prefix_locked(hashes):
+            found = self._indexed_prefix_locked(hashes)
+        found = found[:self._window_depth(hashes, len(found))]
+        with self._lock:
+            for b in found:
                 if b in self._cached:
                     del self._cached[b]
                     self._ref[b] = 1
@@ -674,12 +805,24 @@ def make_pools(model_cfg, num_blocks: int, block_size: int,
     For a model that declares per-sequence state the tuple goes on with
     one ``(planes, state_slots, *shape)`` array for each declared state,
     in its own dtype (:func:`make_state_pools`): the programs thread,
-    donate and update them in place like the row pools."""
+    donate and update them in place like the row pools.
+
+    For a model that declares plane groups ``num_blocks`` is a tuple,
+    one pool size a group, and the pools come a group at a time: the
+    first group's rows, ``(group.planes, its blocks, block_size, row)``
+    each, then the next group's."""
     import jax.numpy as jnp
     spec = model_cfg.cache_spec()
+    groups = spec.plane_groups()
+    counts = tuple(num_blocks) if isinstance(num_blocks, (tuple, list)) \
+        else (num_blocks,)
+    if len(counts) != len(groups):
+        raise ValueError(
+            f"{len(counts)} pool sizes for a cache of {len(groups)} plane "
+            f"groups ({', '.join(g.name for g in groups)})")
     return tuple(
-        jnp.zeros((spec.planes, num_blocks, block_size, _row(width)),
-                  spec.dtype) for _, width in spec.rows) \
+        jnp.zeros((g.planes, n, block_size, _row(width)), spec.dtype)
+        for g, n in zip(groups, counts) for _, width in spec.rows) \
         + make_state_pools(model_cfg, state_slots)
 
 
@@ -722,13 +865,16 @@ def build_state_copy_program():
     return jax.jit(_copy_state, donate_argnums=(0,))
 
 
-def block_bytes(model_cfg, block_size: int) -> int:
+def block_bytes(model_cfg, block_size: int, group: Optional[int] = None) -> int:
     """Bytes of cache one block holds: every declared row, padded as
     :func:`make_pools` allocates it, in every plane (per-sequence state
-    is no part of a block: :func:`state_bytes`)."""
+    is no part of a block: :func:`state_bytes`); with ``group`` in the
+    planes of that plane group alone, a block of its pools."""
     import jax.numpy as jnp
     spec = model_cfg.cache_spec()
-    return (spec.planes * block_size * jnp.dtype(spec.dtype).itemsize
+    planes = spec.planes if group is None \
+        else spec.plane_groups()[group].planes
+    return (planes * block_size * jnp.dtype(spec.dtype).itemsize
             * sum(_row(width) for _, width in spec.rows))
 
 
@@ -1179,6 +1325,7 @@ def build_verify_program(model, spec_tokens: int):
 
     refuse_state(model.cfg, "the speculative verify step (it rolls back "
                  "rejected K/V rows, not a state)")
+    refuse_groups(model.cfg, "the speculative verify step")
     S = int(spec_tokens)
     if S < 1:
         raise ValueError(f"spec_tokens={spec_tokens}: must be >= 1")
@@ -1298,6 +1445,7 @@ def build_beam_program(model, beam_k: int, decode_width: int = 2):
 
     refuse_state(model.cfg, "beam search (a fork shares and copies "
                  "blocks, not a state)")
+    refuse_groups(model.cfg, "beam search")
     K = int(beam_k)
     if K < 1:
         raise ValueError(f"beam_k={beam_k}: must be >= 1")

@@ -14,7 +14,9 @@ Each scheduler iteration does three things, in order:
    on expired deadlines;
 2. **prefill one chunk** — the oldest prefilling sequence advances by at
    most ``HVD_TPU_GEN_PREFILL_CHUNK`` prompt tokens, so a long prompt is
-   chunked and in-flight decodes stall for at most one step;
+   chunked and in-flight decodes stall for at most one step (the chunk's
+   blocks and host arrays are made ready while the decode step in flight
+   still runs; it is dispatched once that step's tokens are delivered);
 3. **decode one step** — every decoding sequence contributes its last
    token to one fixed-shape batch; finished sequences (EOS /
    ``max_tokens``) retire *immediately*, freeing their slot and blocks
@@ -128,6 +130,25 @@ wait for nothing. Speculative verify, beam search and the disagg KV
 wire cannot carry a state and refuse such a model
 (:class:`~.kv_cache.PerSequenceStateError`).
 
+**Plane groups** (a model whose cache declaration names ``groups``:
+window planes beside full ones) ride the same paths; the scheduler
+reads only the allocator's ``window``. A sequence then holds a second
+block list, ``wblocks``, in the window group's pools, one entry a
+logical block like ``blocks``; admission and growth take blocks in both
+groups or in neither (a shortfall in either preempts, as before), two
+tables go to the device, and before each prefill chunk and each decode
+step a running sequence **gives back** the window blocks whose last
+position lies a whole window behind the position about to be written
+(``gen.window.release`` loop span; with chunked prefill the chunk's
+first column sets the bound, with steps in flight the host's lagging
+length does, so no key a query may still read is ever released). A
+released entry reads 0 in the table, which the attention never reads:
+its walk starts inside the window. A prefix hit is cut to the depth the
+window group still covers (:meth:`~.kv_cache.BlockAllocator.match`).
+Speculative verify, beam search and the disagg KV wire keep one block
+list a sequence and refuse such a model
+(:class:`~.kv_cache.PlaneGroupsError`).
+
 Fault sites: ``serving.prefill`` (each prefill chunk — an ``error``
 fails only that sequence), ``serving.decode`` (each decode-step
 enqueue — an ``error`` fails only the sequences in that step's batch;
@@ -161,7 +182,7 @@ from ...ops import paged_attention
 from ...parallel.moe import STATS_FIELDS
 from ..batcher import DeadlineExceededError, QueueFullError
 from .kv_cache import (BlockAllocator, BlocksExhaustedError, DecodeState,
-                       PerSequenceStateError, SampleParams,
+                       PerSequenceStateError, PlaneGroupsError, SampleParams,
                        build_state_copy_program, chain_hash,
                        count_snapshots, gather_blocks, reads_live_blocks,
                        sample_cut, scatter_blocks)
@@ -221,6 +242,15 @@ _M_PAGED_BLOCKS = _metrics.counter(
     "read/table is the share of the table a step pays for: 1 means the "
     "kernel did not engage (not a TPU, or shapes it does not take).",
     labels=("kind",))
+_M_PAGED_GROUP_BLOCKS = _metrics.counter(
+    "hvd_tpu_gen_paged_attn_group_blocks_total",
+    "hvd_tpu_gen_paged_attn_blocks_total split by the plane group of "
+    "the model's cache whose attention sublayers the count is of "
+    "(group='full', the one group of most models, or 'window'): a "
+    "window group's 'read' starts at the first block inside each "
+    "lane's window, not at 0. The unsplit counter is the sum over "
+    "groups, one sublayer of each.",
+    labels=("kind", "group"))
 _M_SAMPLE_STEPS = _metrics.counter(
     "hvd_tpu_gen_sample_steps_total",
     "Dispatches of a prefill chunk, a decode step or a verify step by "
@@ -296,8 +326,9 @@ _M_PHASE = _metrics.histogram(
     "'prefill.prepare' / 'prefill.dispatch', 'decode.prepare' / "
     "'decode.dispatch' (host arrays and uploads, then the program's "
     "call), 'wait' (blocked on a device result), 'deliver' (tokens "
-    "mirrored, streamed, blocks registered, sequences retired) and "
-    "'iter' (the iteration's own remainder). One observation a phase "
+    "mirrored, streamed, blocks registered, sequences retired), "
+    "'window.release' (a model with plane groups: window blocks given "
+    "back) and 'iter' (the iteration's own remainder). One observation a phase "
     "an iteration in which it ran; over any interval the sums add up "
     "to hvd_tpu_gen_step_seconds' host plus device sums.",
     labels=("phase",),
@@ -451,7 +482,7 @@ class GenSequence:
                  "top_p", "seed", "key", "sample_offset", "prefix_hashes",
                  "block_hashes", "cache_gen", "request_id", "trace",
                  "num_beams", "first_dispatch_at", "first_token_at",
-                 "state_slot")
+                 "state_slot", "wblocks", "wreleased")
 
     def __init__(self, seq_id: int, prompt: List[int], max_tokens: int,
                  eos_id: Optional[int], deadline_s: float,
@@ -497,6 +528,11 @@ class GenSequence:
         self.generated: List[int] = []
         self.logprobs: List[float] = []
         self.blocks: List[int] = []
+        #: the window group's blocks (a model with plane groups): entry
+        #: ``j`` holds logical block ``j`` as ``blocks[j]`` does, 0 once
+        #: given back; the first ``wreleased`` entries are
+        self.wblocks: List[int] = []
+        self.wreleased = 0
         #: this sequence's slot of the per-sequence state pools while it
         #: runs (None: not running, or the model declares no state)
         self.state_slot: Optional[int] = None
@@ -630,6 +666,20 @@ class ContinuousBatcher:
                     "the allocator has state slots but no snapshot pools "
                     "were given (kv_cache.make_state_pools)")
             self._copy_state = build_state_copy_program()
+        #: the window group's allocator and its window in tokens, for a
+        #: model with plane groups (its pools follow the first group's)
+        self._walloc = getattr(allocator, "window", None)
+        self._window = 0 if self._walloc is None \
+            else allocator.window_span * allocator.block_size
+        #: (group, window) of the attention sublayers a dispatch is
+        #: counted by in hvd_tpu_gen_paged_attn_*_blocks_total
+        self._attn_groups = (("full", None),) if self._walloc is None \
+            else (("full", None), (self._walloc.group, self._window))
+        if self._walloc is not None and (verify_program is not None
+                                         or beam_program is not None):
+            raise PlaneGroupsError(
+                "a verify or beam program keeps one block list a "
+                "sequence; this allocator has a window group")
         self._prefix_cache = bool(getattr(allocator, "prefix_cache", False))
         #: identity of the params object the last device call used —
         #: a hot-swap means cached K/V no longer matches what a cold
@@ -800,6 +850,11 @@ class ContinuousBatcher:
                     f"num_beams={num_beams}: beam search cannot carry "
                     f"per-sequence recurrent state (a fork shares and "
                     f"copies KV blocks, not a state)")
+            if self._walloc is not None:
+                raise PlaneGroupsError(
+                    f"num_beams={num_beams}: a beam fork shares and "
+                    f"copies one list of blocks, and this model's cache "
+                    f"has plane groups")
             if self._beam_prog is None:
                 raise ValueError(
                     "beam search is disabled on this engine (no beam "
@@ -973,6 +1028,11 @@ class ContinuousBatcher:
                 "recurrent state: the wire ships KV blocks, and a block "
                 "of this model's cache is worth nothing without the "
                 "state that follows it")
+        if self._walloc is not None:
+            raise PlaneGroupsError(
+                "the disagg KV transfer ships one pool's blocks, and "
+                "this model's cache has plane groups: a chain without "
+                "its window group's blocks cannot be continued")
 
     def export_kv_blocks(self, hashes: Sequence[str]):
         """Scheduler-thread body of ``POST /v1/kv/fetch`` (call via
@@ -1269,11 +1329,19 @@ class ContinuousBatcher:
             s.cache_len = 0
             s.blocks = []
             s.block_hashes = []
+            s.wblocks, s.wreleased = [], 0
             if self._prefix_cache:
                 s.blocks = self._alloc.match(s.prefix_hashes)
                 s.block_hashes = list(s.prefix_hashes[:len(s.blocks)])
                 s.prefilled = len(s.blocks) * self._alloc.block_size
                 s.cache_len = s.prefilled
+                if self._walloc is not None:
+                    # the window group's part of the hit: the blocks of
+                    # the window before the cut, nothing before them
+                    s.wblocks = self._walloc.match_tail(
+                        s.block_hashes, self._alloc.window_span)
+                    s.wreleased = len(s.wblocks) - sum(
+                        1 for b in s.wblocks if b)
                 # hit attribution: a block whose contents arrived over
                 # the disagg KV wire counts source=transfer until it
                 # recycles; everything else was local prefill work
@@ -1322,42 +1390,29 @@ class ContinuousBatcher:
         s = next((x for x in self._running if x.state == "prefill"), None)
         if s is None:
             return
-        # drain pending decode steps first: their emissions precede this
-        # prefill in device order, and the log/stream order should say so
-        # (it also makes preemption decisions below see current state)
+        spans = self._spans
+        # the chunk's host arrays do not wait for the step in flight: with
+        # the blocks there for the taking they are built while the device
+        # still runs it (a grow that has to preempt needs the pipeline
+        # drained, and waits for the drain below)
+        ready = None
+        if self._inflight and self._chunk_blocks_available(s):
+            with spans.span("gen.prefill.prepare"):
+                ready = self._prepare_chunk(s)
+        # drain pending decode steps before the dispatch: their emissions
+        # precede this prefill in device order, and the log/stream order
+        # should say so (it also makes preemption decisions below see
+        # current state)
         self._flush_inflight()
         if s.state != "prefill":
             return                # a device failure during the drain
-        spans = self._spans
-        total = len(s.prefill_tokens)
-        chunk = s.prefill_tokens[s.prefilled:s.prefilled + self.prefill_chunk]
-        live = len(chunk)
-        with spans.span("gen.prefill.prepare"):
-            need = self._alloc.blocks_for(s.prefilled + live) - len(s.blocks)
-            if need > 0 and not self._grow(s, need):
+        if ready is None:
+            with spans.span("gen.prefill.prepare"):
+                ready = self._prepare_chunk(s)
+            if ready is None:
                 return          # s itself was preempted; nothing to run
-            tokens = np.zeros((1, self.prefill_chunk), np.int32)
-            tokens[0, :live] = chunk
-            row = np.zeros((1, self.max_blocks), np.int32)
-            row[0, :len(s.blocks)] = s.blocks
-            # the resume path discards the sampled token (it was emitted
-            # before the eviction): force the cheap greedy branch
-            temp = np.asarray([0.0 if s.resume_decode else s.temperature],
-                              np.float32)
-            top_k = np.asarray([s.top_k], np.int32)
-            top_p = np.asarray([s.top_p], np.float32)
-            args = (
-                PagedCache(self._pools, jnp.asarray(row),
-                           jnp.asarray(np.asarray([s.prefilled], np.int32)),
-                           jnp.asarray(np.asarray([live], np.int32)),
-                           None if s.state_slot is None else jnp.asarray(
-                               np.asarray([s.state_slot], np.int32))),
-                jnp.asarray(tokens),
-                SampleParams(
-                    temperature=jnp.asarray(temp), top_k=jnp.asarray(top_k),
-                    top_p=jnp.asarray(top_p),
-                    key=jnp.asarray(s.key[None, :]),
-                    emitted=jnp.asarray([s.sample_offset], jnp.int32)))
+        live, total, cache, tokens, sample, cut = ready
+        args = (PagedCache(self._pools, *cache), tokens, sample)
         if s.request_id:
             _tracing.note_request(s.request_id)
         try:
@@ -1372,8 +1427,7 @@ class ContinuousBatcher:
                                             "prefilled": s.prefilled,
                                             "total": total}):
                 _FP_PREFILL.fire()
-                _M_SAMPLE_STEPS.labels(
-                    cut=sample_cut(temp, top_k, top_p)).inc()
+                _M_SAMPLE_STEPS.labels(cut=cut).inc()
                 if s.first_dispatch_at is None:
                     self._first_dispatch(s)
                 tok, logp = self._run_prefill(*args)
@@ -1382,6 +1436,50 @@ class ContinuousBatcher:
             return
         with spans.span("gen.deliver"):
             self._deliver_prefill(s, live, total, tok, logp, now)
+
+    def _chunk_blocks_available(self, s: GenSequence) -> bool:
+        """Whether ``s``'s next chunk can take its blocks (in every
+        plane group) without a preemption."""
+        live = min(self.prefill_chunk, len(s.prefill_tokens) - s.prefilled)
+        upto = self._alloc.blocks_for(s.prefilled + live)
+        return upto - len(s.blocks) <= self._alloc.available_blocks and (
+            self._walloc is None
+            or upto - len(s.wblocks) <= self._walloc.available_blocks)
+
+    def _prepare_chunk(self, s: GenSequence):
+        """The ``gen.prefill.prepare`` phase for ``s``'s next chunk: its
+        blocks, then its arguments on the device. ``(live, total, the
+        cache's fields after the pools, tokens, sampling, the sampling
+        epilogue's cut)``, or None when growing preempted ``s`` itself."""
+        total = len(s.prefill_tokens)
+        chunk = s.prefill_tokens[s.prefilled:s.prefilled + self.prefill_chunk]
+        live = len(chunk)
+        # the chunk's first column sits at s.prefilled: what lies a whole
+        # window behind it no column of the chunk reads
+        self._release_window(s, s.prefilled)
+        upto = self._alloc.blocks_for(s.prefilled + live)
+        if (upto > len(s.blocks) or self._short_of_window(s, upto)) \
+                and not self._grow(s, upto):
+            return None
+        tokens = np.zeros((1, self.prefill_chunk), np.int32)
+        tokens[0, :live] = chunk
+        # the resume path discards the sampled token (it was emitted
+        # before the eviction): force the cheap greedy branch
+        temp = np.asarray([0.0 if s.resume_decode else s.temperature],
+                          np.float32)
+        top_k = np.asarray([s.top_k], np.int32)
+        top_p = np.asarray([s.top_p], np.float32)
+        cache = (self._table_rows([s], 1),
+                 jnp.asarray(np.asarray([s.prefilled], np.int32)),
+                 jnp.asarray(np.asarray([live], np.int32)),
+                 None if s.state_slot is None else jnp.asarray(
+                     np.asarray([s.state_slot], np.int32)))
+        sample = SampleParams(
+            temperature=jnp.asarray(temp), top_k=jnp.asarray(top_k),
+            top_p=jnp.asarray(top_p), key=jnp.asarray(s.key[None, :]),
+            emitted=jnp.asarray([s.sample_offset], jnp.int32))
+        return (live, total, cache, jnp.asarray(tokens), sample,
+                sample_cut(temp, top_k, top_p))
 
     def _first_dispatch(self, s: GenSequence) -> None:
         """The request's wait for its first prefill chunk ends here:
@@ -1598,12 +1696,15 @@ class ContinuousBatcher:
         ``lengths`` tokens, ``chunk`` columns wide, into
         ``hvd_tpu_gen_paged_attn_blocks_total``."""
         table = self.max_seqs * self.max_blocks
-        read = table if kind not in self._reads_live else \
-            paged_attention.blocks_read(lengths, chunk,
-                                        self._alloc.block_size,
-                                        self.max_blocks)
-        _M_PAGED_BLOCKS.labels(kind="table").inc(table)
-        _M_PAGED_BLOCKS.labels(kind="read").inc(read)
+        for group, window in self._attn_groups:
+            read = table if kind not in self._reads_live else \
+                paged_attention.blocks_read(lengths, chunk,
+                                            self._alloc.block_size,
+                                            self.max_blocks, window)
+            _M_PAGED_BLOCKS.labels(kind="table").inc(table)
+            _M_PAGED_BLOCKS.labels(kind="read").inc(read)
+            _M_PAGED_GROUP_BLOCKS.labels(kind="table", group=group).inc(table)
+            _M_PAGED_GROUP_BLOCKS.labels(kind="read", group=group).inc(read)
 
     def _prepare_decode(self, span) -> List[GenSequence]:
         """What the plain and the speculative step prepare alike, under
@@ -1643,14 +1744,19 @@ class ContinuousBatcher:
             # at worst a few blocks of slack, never a correctness risk
             width = 1 if not self.spec else 1 + max(0, min(
                 self.spec_tokens, s.max_tokens - len(s.generated) - 1))
-            need = self._alloc.blocks_for(s.cache_len + pending + width) \
-                - len(s.blocks)
-            if need <= 0:
+            # steps in flight run ahead of the host's length, so the
+            # host's is the oldest position any of them still writes
+            self._release_window(s, s.cache_len)
+            upto = self._alloc.blocks_for(s.cache_len + pending + width)
+            need = upto - len(s.blocks)
+            if need <= 0 and not self._short_of_window(s, upto):
                 continue
             # available counts evictable cached blocks too: allocate
             # sacrifices those before the scheduler considers preempting
-            if need <= self._alloc.available_blocks:
-                s.blocks.extend(self._alloc.allocate(need))
+            if need <= self._alloc.available_blocks and (
+                    self._walloc is None or upto - len(s.wblocks)
+                    <= self._walloc.available_blocks):
+                self._grow(s, upto)
                 self._tables_dirty = True
                 continue
             # exhaustion. Preemption frees blocks of lanes the device
@@ -1659,7 +1765,7 @@ class ContinuousBatcher:
             if self._inflight:
                 self._flush_inflight()
                 return None     # lengths/membership moved: re-project
-            if self._grow(s, need):
+            if self._grow(s, upto):
                 self._tables_dirty = True
             return None         # membership changed either way
         return batch
@@ -1711,12 +1817,56 @@ class ContinuousBatcher:
         self._tables_dirty = True
 
     def _upload_tables(self) -> None:
-        tables = np.zeros((self.max_seqs, self.max_blocks), np.int32)
-        for i, s in enumerate(self._lanes):
-            if s is not None and s.state == "decode":
-                tables[i, :len(s.blocks)] = s.blocks
-        self._dtables = jnp.asarray(tables)
+        self._dtables = self._table_rows(
+            [s if s is not None and s.state == "decode" else None
+             for s in self._lanes], self.max_seqs)
         self._tables_dirty = False
+
+    def _table_rows(self, seqs, rows: int):
+        """The block tables of ``seqs`` (None: an empty row) on the
+        device, ``(rows, max_blocks)``: one array, or for a model with
+        plane groups one a group."""
+        lists = ("blocks",) if self._walloc is None else ("blocks", "wblocks")
+        out = []
+        for name in lists:
+            table = np.zeros((rows, self.max_blocks), np.int32)
+            for i, s in enumerate(seqs):
+                if s is not None:
+                    held = getattr(s, name)
+                    table[i, :len(held)] = held
+            out.append(jnp.asarray(table))
+        return out[0] if self._walloc is None else tuple(out)
+
+    # -- the window group ------------------------------------------------------
+
+    def _short_of_window(self, s: GenSequence, upto: int) -> bool:
+        return self._walloc is not None and upto > len(s.wblocks)
+
+    def _release_window(self, s: GenSequence, position: int) -> None:
+        """Give back ``s``'s window-group blocks whose last position
+        lies a whole window or more behind ``position``, the oldest
+        position still to be written: no query from there on reads
+        them. Indexed ones park in the window allocator's cached list."""
+        if self._walloc is None:
+            return
+        upto = min((position - self._window + 1) // self._alloc.block_size,
+                   len(s.wblocks))
+        if upto <= s.wreleased:
+            return
+        with self._spans.span("gen.window.release", seq=s.id,
+                              blocks=upto - s.wreleased):
+            self._walloc.release(s.wblocks[s.wreleased:upto])
+            s.wblocks[s.wreleased:upto] = [0] * (upto - s.wreleased)
+            s.wreleased = upto
+
+    def _free_blocks(self, s: GenSequence) -> None:
+        """Every block ``s`` holds, in every group, back to its pool."""
+        if s.blocks:
+            self._alloc.free(s.blocks)
+            s.blocks = []
+        if s.wblocks:
+            self._walloc.free([b for b in s.wblocks if b])
+            s.wblocks, s.wreleased = [], 0
 
     def _flush_inflight(self) -> None:
         if not self._inflight:
@@ -2094,6 +2244,8 @@ class ContinuousBatcher:
         if p is not self._last_params:
             if self._last_params is not _UNSET and self._prefix_cache:
                 self._alloc.reset_cache()
+                if self._walloc is not None:
+                    self._walloc.reset_cache()
             self._last_params = p
         return p
 
@@ -2131,6 +2283,8 @@ class ContinuousBatcher:
                 h = chain_hash(s.block_hashes[-1] if j else None,
                                full[j * bs:(j + 1) * bs])
             self._alloc.register(s.blocks[j], h)
+            if self._walloc is not None and s.wblocks[j]:
+                self._walloc.register(s.wblocks[j], h)
             s.block_hashes.append(h)
 
     def _reset_device(self) -> None:
@@ -2158,9 +2312,12 @@ class ContinuousBatcher:
         # the rebuilt pools are zeroed: every indexed block's contents
         # are gone, so the content index must go with them
         self._alloc.reset_cache()
+        if self._walloc is not None:
+            self._walloc.reset_cache()
 
-    def _grow(self, s: GenSequence, need: int) -> bool:
-        """Allocate ``need`` blocks for ``s``, preempting the youngest
+    def _grow(self, s: GenSequence, upto: int) -> bool:
+        """Allocate what ``s`` lacks of ``upto`` logical blocks (in
+        every plane group, or in none), preempting the youngest
         block-holding *younger* peer on exhaustion; with none left,
         ``s`` preempts itself. Returns False when ``s`` was preempted.
         Callers guarantee the pipeline is drained before a preempting
@@ -2175,7 +2332,15 @@ class ContinuousBatcher:
         the allocation that just failed) — no recompute churn."""
         while True:
             try:
-                s.blocks.extend(self._alloc.allocate(need))
+                got = self._alloc.allocate(upto - len(s.blocks))
+                if self._walloc is not None:
+                    try:
+                        s.wblocks.extend(self._walloc.allocate(
+                            upto - len(s.wblocks)))
+                    except BlocksExhaustedError:
+                        self._alloc.free(got)
+                        raise
+                s.blocks.extend(got)
                 return True
             except BlocksExhaustedError:
                 victims = [x for x in self._running
@@ -2194,8 +2359,7 @@ class ContinuousBatcher:
         except Exception as e:  # noqa: BLE001
             self._deliver_error(s, e)
             return
-        self._alloc.free(s.blocks)
-        s.blocks = []
+        self._free_blocks(s)
         s.block_hashes = []
         # recompute: the readmission restores a snapshot or zeros
         self._release_state(s)
@@ -2263,9 +2427,7 @@ class ContinuousBatcher:
             self._retire(s, device_synced=True)
 
     def _retire(self, s: GenSequence, device_synced: bool = True) -> None:
-        if s.blocks:
-            self._alloc.free(s.blocks)
-            s.blocks = []
+        self._free_blocks(s)
         self._release_state(s)
         if s in self._running:
             self._running.remove(s)

@@ -460,3 +460,59 @@ def test_v5e_latent_pool_is_row_major_and_uncopied(one_v5e_chip,
     # both programs fit beside each other's arguments with room to spare
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.5e9, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("name", ["prefill", "decode"])
+def test_v5e_plane_groups_alias_both_pools_and_take_the_grouped_kernel(
+        one_v5e_chip, no_compile_cache, monkeypatch, name):
+    """Command A+ (ISSUE 33) at its published widths and the benchmark's
+    lanes, chunk, block and table, one sliding and one full layer: the
+    two plane groups' four pools alias input to output and none is
+    copied; the decode program attends through the paged kernel on both
+    planes (its grouped layout: 16 query heads a key-value head, and on
+    the sliding plane the window), gathering no table; the prefill
+    chunk's 8192 query rows a key-value head walk the keys by blocks in
+    XLA, and its temporaries stay far under a gathered table's 8.9 GB of
+    float32 scores."""
+    from horovod_tpu.models import CommandAPlus, CommandAPlusConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = CommandAPlusConfig(
+        vocab_size=32768, num_hidden_layers=2,
+        layer_types=("sliding_attention", "full_attention"),
+        held_experts=(0, 2), table_positions=33792)
+    model = CommandAPlus(cfg)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_v5e_chip), tree)
+
+    lanes, width = 16, 33792 // 64
+    with jax.enable_x64(False):     # the chip runs without x64
+        params = on_chip(jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), _i32(1, 8))))
+        pools = on_chip(jax.eval_shape(
+            lambda: kvc.make_pools(cfg, (8192, 1185), 64)))
+        assert [p.shape for p in pools] == [(1, 8192, 64, 1024)] * 2 \
+            + [(1, 1185, 64, 1024)] * 2
+        program, args = {
+            "prefill": (kvc.build_prefill_program(model), (
+                PagedCache(pools, (_i32(1, width), _i32(1, width)),
+                           _i32(1), _i32(1)), _i32(1, 512), _greedy(1))),
+            "decode": (kvc.build_decode_program(model, DECODE_WIDTH), (
+                pools, (_i32(lanes, width), _i32(lanes, width)),
+                _state([0] * lanes, [0] * lanes, [0] * lanes,
+                       [0] * lanes))),
+        }[name]
+        compiled = program.lower(params, *on_chip(args)).compile()
+    hlo = compiled.as_text()
+    for pool in (pools[0], pools[2]):
+        aliased, pool_params, offenders = _pool_structure(hlo, pool.shape)
+        assert aliased == pool_params and len(pool_params) == 2
+        assert not offenders, offenders[:6]
+    kernels = len(re.findall(r'custom_call_target="tpu_custom_call"', hlo))
+    assert kernels == (2 if name == "decode" else 0), kernels
+    # no lane's table gathered whole: (lanes, 33792 slots, 1024)
+    assert "[16,33792,1024]" not in hlo and "[1,33792,1024]" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
