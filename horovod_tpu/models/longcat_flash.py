@@ -30,6 +30,18 @@ width; past ``r (dn + dv) / (2 r - dn - dv)`` queries a chunk (171 at
 the published widths) it is the cheaper of the two, by the count of
 multiplications alone.
 
+On the paged path the absorbed form gathers the lane's block table and
+masks a slot by its position. The expanded form gathers nothing: it
+**walks** the lane's blocks, :data:`KEY_BLOCK` slots at a time, from
+block 0 to the one that holds the chunk's last live position
+(``lengths + live - 1``: a dynamic trip count), reads the rows where
+they lie in the pool the chunk has just written, rebuilds that key
+block's keys and values, masks a slot by its position and folds the
+block into a float32 running maximum, sum and accumulator
+(:meth:`LatentAttention._walked`). A chunk costs what the sequence
+holds and not the table, and the float32 scores that exist at a time
+are a head group's against one key block.
+
 Weights are created and held in ``param_dtype`` (bfloat16 when served;
 the router and its correction bias in float32) and nothing casts a
 weight inside a call: a matmul takes them as they lie. The expert layer
@@ -39,7 +51,8 @@ its own experts' part. Routing counts leave the model through the flax
 collection ``moe_stats`` (one int32 vector a layer).
 
 Named scopes, under flax's module scopes:
-``layer_<i>/attn_<j>/{q_proj,kv_write,kv_gather,absorb,attention,out_proj}``,
+``layer_<i>/attn_<j>/{q_proj,kv_write,kv_gather,absorb,attention,out_proj}``
+(``kv_gather`` and ``absorb`` in the absorbed form only),
 ``layer_<i>/mlp_<j>``, ``layer_<i>/moe/{router,sort,experts,identity,combine}``,
 ``head``.
 """
@@ -59,10 +72,15 @@ from .transformer import CacheSpec, PagedCache
 Dtype = Any
 
 #: heads the expanded form of latent attention rebuilds keys and values
-#: for at a time: its float32 scores are heads x chunk x table x 4 bytes
-#: (0.55 GB at 16 x 512 x 16896); a head count it does not divide is
-#: taken whole
+#: for at a time: its float32 scores are heads x queries x keys x 4
+#: bytes; a head count it does not divide is taken whole
 HEAD_GROUP = 16
+#: slots of a lane's table the paged expanded form scores at a time, in
+#: whole pool blocks (8 of 64): a head group's float32 scores against
+#: them are 16 x 512 x 512 x 4 B = 16.8 MB, which the TPU compiler keeps
+#: out of HBM where all 64 heads' 67 MB pass through it three times
+KEY_BLOCK = 512
+_NEG_INF = jnp.finfo(jnp.float32).min
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,6 +131,22 @@ class LongcatFlashConfig:
         """Whether a chunk of ``chunk`` queries takes the expanded form."""
         r, dn, dv = self.kv_lora_rank, self.qk_nope_head_dim, self.v_head_dim
         return chunk * (2 * r - dn - dv) > r * (dn + dv)
+
+    def prefill_keys_walked(self, chunk: int, length: int, live: int,
+                            block_size: int, max_blocks: int) -> int:
+        """Slots of a lane's table that one attention of a paged chunk
+        reads: ``chunk`` columns wide, ``live`` of them live, after
+        ``length`` tokens. The expanded form walks to the chunk's last
+        live position, rounded up to the key block (nothing for a dead
+        lane); the absorbed form gathers the whole table. Host
+        arithmetic for the scheduler's counter
+        (``hvd_tpu_gen_prefill_attn_keys_total``), the same rule and the
+        same walk as :meth:`LatentAttention._walked`."""
+        table = max_blocks * block_size
+        if not self.expands(chunk):
+            return table
+        keys = _key_block(block_size)
+        return min(-(-(length + live) // keys) * keys, table) if live else 0
 
 
 class LatentAttention(nn.Module):
@@ -174,19 +208,18 @@ class LatentAttention(nn.Module):
                 blocks = jnp.where(valid, blocks, 0)
                 pool = pool.at[plane, blocks, positions % block_size].set(
                     new_rows)
+        scale = (dn + dr) ** -0.5
         if layer_cache is None:
-            rows = new_rows
+            form = self._expanded if cfg.expands(C) else self._absorbed
+            ctx = form(q_nope, q_rope, new_rows, mask, w_uk, w_uv, scale)
+        elif cfg.expands(C):
+            ctx = self._walked(q_nope, q_rope, pool, plane, block_tables,
+                               positions, live, w_uk, w_uv, scale)
         else:
             with jax.named_scope("kv_gather"):
                 # every table slot, from the pool just written:
                 # position t of a sequence lives at index t
                 rows = pool[plane, block_tables].reshape(B, -1, width)
-
-        scale = (dn + dr) ** -0.5
-        if cfg.expands(C):
-            ctx = self._expanded(q_nope, q_rope, rows, mask, w_uk, w_uv,
-                                 scale)
-        else:
             ctx = self._absorbed(q_nope, q_rope, rows, mask, w_uk, w_uv,
                                  scale)
         with jax.named_scope("out_proj"):
@@ -215,40 +248,114 @@ class LatentAttention(nn.Module):
     def _expanded(self, q_nope, q_rope, rows, mask, w_uk, w_uv, scale):
         """Keys and values rebuilt from the rows, a group of heads at a
         time, attention at head width."""
-        cfg = self.cfg
-        r, dr, H = (cfg.kv_lora_rank, cfg.qk_rope_head_dim,
-                    cfg.num_attention_heads)
-        g = HEAD_GROUP if H % HEAD_GROUP == 0 else H
+        r, dr = self.cfg.kv_lora_rank, self.cfg.qk_rope_head_dim
         c, kr = rows[..., :r], rows[..., r:r + dr]
 
-        def by_group(a, axis):       # split the head axis, groups first
-            a = a.reshape(a.shape[:axis] + (H // g, g) + a.shape[axis + 1:])
-            return jnp.moveaxis(a, axis, 0)
-
-        def group(args):
-            qn, qr, uk, uv = args
-            k_nope = jnp.einsum("btr,rhd->bthd", c, uk)
-            v = jnp.einsum("btr,rhd->bthd", c, uv)
-            scores = (jnp.einsum("bshd,bthd->bhst", qn, k_nope,
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("bshd,btd->bhst", qr, kr,
-                                   preferred_element_type=jnp.float32))
-            probs = _masked_softmax(scores * scale, mask).astype(v.dtype)
+        def group(qn, qr, uk, uv):
+            scores, v = _rebuilt_scores(qn, qr, c, kr, uk, uv, scale)
+            probs = _masked_softmax(scores, mask).astype(v.dtype)
             return jnp.einsum("bhst,bthd->bshd", probs, v)
 
         with jax.named_scope("attention"):
-            ctx = jax.lax.map(group, (by_group(q_nope, 2),
-                                      by_group(q_rope, 2),
-                                      by_group(w_uk, 1), by_group(w_uv, 1)))
-            # (groups, B, S, g, dv) -> (B, S, H, dv)
-            ctx = jnp.moveaxis(ctx, 0, 2)
-            return ctx.reshape(ctx.shape[:2] + (H, ctx.shape[-1]))
+            return _by_head_groups(group, q_nope, q_rope, w_uk, w_uv)
+
+    def _walked(self, q_nope, q_rope, pool, plane, tables, positions, live,
+                w_uk, w_uv, scale):
+        """The expanded form over the paged pool: plane ``plane`` of
+        ``pool`` through ``tables`` ``(B, max_blocks)``, walked by key
+        blocks under a running softmax from block 0 to the block of the
+        last live column of any live lane, so its cost follows what the
+        sequences hold and a table entry past it is never read; with no
+        live lane nothing is read. Every softmax is
+        :func:`_masked_softmax`'s, a key block's at a time, so the model
+        keeps one softmax for whoever holds its precision (the
+        benchmark's tolerance tool replaces that function)."""
+        r, dr = self.cfg.kv_lora_rank, self.cfg.qk_rope_head_dim
+        B, C = positions.shape
+        block_size, width = pool.shape[2:]
+        keys = _key_block(block_size)
+        per = keys // block_size
+        last = positions[:, 0] + live - 1
+        end = jnp.max(jnp.where(live > 0, last // keys + 1, 0))
+
+        def group(qn, qr, uk, uv):
+            def step(i, carry):
+                m, l, acc = carry
+                blocks = jnp.take(tables, i * per + jnp.arange(per), axis=1,
+                                  mode="fill", fill_value=0)    # (B, per)
+                rows = pool[plane, blocks].reshape(B, keys, width)
+                scores, v = _rebuilt_scores(
+                    qn, qr, rows[..., :r], rows[..., r:r + dr], uk, uv, scale)
+                t = i * keys + jnp.arange(keys)
+                seen = (t[None, None, :]
+                        <= positions[:, :, None])[:, None]  # (B, 1, C, keys)
+                # the key block's own softmax, then its place in the
+                # running one: its row maximum, and its row sum read off
+                # the largest probability (exp(0) over the sum). A row
+                # that sees nothing of the block weighs exp(-huge) = 0
+                probs = _masked_softmax(scores, seen)
+                m_blk = jnp.max(jnp.where(seen, scores, _NEG_INF), axis=-1)
+                l_blk = 1.0 / jnp.max(probs, axis=-1)
+                m_new = jnp.maximum(m, m_blk)
+                old = jnp.exp(m - m_new)
+                new = l_blk * jnp.exp(m_blk - m_new)
+                acc = acc * old[..., None] + new[..., None] * jnp.einsum(
+                    "bhst,bthd->bhsd", probs.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32)
+                return m_new, l * old + new, acc
+
+            shape = (B, qn.shape[2], C)
+            _, l, acc = jax.lax.fori_loop(
+                0, end, step,
+                (jnp.full(shape, _NEG_INF, jnp.float32),
+                 jnp.zeros(shape, jnp.float32),
+                 jnp.zeros(shape + (uv.shape[-1],), jnp.float32)))
+            out = acc / jnp.maximum(l, 1e-30)[..., None]
+            return jnp.swapaxes(out, 1, 2).astype(pool.dtype)
+
+        with jax.named_scope("attention"):
+            return _by_head_groups(group, q_nope, q_rope, w_uk, w_uv)
+
+
+def _key_block(block_size: int) -> int:
+    """Slots of one key block of the walk: :data:`KEY_BLOCK` in whole
+    pool blocks, one block at the least."""
+    return max(1, KEY_BLOCK // block_size) * block_size
+
+
+def _rebuilt_scores(q_nope, q_rope, c, kr, w_uk, w_uv, scale):
+    """``(scores (B, h, S, T) float32, v (B, T, h, dv))`` of a group of
+    heads against ``T`` latent rows ``[c | kr]``."""
+    k_nope = jnp.einsum("btr,rhd->bthd", c, w_uk)
+    v = jnp.einsum("btr,rhd->bthd", c, w_uv)
+    scores = (jnp.einsum("bshd,bthd->bhst", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bshd,btd->bhst", q_rope, kr,
+                           preferred_element_type=jnp.float32))
+    return scores * scale, v
+
+
+def _by_head_groups(attend, q_nope, q_rope, w_uk, w_uv):
+    """``attend(q_nope, q_rope, w_uk, w_uv) -> (B, S, g, dv)`` over the
+    heads, :data:`HEAD_GROUP` at a time: ``(B, S, H, dv)``."""
+    H = q_nope.shape[2]
+    g = HEAD_GROUP if H % HEAD_GROUP == 0 else H
+
+    def split(a, axis):             # split the head axis, groups first
+        a = a.reshape(a.shape[:axis] + (H // g, g) + a.shape[axis + 1:])
+        return jnp.moveaxis(a, axis, 0)
+
+    ctx = jax.lax.map(lambda args: attend(*args),
+                      (split(q_nope, 2), split(q_rope, 2),
+                       split(w_uk, 1), split(w_uv, 1)))
+    # (groups, B, S, g, dv) -> (B, S, H, dv)
+    ctx = jnp.moveaxis(ctx, 0, 2)
+    return ctx.reshape(ctx.shape[:2] + (H, ctx.shape[-1]))
 
 
 def _masked_softmax(scores, mask):
     """Causal softmax in float32."""
-    scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
-    return jax.nn.softmax(scores, axis=-1)
+    return jax.nn.softmax(jnp.where(mask, scores, _NEG_INF), axis=-1)
 
 
 class ExpertLayer(nn.Module):
@@ -343,13 +450,17 @@ class LongcatFlash(nn.Module):
             pool = None
         else:
             # incremental: the chunk starts at each sequence's cache
-            # length; gathered slot t holds absolute position t, and a
-            # query at position p attends to every t <= p
+            # length; slot t of a lane's table holds absolute position
+            # t, and a query at position p attends to every t <= p. The
+            # expanded form masks a key block by position as it walks;
+            # the absorbed form masks the table it gathers
             positions = cache.lengths[:, None] + jnp.arange(S)[None, :]
             (pool,) = cache.pools
-            t_max = cache.block_tables.shape[1] * pool.shape[2]
-            mask = (jnp.arange(t_max)[None, None, None, :]
-                    <= positions[:, None, :, None])
+            mask = None
+            if not cfg.expands(S):
+                t_max = cache.block_tables.shape[1] * pool.shape[2]
+                mask = (jnp.arange(t_max)[None, None, None, :]
+                        <= positions[:, None, :, None])
             valid = jnp.arange(S)[None, :] < cache.live[:, None]
         for i in range(cfg.num_layers):
             layer = DoubleLayer(cfg, name=f"layer_{i}")

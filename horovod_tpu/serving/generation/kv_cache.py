@@ -947,6 +947,21 @@ def reads_live_blocks(program, pools) -> bool:
         rows, pool.shape[2], pool.shape[3], pool.dtype)
 
 
+def prefill_keys_walked(program, chunk: int, length: int, live: int,
+                        block_size: int, max_blocks: int) -> int:
+    """Slots of a lane's block table that one attention of the prefill
+    ``program`` reads for a chunk ``chunk`` columns wide, ``live`` of
+    them live, after ``length`` tokens: the model's own arithmetic
+    (``cfg.prefill_keys_walked``, the forward's rule and its walk, hung
+    on the program by its builder), so the scheduler counts what the
+    program does without lowering it; the whole table for a model whose
+    prefill gathers it whatever the sequence holds."""
+    walked = getattr(program, "keys_walked", None)
+    if walked is None:
+        return max_blocks * block_size
+    return int(walked(chunk, length, live, block_size, max_blocks))
+
+
 @functools.lru_cache(maxsize=8)
 def build_program(model):
     """The raw-logits jitted incremental forward.
@@ -1230,7 +1245,9 @@ def build_prefill_program(model):
         token, logprob = sample_tokens(logits, sample)
         return (token, logprob, cache, *stats)
 
-    return jax.jit(_prefill, donate_argnums=(1,))
+    program = jax.jit(_prefill, donate_argnums=(1,))
+    program.keys_walked = getattr(model.cfg, "prefill_keys_walked", None)
+    return program
 
 
 @functools.lru_cache(maxsize=8)

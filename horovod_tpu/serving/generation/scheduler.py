@@ -184,8 +184,8 @@ from ..batcher import DeadlineExceededError, QueueFullError
 from .kv_cache import (BlockAllocator, BlocksExhaustedError, DecodeState,
                        PerSequenceStateError, PlaneGroupsError, SampleParams,
                        build_state_copy_program, chain_hash,
-                       count_snapshots, gather_blocks, reads_live_blocks,
-                       sample_cut, scatter_blocks)
+                       count_snapshots, gather_blocks, prefill_keys_walked,
+                       reads_live_blocks, sample_cut, scatter_blocks)
 
 _M_TOKENS = _metrics.counter(
     "hvd_tpu_gen_tokens_total",
@@ -251,6 +251,18 @@ _M_PAGED_GROUP_BLOCKS = _metrics.counter(
     "lane's window, not at 0. The unsplit counter is the sum over "
     "groups, one sublayer of each.",
     labels=("kind", "group"))
+_M_PREFILL_KEYS = _metrics.counter(
+    "hvd_tpu_gen_prefill_attn_keys_total",
+    "Key slots one attention sublayer of a prefill chunk had before it, "
+    "summed over dispatches: kind='table' every slot of the lane's "
+    "block table (max_blocks x block_size, what a program that gathers "
+    "the table reads whatever the sequence holds), kind='walked' the "
+    "slots the program's attention reads: where it walks the lane's "
+    "blocks, the chunk's last live position rounded up to the walk's "
+    "key block; where it does not, the whole table. walked/table is "
+    "the share of the table a chunk pays for: 1 means the prefill "
+    "program does not walk.",
+    labels=("kind",))
 _M_SAMPLE_STEPS = _metrics.counter(
     "hvd_tpu_gen_sample_steps_total",
     "Dispatches of a prefill chunk, a decode step or a verify step by "
@@ -1427,6 +1439,7 @@ class ContinuousBatcher:
                                             "prefilled": s.prefilled,
                                             "total": total}):
                 _FP_PREFILL.fire()
+                self._count_prefill_keys(s.prefilled, live)
                 _M_SAMPLE_STEPS.labels(cut=cut).inc()
                 if s.first_dispatch_at is None:
                     self._first_dispatch(s)
@@ -1705,6 +1718,17 @@ class ContinuousBatcher:
             _M_PAGED_BLOCKS.labels(kind="read").inc(read)
             _M_PAGED_GROUP_BLOCKS.labels(kind="table", group=group).inc(table)
             _M_PAGED_GROUP_BLOCKS.labels(kind="read", group=group).inc(read)
+
+    def _count_prefill_keys(self, length: int, live: int) -> None:
+        """One dispatch of the prefill program for a chunk of ``live``
+        columns after ``length`` tokens, into
+        ``hvd_tpu_gen_prefill_attn_keys_total``."""
+        block_size = self._alloc.block_size
+        _M_PREFILL_KEYS.labels(kind="table").inc(
+            self.max_blocks * block_size)
+        _M_PREFILL_KEYS.labels(kind="walked").inc(prefill_keys_walked(
+            self._prefill_prog, self.prefill_chunk, length, live,
+            block_size, self.max_blocks))
 
     def _prepare_decode(self, span) -> List[GenSequence]:
         """What the plain and the speculative step prepare alike, under

@@ -4,7 +4,9 @@ plain reference (``perfbench/reference/longcat_flash.py``, float32,
 
 (a) full forward against the reference, and departures caught;
 (b) chunked prefill then decode through ``GenerationEngine``;
-(c) absorbed against expanded latent attention on the same cache;
+(c) absorbed against expanded latent attention on the same cache, and
+    the expanded form's walk of the lane's blocks (ISSUE 34) against the
+    whole table gathered;
 (d) the shares of a layer's experts add up to the uncut layer;
 (e) dropless under a router that sends most tokens to one expert;
 (f) the routing counters; (g) the latent pool over the disagg wire;
@@ -25,7 +27,8 @@ import jax.numpy as jnp
 
 from horovod_tpu import metrics as M
 from horovod_tpu.models import LongcatFlash, LongcatFlashConfig
-from horovod_tpu.models.longcat_flash import HEAD_GROUP
+from horovod_tpu.models import longcat_flash as lf
+from horovod_tpu.models.longcat_flash import HEAD_GROUP, LatentAttention
 from horovod_tpu.models.transformer import PagedCache
 from horovod_tpu.parallel.moe import (STATS_FIELDS, TILE, held_experts_mlp,
                                       route_topk)
@@ -255,6 +258,199 @@ def test_absorbed_equals_expanded_on_the_same_cache(split_params):
     want = ref.forward(split_params["params"], jnp.asarray(seq), SETTINGS)
     np.testing.assert_allclose(absorbed[0], np.asarray(want)[0], rtol=0,
                                atol=TOL)
+
+
+# -- (c) the walk of the paged expanded form -----------------------------------
+
+#: one double layer of ``SPLIT``; blocks of 4 slots, key blocks of 8 (two
+#: blocks), a chunk of 8, a table of 10 blocks: 40 slots, 5 key blocks
+WALK = dataclasses.replace(SPLIT, num_layers=1, max_position_embeddings=40)
+WALK_BS, WALK_KEYS, WALK_CHUNK, WALK_MAX_BLOCKS = 4, 8, 8, 10
+WALK_TABLE = WALK_MAX_BLOCKS * WALK_BS
+#: a lane's blocks in no order (0 is the null block); a block of large
+#: rows, which a walked key block may hold past the sequence; a block of
+#: NaN, which no walk may touch
+WALK_BLOCKS, JUNK, POISON = [7, 3, 12, 5, 9, 1, 14, 2, 11, 6], 21, 22
+
+
+@pytest.fixture
+def key_blocks_of_8(monkeypatch):
+    monkeypatch.setattr(lf, "KEY_BLOCK", WALK_KEYS)
+    assert WALK.expands(WALK_CHUNK) and not WALK.expands(2)
+
+
+@pytest.fixture(scope="module")
+def walk_params():
+    return LongcatFlash(WALK).init(jax.random.PRNGKey(1),
+                                   jnp.zeros((1, 4), jnp.int32))
+
+
+def _seeded_pool():
+    """Every block holds seeded rows, as a pool in use does."""
+    (pool,) = kvc.make_pools(WALK, 24, WALK_BS)
+    rows = np.random.RandomState(0).standard_normal(pool.shape)
+    rows[:, JUNK] *= 50.0
+    rows[:, POISON] = np.nan
+    return jnp.asarray(rows, pool.dtype)
+
+
+def _whole_table(self, q_nope, q_rope, pool, plane, tables, positions, live,
+                 w_uk, w_uv, scale):
+    """What the walk replaces: every slot of the table gathered, the
+    dense form under ``_masked_softmax`` over a table-wide mask."""
+    rows = pool[plane, tables].reshape(positions.shape[0], -1, pool.shape[3])
+    mask = (jnp.arange(rows.shape[1])[None, None, None, :]
+            <= positions[:, None, :, None])
+    return self._expanded(q_nope, q_rope, rows, mask, w_uk, w_uv, scale)
+
+
+def _paged_chunk(params, pool, tables, lengths, live, tokens):
+    """One paged chunk through the whole model, traced anew."""
+    def run(pool, tokens):
+        cache = PagedCache((pool,), jnp.asarray(tables, jnp.int32),
+                           jnp.asarray(lengths, jnp.int32),
+                           jnp.asarray(live, jnp.int32))
+        (logits, cache), _ = LongcatFlash(WALK).apply(
+            params, tokens, cache=cache, mutable=["moe_stats"])
+        return logits, cache.pools[0]
+
+    logits, pool = jax.jit(run)(pool, jnp.asarray(tokens, jnp.int32))
+    return np.asarray(logits), np.asarray(pool)
+
+
+@pytest.mark.parametrize("prefix,live", [
+    (0, 8),                         # nothing before the chunk
+    (5, 6),                         # ends inside a key block
+    (24, 8),                        # several key blocks before it
+    (WALK_TABLE - WALK_CHUNK, 8),   # the last chunk the table holds
+    (16, 3),                        # pad columns
+    (13, 0),                        # a dead lane
+], ids=["prefix_0", "ends_inside", "deep", "table_end", "pad", "dead"])
+def test_walk_matches_the_whole_table_form(walk_params, key_blocks_of_8,
+                                           monkeypatch, prefix, live):
+    """A chunk's logits at its live columns and the rows it leaves: the
+    walk against the gathered table on the same pool. The walk's table
+    names ``JUNK`` where its last key block reaches past the sequence
+    (read and masked) and ``POISON`` from there on (never read: one NaN
+    row would show in every output, which is what proves the walk
+    stops); the oracle's table is clean, since a gather multiplies every
+    slot in."""
+    held = -(-(prefix + live) // WALK_BS) if live else 0
+    walked = WALK.prefill_keys_walked(WALK_CHUNK, prefix, live, WALK_BS,
+                                      WALK_MAX_BLOCKS) // WALK_BS
+    pool = _seeded_pool()
+    tokens = np.random.RandomState(2).randint(0, WALK.vocab_size,
+                                              (1, WALK_CHUNK))
+    walk_table = [WALK_BLOCKS[:held] + [JUNK] * (walked - held)
+                  + [POISON] * (WALK_MAX_BLOCKS - walked)]
+    got, got_pool = _paged_chunk(walk_params, pool, walk_table, [prefix],
+                                 [live], tokens)
+    monkeypatch.setattr(LatentAttention, "_walked", _whole_table)
+    clean_table = [WALK_BLOCKS[:held] + [0] * (WALK_MAX_BLOCKS - held)]
+    want, want_pool = _paged_chunk(walk_params, pool, clean_table, [prefix],
+                                   [live], tokens)
+    assert np.isfinite(got).all()       # pad columns and a dead lane too
+    np.testing.assert_allclose(got[0, :live], want[0, :live], rtol=0,
+                               atol=TOL)
+    # live rows went where the table says, the rest to the null block
+    changed = {int(b) for b in np.unique(np.nonzero(~np.isclose(
+        got_pool, np.asarray(pool), equal_nan=True))[1])}
+    assert changed <= set(WALK_BLOCKS[prefix // WALK_BS:held]) | {0}
+    assert (0 in changed) == (live < WALK_CHUNK)
+    np.testing.assert_allclose(got_pool[:, 1:JUNK], want_pool[:, 1:JUNK],
+                               rtol=0, atol=TOL)
+
+
+def _key_blocks_read(lengths, live):
+    """The key blocks one walk reads, found by poisoning one at a time:
+    a NaN row reaches every output through ``P x V`` even where its slot
+    is masked. Every lane has ten blocks of its own."""
+    rng = np.random.RandomState(3)
+    lanes, H = len(lengths), WALK.num_attention_heads
+    f32 = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape), jnp.float32)
+    q_nope, q_rope = (f32(lanes, WALK_CHUNK, H, WALK.qk_nope_head_dim),
+                      f32(lanes, WALK_CHUNK, H, WALK.qk_rope_head_dim))
+    w_uk, w_uv = (f32(WALK.kv_lora_rank, H, WALK.qk_nope_head_dim),
+                  f32(WALK.kv_lora_rank, H, WALK.v_head_dim))
+    tables = 1 + np.arange(lanes * WALK_MAX_BLOCKS).reshape(lanes, -1)
+    positions = jnp.asarray(lengths)[:, None] + jnp.arange(WALK_CHUNK)[None]
+    per, read = WALK_KEYS // WALK_BS, set()
+    for kb in range(WALK_MAX_BLOCKS // per):
+        rows = np.random.RandomState(4).standard_normal(
+            kvc.make_pools(WALK, 1 + tables.size, WALK_BS)[0].shape)
+        rows[:, tables[:, kb * per:(kb + 1) * per].ravel()] = np.nan
+        out = LatentAttention(WALK)._walked(
+            q_nope, q_rope, jnp.asarray(rows, jnp.float32), 1,
+            jnp.asarray(tables), positions, jnp.asarray(live), w_uk, w_uv,
+            0.3)
+        if not np.isfinite(np.asarray(out)).all():
+            read.add(kb)
+    return read
+
+
+@pytest.mark.parametrize("lengths,live", [
+    ([0], [1]), ([0], [8]), ([7], [1]), ([7], [2]), ([20], [4]), ([20], [5]),
+    ([32], [8]), ([11], [0]), ([3, 26, 9], [8, 5, 0])])
+def test_trip_count_follows_lengths_plus_live(key_blocks_of_8, lengths, live):
+    """The walk reads key blocks 0 .. ceil((length + live) / keys) - 1 of
+    the deepest live lane and no other, and the configuration's host
+    arithmetic (what the scheduler's counter adds a chunk) says the
+    same of every lane."""
+    walked = [WALK.prefill_keys_walked(WALK_CHUNK, n, c, WALK_BS,
+                                       WALK_MAX_BLOCKS)
+              for n, c in zip(lengths, live)]
+    assert walked == [-(-(n + c) // WALK_KEYS) * WALK_KEYS if c else 0
+                      for n, c in zip(lengths, live)]
+    read = _key_blocks_read(lengths, live)
+    assert read == set(range(max(walked) // WALK_KEYS))
+
+
+def test_keys_walked_at_the_cells_sizes():
+    # blocks of 64, key blocks of 512, a table of 264 blocks, a chunk of
+    # 512: the chunk's last live position rounded up to the key block
+    walked = lambda n, c, chunk=512: LongcatFlashConfig(  # noqa: E731
+        ).prefill_keys_walked(chunk, n, c, 64, 264)
+    assert walked(0, 512) == 512 and walked(0, 1) == 512
+    assert walked(512, 1) == 1024 and walked(4096, 300) == 4608
+    assert walked(16384, 512) == 16896
+    assert walked(4096, 0) == 0                     # a dead lane
+    # a chunk too narrow to expand gathers the table
+    assert walked(4096, 2, chunk=2) == 16896
+    # a table that is not whole key blocks: clipped to its slots
+    assert WALK.prefill_keys_walked(8, 60, 8, 4, 9) == 36
+
+
+def _keys_counter():
+    return {k: M.snapshot().get(
+        'hvd_tpu_gen_prefill_attn_keys_total{kind="%s"}' % k, 0)
+        for k in ("walked", "table")}
+
+
+def test_engine_counts_the_keys_its_prefill_walks(walk_params,
+                                                  key_blocks_of_8):
+    """A prompt of 19 tokens is chunks of 8, 8 and 3 live columns at
+    prefixes 0, 8 and 16: the counter adds each chunk's last live
+    position rounded up to the key block, 8 + 16 + 24 slots of a 40-slot
+    table a chunk."""
+    prompt = np.asarray(_tokens(5, 19)).tolist()
+    before = _keys_counter()
+    with _engine(walk_params, WALK, num_blocks=24, max_seqs=2) as eng:
+        toks = eng.result(eng.submit(prompt, max_tokens=3), timeout=240)
+        assert eng.allocator.in_use == 0
+    after = _keys_counter()
+    assert after["table"] - before["table"] == 3 * WALK_TABLE
+    assert after["walked"] - before["walked"] == 8 + 16 + 24
+    logits = LongcatFlash(WALK).apply(
+        walk_params, jnp.asarray([prompt + toks], jnp.int32))
+    assert toks == np.asarray(logits)[0, 18:21].argmax(-1).tolist()
+
+
+def test_a_model_without_the_walk_counts_the_whole_table():
+    program = kvc.build_prefill_program(LongcatFlash(WALK))
+    assert kvc.prefill_keys_walked(program, 512, 4096, 300, 64, 264) == 4608
+    assert kvc.prefill_keys_walked(lambda *a: None, 512, 4096, 300, 64,
+                                   264) == 16896
 
 
 # -- (d), (e) the expert layer ------------------------------------------------
