@@ -110,6 +110,8 @@ class _Bindings:
             ctypes.POINTER(ctypes.c_double), ctypes.c_int32]
         c.hvd_hist_destroy.argtypes = [ctypes.c_void_p]
         c.hvd_hist_observe.argtypes = [ctypes.c_void_p, ctypes.c_double]
+        c.hvd_hist_observe_n.argtypes = [
+            ctypes.c_void_p, ctypes.c_double, ctypes.c_uint64]
         c.hvd_hist_read.restype = ctypes.c_int32
         c.hvd_hist_read.argtypes = [
             ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64),

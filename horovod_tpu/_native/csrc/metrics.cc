@@ -79,6 +79,17 @@ HVD_EXPORT void hvd_hist_observe(void* p, double v) {
   h->total.fetch_add(1, std::memory_order_relaxed);
 }
 
+// n observations of one value in one call: a caller that holds the
+// interpreter lock pays for dropping it once, not n times
+HVD_EXPORT void hvd_hist_observe_n(void* p, double v, uint64_t n) {
+  Hist* h = static_cast<Hist*>(p);
+  int32_t idx = static_cast<int32_t>(
+      std::lower_bound(h->bounds, h->bounds + h->n, v) - h->bounds);
+  h->counts[idx].fetch_add(n, std::memory_order_relaxed);
+  atomic_add(h->sum, v * static_cast<double>(n));
+  h->total.fetch_add(n, std::memory_order_relaxed);
+}
+
 HVD_EXPORT int32_t hvd_hist_read(void* p, uint64_t* out_counts,
                                  double* out_sum, uint64_t* out_total) {
   Hist* h = static_cast<Hist*>(p);

@@ -234,6 +234,25 @@ class Histogram:
             self._sum += v
             self._count += 1
 
+    def observe_n(self, value: float, count: int) -> None:
+        """``count`` observations of ``value`` at the price of one: a
+        native call drops the interpreter lock, and a hot loop that
+        observes once an item hands it to whoever waits, once an item."""
+        if not self._registry.enabled or count <= 0:
+            return
+        if not self._ready:
+            self._resolve()
+        v = float(value)
+        if self._h is not None:
+            self._nat.cdll.hvd_hist_observe_n(self._h, v, count)
+            return
+        import bisect
+        idx = bisect.bisect_left(self._bounds, v)
+        with self._lock:
+            self._counts[idx] += count
+            self._sum += v * count
+            self._count += count
+
     def exemplar(self) -> Optional[Tuple[str, float]]:
         """(trace id, observed value) of the most recent observation
         that carried one, or None."""

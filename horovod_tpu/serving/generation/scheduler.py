@@ -369,6 +369,44 @@ _M_TTFT = _metrics.histogram(
     "hvd_tpu_gen_queue_wait_seconds plus "
     "hvd_tpu_gen_prefill_span_seconds of the same request.",
     buckets=_WAIT_BUCKETS)
+#: a token's gap runs from a millisecond (a toy model on a CPU) to a
+#: second and more (a long chunk between two tokens): 5 % steps over
+#: that range, so that a percentile interpolated inside a bucket lies
+#: within 3 % of the sample's (tests/test_loop_spans.py holds it on the
+#: two-humped shapes the serving cells show), and a few bounds each side
+_ITL_BUCKETS = (0.00025, 0.0005) + tuple(
+    round(0.001 * 1.05 ** i, 7) for i in range(142)) + (
+    2.5, 5.0, 10.0, 30.0)
+_M_ITL = _metrics.histogram(
+    "hvd_tpu_gen_itl_seconds",
+    "Per token of a request but its first, the time since the same "
+    "sequence's previous token was put on its stream (inter-token "
+    "latency as the scheduler sees it: one stamp a delivery, on the "
+    "loop spans' clock), by what the loop dispatched between the two: "
+    "'decode' (decode steps only), 'prefill' (at least one prefill "
+    "chunk, this sequence's or another's), 'preempt' (the sequence was "
+    "preempted and recomputed). Tokens that one verify or beam delivery "
+    "emits together: the first carries the gap, the others observe 0 "
+    "under 'decode', as a reader of the stream meets them.",
+    labels=("between",), buckets=_ITL_BUCKETS)
+_M_ITER = _metrics.histogram(
+    "hvd_tpu_gen_iter_seconds",
+    "Per busy scheduler iteration, its whole duration (its gen.iter "
+    "span: hvd_tpu_gen_step_seconds' host plus device of the same "
+    "iteration) by what it dispatched: 'decode' (decode, verify or beam "
+    "steps only; also a pass that only drained a step in flight), "
+    "'prefill' (a prefill chunk only), 'both'. The root's ring record "
+    "holds the sizes: chunk (prompt tokens), lanes, emitted (tokens put "
+    "on streams).",
+    labels=("carried",),
+    buckets=(0.001, 0.0025, 0.005, 0.0075, 0.01, 0.015, 0.02, 0.03, 0.04,
+             0.05, 0.075, 0.1, 0.15, 0.25, 0.5, 1.0, 2.5))
+_M_PARKED = _metrics.counter(
+    "hvd_tpu_gen_parked_seconds_total",
+    "Seconds the scheduler's loop spent blocked on its submission queue "
+    "with nothing running, waiting or in flight (its gen.park loop "
+    "spans). 1 - parked/elapsed is the loop's utilisation; the rest of "
+    "its time lies under gen.iter spans.")
 _M_SPEC_DRAFTED = _metrics.counter(
     "hvd_tpu_gen_spec_drafted_total",
     "Tokens proposed by the speculative-decoding drafter "
@@ -494,7 +532,8 @@ class GenSequence:
                  "top_p", "seed", "key", "sample_offset", "prefix_hashes",
                  "block_hashes", "cache_gen", "request_id", "trace",
                  "num_beams", "first_dispatch_at", "first_token_at",
-                 "state_slot", "wblocks", "wreleased")
+                 "state_slot", "wblocks", "wreleased", "last_token_ns",
+                 "chunks_seen", "preempted")
 
     def __init__(self, seq_id: int, prompt: List[int], max_tokens: int,
                  eos_id: Optional[int], deadline_s: float,
@@ -581,6 +620,13 @@ class GenSequence:
         #: re-admission after a preemption observes no second wait
         self.first_dispatch_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
+        #: the newest token's delivery stamp (time.perf_counter_ns), the
+        #: loop's count of prefill chunks dispatched by then, and whether
+        #: the sequence was preempted since: what labels the next gap in
+        #: hvd_tpu_gen_itl_seconds
+        self.last_token_ns = 0
+        self.chunks_seen = 0
+        self.preempted = False
         #: serving request id, stamped into preemption/deadline
         #: diagnostics whether or not the request is traced
         self.request_id = request_id
@@ -763,8 +809,8 @@ class ContinuousBatcher:
         self._lanes: List[Optional[GenSequence]] = [None] * self.max_seqs
         self._epoch = 0
         self._state_epoch = -1
-        #: decode steps enqueued but not yet consumed:
-        #: (token_dev, logprob_dev, lane snapshot, routing counts)
+        #: decode steps enqueued but not yet consumed: (token_dev,
+        #: logprob_dev, lane snapshot, routing counts, flight number)
         self._inflight: "collections.deque" = collections.deque()
         #: routing counts of prefill chunks not read back yet, as
         #: (phase, device vector); a decode step's travel with its
@@ -774,6 +820,25 @@ class ContinuousBatcher:
         #: the self times that hvd_tpu_gen_phase_seconds and
         #: hvd_tpu_gen_step_seconds are derived from
         self._spans = _tracing.LoopTrace("gen.iter", histogram=_M_PHASE)
+        #: one number a program dispatched, on its gen.*.dispatch span
+        #: and on the gen.wait that awaits its result: the order the
+        #: device runs them in
+        self._flights = itertools.count(1)
+        #: prefill chunks dispatched since the batcher was made
+        self._chunks = 0
+        #: what this pass dispatched and emitted: chunk (prompt tokens),
+        #: lanes, emitted tokens; on the gen.iter record at its close
+        self._carried = [0, 0, 0]
+        #: the stamp the tokens of the delivery under way share
+        self._delivery_ns = 0
+        #: this pass's token gaps, (label, nanoseconds) -> tokens: the
+        #: lanes of a decode step mostly share both, and one native call
+        #: a group at the pass's end drops the interpreter lock once
+        #: where one a token handed it to a stream's reader mid-delivery
+        #: (0.27 ms a pass at 14 lanes: PERF.md section 6, PR 35)
+        self._gaps: dict = {}
+        self._itl = {b: _M_ITL.labels(between=b)
+                     for b in ("decode", "prefill", "preempt")}
         self._lock = _locks.lock(
             "serving.generation.ContinuousBatcher._lock")
         self._thread: Optional[threading.Thread] = None
@@ -1165,11 +1230,17 @@ class ContinuousBatcher:
         err = RuntimeError("generation scheduler stopped")
         step_host = _M_STEP.labels(component="host")
         step_device = _M_STEP.labels(component="device")
+        iter_carried = {c: _M_ITER.labels(carried=c)
+                        for c in ("decode", "prefill", "both")}
+        spans = self._spans
+        spans.start()
         while True:
             # block only when fully idle; otherwise drain without waiting
             if not self._running and not self._waiting \
                     and not self._inflight:
-                item = self._q.get()
+                with spans.park() as park:
+                    item = self._q.get()
+                _M_PARKED.inc(park.dur_ns * 1e-9)
                 if item is _STOP or self._stopped:
                     if item is not _STOP and item is not None:
                         self._deliver_error(item, err)
@@ -1183,27 +1254,39 @@ class ContinuousBatcher:
             # is traced, not observed); nothing the queue drain below
             # does moves either
             busy = bool(self._running or self._inflight)
-            spans = self._spans
+            carried = self._carried = [0, 0, 0]
             with spans.iteration(observe=busy, busy=busy,
                                  running=len(self._running),
                                  waiting=len(self._waiting)
                                  + self._q.qsize(),
-                                 inflight=len(self._inflight)):
+                                 inflight=len(self._inflight)) as root:
                 with spans.span("gen.admit"):
                     now = self._drain_and_admit(err)
                 if now is not None:
                     self._prefill_step(now)
                     self._decode_step(now)
+                    for (between, gap_ns), n in self._gaps.items():
+                        self._itl[between].observe_n(gap_ns * 1e-9, n)
+                    self._gaps.clear()
+                    self._publish_gauges()
+                chunk, lanes, emitted = carried
+                root.annotate(chunk=chunk, lanes=lanes, emitted=emitted)
             if busy:
                 # derived from the spans' own stamps: device is what the
-                # gen.wait spans cover, host the rest of gen.iter
-                phases = spans.self_ns
-                dev = phases.get("gen.wait", 0)
+                # gen.wait spans cover, host the rest of gen.iter. (The
+                # next root span starts where this one ended, so these
+                # observations lie under it.)
+                dev = spans.self_ns.get("gen.wait", 0)
                 step_device.observe(dev * 1e-9)
-                step_host.observe((sum(phases.values()) - dev) * 1e-9)
+                step_host.observe((root.dur_ns - dev) * 1e-9)
+                if not chunk:
+                    # also a pass that only drained a step in flight
+                    kind = "decode"
+                else:
+                    kind = "both" if lanes else "prefill"
+                iter_carried[kind].observe(root.dur_ns * 1e-9)
             if now is None:
                 return          # stopped: everything was failed
-            self._publish_gauges()
         self._shutdown(err)
 
     def _drain_and_admit(self, err: BaseException) -> Optional[float]:
@@ -1427,13 +1510,15 @@ class ContinuousBatcher:
         args = (PagedCache(self._pools, *cache), tokens, sample)
         if s.request_id:
             _tracing.note_request(s.request_id)
+        flight = next(self._flights)
         try:
             # the request span installs the request's context on the
             # scheduler thread, so collectives submitted inside the
             # prefill program bind under this chunk
             with spans.span("gen.prefill.dispatch", seq=s.id,
                             request=s.request_id or "", chunk=live,
-                            prefilled=s.prefilled, total=total), \
+                            prefilled=s.prefilled, total=total,
+                            flight=flight), \
                     _tracing.span_for(s.trace, "gen.prefill",
                                       args={"seq": s.id, "chunk": live,
                                             "prefilled": s.prefilled,
@@ -1447,8 +1532,10 @@ class ContinuousBatcher:
         except Exception as e:  # noqa: BLE001 — fails only this sequence
             self._deliver_error(s, e)
             return
+        self._chunks += 1
+        self._carried[0] += live
         with spans.span("gen.deliver"):
-            self._deliver_prefill(s, live, total, tok, logp, now)
+            self._deliver_prefill(s, live, total, tok, logp, now, flight)
 
     def _chunk_blocks_available(self, s: GenSequence) -> bool:
         """Whether ``s``'s next chunk can take its blocks (in every
@@ -1503,7 +1590,7 @@ class ContinuousBatcher:
                            args={"seq": s.id})
 
     def _deliver_prefill(self, s: GenSequence, live: int, total: int,
-                         tok, logp, now: float) -> None:
+                         tok, logp, now: float, flight: int) -> None:
         _M_TOKENS.labels(phase="prefill").inc(live)
         s.prefilled += live
         s.cache_len = s.prefilled
@@ -1547,8 +1634,10 @@ class ContinuousBatcher:
                 # chunks never reach this sync: their sampled token is
                 # simply not consumed.)
                 _M_TOKENS.labels(phase="decode").inc()
-                with self._spans.span("gen.wait", program="prefill"):
+                with self._spans.span("gen.wait", program="prefill",
+                                      flight=flight):
                     tok_v, logp_v = self._readback((tok, logp))
+                self._delivery_ns = time.perf_counter_ns()
                 logp_v = _corrupt_logprobs(logp_v, [s])
                 if not np.isfinite(logp_v[0]):
                     self._deliver_error(s, RuntimeError(
@@ -1680,9 +1769,11 @@ class ContinuousBatcher:
                     if s.state == "decode":
                         self._deliver_error(s, e)
                 return
+            flight = next(self._flights)
             try:
                 with self._spans.span("gen.decode.dispatch",
-                                      program="decode", lanes=len(batch)):
+                                      program="decode", lanes=len(batch),
+                                      flight=flight):
                     # the device's lengths run ahead of the host's
                     # mirror by the steps in flight
                     ahead = len(self._inflight)
@@ -1696,8 +1787,9 @@ class ContinuousBatcher:
                 self._reset_device()
                 return
             self._pools, self._dstate, tok, logp, *stats = out
+            self._carried[1] += len(batch)
             self._inflight.append((tok, logp, list(self._lanes),
-                                   [("decode", c) for c in stats]))
+                                   [("decode", c) for c in stats], flight))
         # consume down to the configured pipeline depth — everything,
         # when nothing was enqueued this iteration
         limit = self.async_depth if batch else 0
@@ -1900,9 +1992,10 @@ class ContinuousBatcher:
             self._process_flight(now)
 
     def _process_flight(self, now: float) -> None:
-        tok_d, logp_d, lanes, stats = self._inflight.popleft()
+        tok_d, logp_d, lanes, stats, flight = self._inflight.popleft()
         try:
-            with self._spans.span("gen.wait", program="decode"):
+            with self._spans.span("gen.wait", program="decode",
+                                  flight=flight):
                 tok, logp = self._readback((tok_d, logp_d), stats)
         except Exception:  # noqa: BLE001 — the device step itself died
             self._reset_device()
@@ -1911,6 +2004,7 @@ class ContinuousBatcher:
             self._deliver_flight(tok, logp, lanes, now)
 
     def _deliver_flight(self, tok, logp, lanes, now: float) -> None:
+        self._delivery_ns = time.perf_counter_ns()
         logp = _corrupt_logprobs(logp, lanes)   # serving.logprob drill
         emitted = []
         for i, s in enumerate(lanes):
@@ -1986,9 +2080,10 @@ class ContinuousBatcher:
                 if s.state == "decode":
                     self._deliver_error(s, e)
             return
+        flight = next(self._flights)
         try:
             with spans.span("gen.decode.dispatch", program="verify",
-                            lanes=len(batch)):
+                            lanes=len(batch), flight=flight):
                 self._count_attention_blocks(
                     "verify", [x.cache_len for x in batch],
                     self.spec_tokens + 1)
@@ -2000,8 +2095,10 @@ class ContinuousBatcher:
             self._reset_device()
             return
         self._pools, self._dstate, pred_d, logp_d, n_emit_d = out
+        self._carried[1] += len(batch)
         try:
-            with spans.span("gen.wait", program="verify") as wait:
+            with spans.span("gen.wait", program="verify",
+                            flight=flight) as wait:
                 pred = np.asarray(pred_d)
                 logp = np.asarray(logp_d)
                 n_emit = np.asarray(n_emit_d)
@@ -2017,6 +2114,7 @@ class ContinuousBatcher:
             self._deliver_verify(pred, logp, n_emit, now)
 
     def _deliver_verify(self, pred, logp, n_emit, now: float) -> None:
+        self._delivery_ns = time.perf_counter_ns()
         logp = _corrupt_logprobs(logp, self._lanes)  # serving.logprob
         emitted = []
         for i, s in enumerate(list(self._lanes)):
@@ -2135,9 +2233,10 @@ class ContinuousBatcher:
                 _free_hyps(active)
                 self._deliver_error(s, e)
                 return
+            flight = next(self._flights)
             try:
                 with spans.span("gen.decode.dispatch", program="beam",
-                                lanes=len(active)):
+                                lanes=len(active), flight=flight):
                     self._count_attention_blocks(
                         "beam", [h["cache_len"] for h in active],
                         DECODE_WIDTH)
@@ -2150,8 +2249,9 @@ class ContinuousBatcher:
                 self._reset_device()
                 return
             self._pools, top_tok_d, top_lp_d = out
+            self._carried[1] += len(active)
             try:
-                with spans.span("gen.wait", program="beam"):
+                with spans.span("gen.wait", program="beam", flight=flight):
                     top_tok = np.asarray(top_tok_d)
                     top_lp = np.asarray(top_lp_d)
             except Exception:  # noqa: BLE001
@@ -2245,6 +2345,7 @@ class ContinuousBatcher:
                             h["score"] for h in active):
                         break
         with spans.span("gen.deliver"):
+            self._delivery_ns = time.perf_counter_ns()
             pool = finished if finished else active
             win = max(pool, key=lambda h: h["score"])
             _free_hyps(active)
@@ -2387,6 +2488,7 @@ class ContinuousBatcher:
         s.block_hashes = []
         # recompute: the readmission restores a snapshot or zeros
         self._release_state(s)
+        s.preempted = True
         if s.state == "decode" and s.generated:
             # cache must be rebuilt up to (but not including) the newest
             # generated token — it is the resumed decode's input
@@ -2433,6 +2535,19 @@ class ContinuousBatcher:
             if s.first_dispatch_at is not None:
                 _M_PREFILL_SPAN.observe(t - s.first_dispatch_at)
                 _M_TTFT.observe(t - s.arrived_at)
+        else:
+            if s.preempted:
+                between = "preempt"
+            elif s.chunks_seen != self._chunks:
+                between = "prefill"
+            else:
+                between = "decode"
+            gap = (between, self._delivery_ns - s.last_token_ns)
+            self._gaps[gap] = self._gaps.get(gap, 0) + 1
+        s.last_token_ns = self._delivery_ns
+        s.chunks_seen = self._chunks
+        s.preempted = False
+        self._carried[2] += 1
         if s.trace is not None:
             # one instant span per emitted token — the decode-step
             # analogue of the per-chunk prefill span (the guard is a

@@ -360,6 +360,7 @@ def reset() -> None:
     _LAST_REQUEST = None
     _tls.ctx = None
     _LOOP_RING.clear()
+    _LOOP_DROPPED[0] = 0
 
 
 # ---------------------------------------------------------------------------
@@ -522,17 +523,25 @@ def collective(entry: tuple) -> None:
 # loop spans: work that belongs to no single request
 # ---------------------------------------------------------------------------
 
-#: closed loop spans retained, oldest evicted first. A benchmark reads
-#: the ring up to 90 s after the part of a window it traced. The
-#: generation loop runs 4 iterations a second today and closes 6 spans in
-#: an iteration that only decodes, 11 in one that carries a prefill chunk
-#: too: ten times that rate for 90 s is 3600 iterations, 39 600 spans at
-#: the most. A record is a tuple of 8 with four ints and, on about half
-#: the spans, an args dict of its own: 386 bytes a span over such an
-#: iteration (sys.getsizeof, CPython 3.12), 15.8 MB for a full ring.
-_LOOP_RING_DEPTH = 40960
+#: closed loop spans retained, oldest evicted first. A benchmark traces
+#: the first 6 s of a 51 s window and reads the ring after the window, a
+#: drain of up to 30 s and the trace's parse: some 95 s after the first
+#: span it needs. The fastest generation loop measured turns 60 passes a
+#: second (GPT-2 XL at two lanes busy, one v5e chip) and closes 6 spans in
+#: a pass that only decodes, 11 in one that carries a prefill chunk too,
+#: 13 for a model that also snapshots a state or releases window blocks:
+#: 60 x 11 x 95 = 63 000 spans. Four times that, for a decode step half
+#: as long and a slower reader: 2**18. A record is a tuple of 8 with five
+#: ints and, on most spans, an args dict of its own: 394 bytes a span
+#: over such passes (sys.getsizeof, CPython 3.12), 103 MB for a full
+#: ring, which a loop at that rate fills in six and a half minutes.
+#: :func:`loop_ring` tells a reader whether what it wants was evicted.
+_LOOP_RING_DEPTH = 1 << 18
 
 _LOOP_RING: "collections.deque" = collections.deque(maxlen=_LOOP_RING_DEPTH)
+#: records the ring has evicted since the last reset(), in a list so that
+#: the loops' threads and the readers share one cell
+_LOOP_DROPPED = [0]
 _LOOP_SPAN_IDS = itertools.count(1)
 _LOOP_ITER_IDS = itertools.count(1)
 _perf_ns = time.perf_counter_ns
@@ -582,7 +591,12 @@ class LoopSpan:
         loop._top = self
         if self._ann is not None:
             self._ann.__enter__()
-        self.start_ns = _perf_ns()
+        # a root opens where the last root closed: what the thread did
+        # between the two (the closed pass's histogram observations, the
+        # loop's own top) lies in the later one, and the roots tile the
+        # thread's time
+        self.start_ns = (self.parent is None and loop._tiled_ns) \
+            or _perf_ns()
         return self
 
     def __exit__(self, etype, exc, tb):
@@ -596,11 +610,14 @@ class LoopSpan:
         self_ns[name] = self_ns.get(name, 0) + dur - self._child_ns
         if parent is not None:
             parent._child_ns += dur
+        if len(_LOOP_RING) == _LOOP_RING_DEPTH:
+            _LOOP_DROPPED[0] += 1
         _LOOP_RING.append((name, self.start_ns, end, self.span_id,
                            parent.span_id if parent is not None else None,
                            loop.prefix, loop.iteration_id,
                            self.args or None))
         if parent is None:
+            loop._tiled_ns = end
             loop._close_iteration()
         return False
 
@@ -616,7 +633,12 @@ class LoopTrace:
     :attr:`self_ns` holds the pass's self time by span name, in
     nanoseconds, which sum to the root's duration exactly; with
     ``histogram`` (a family labelled ``phase``) and ``observe=True`` each
-    entry is observed under the name without its prefix."""
+    entry is observed under the name without its prefix.
+
+    :meth:`park` opens a root of another kind (``<prefix>.park``): the
+    loop blocked with nothing to do. A root starts at the stamp the root
+    before it ended on, so from :meth:`start` on every instant of the
+    loop's thread lies under exactly one root."""
 
     def __init__(self, root: str, histogram=None):
         from jax.profiler import TraceAnnotation
@@ -628,18 +650,32 @@ class LoopTrace:
         self._top: Optional[LoopSpan] = None
         self._observe = False
         self._profiling = False
+        self._tiled_ns = 0
         self.iteration_id = 0
         self.self_ns: dict = {}
 
-    def iteration(self, observe: bool = True, **args) -> LoopSpan:
+    def start(self) -> None:
+        """The loop's thread starts (again): its first root opens on its
+        own stamp, not on the end of a root of the thread before."""
+        self._tiled_ns = 0
+
+    def _root(self, name: str, observe: bool, args: dict) -> LoopSpan:
         self.iteration_id = next(_LOOP_ITER_IDS)
         self._observe = observe
-        # asked once a pass: a session that starts mid-pass shows the
-        # loop from its next pass on
+        # asked once a root: a session that starts mid-pass shows the
+        # loop from its next root on
         self._profiling = self._annotation.is_enabled()
         self._top = None        # a pass that died mid-span left its stack
         self.self_ns = {}
-        return LoopSpan(self, self.root, args)
+        return LoopSpan(self, name, args)
+
+    def iteration(self, observe: bool = True, **args) -> LoopSpan:
+        return self._root(self.root, observe, args)
+
+    def park(self) -> LoopSpan:
+        """The root span over a wait for work, ``<prefix>.park``: in the
+        ring and the profiler like a pass, in no histogram."""
+        return self._root(self.prefix + ".park", False, {})
 
     def span(self, name: str, **args):
         if self._top is None:
@@ -656,6 +692,21 @@ class LoopTrace:
                 child = bound[name] = self._histogram.labels(
                     phase=name.split(".", 1)[1])
             child.observe(ns * 1e-9)
+
+
+def loop_ring() -> dict:
+    """What a reader of :func:`loop_spans` has to know of the ring:
+    ``depth``, records ``held``, records ``dropped`` (evicted unread
+    since :func:`reset`) and ``oldest_end_ns``, the end of the oldest
+    record held (None when empty). A reader that wants the spans since an
+    instant has them all if nothing was dropped or the oldest record
+    ended before that instant."""
+    try:
+        oldest = _LOOP_RING[0][2]
+    except IndexError:
+        oldest = None
+    return {"depth": _LOOP_RING_DEPTH, "held": len(_LOOP_RING),
+            "dropped": _LOOP_DROPPED[0], "oldest_end_ns": oldest}
 
 
 def loop_spans(since: float = 0.0) -> list:

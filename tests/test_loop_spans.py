@@ -26,11 +26,15 @@ CFG = TransformerConfig(vocab_size=64, num_layers=2, d_model=32,
                         dtype=jnp.float32)
 
 ROOT = "gen.iter"
+PARK = "gen.park"
 CHILDREN = {"gen.admit", "gen.prefill.prepare", "gen.prefill.dispatch",
             "gen.decode.prepare", "gen.decode.dispatch", "gen.wait",
             "gen.deliver"}
 WAITS = ("hvd_tpu_gen_queue_wait_seconds",
          "hvd_tpu_gen_prefill_span_seconds", "hvd_tpu_gen_ttft_seconds")
+ITL = "hvd_tpu_gen_itl_seconds"
+ITER = "hvd_tpu_gen_iter_seconds"
+STEP = "hvd_tpu_gen_step_seconds"
 
 
 @pytest.fixture(autouse=True)
@@ -74,6 +78,14 @@ def _phase_series(snap):
     return [k for k in snap if k.startswith("hvd_tpu_gen_phase_seconds{")]
 
 
+def _delta(before, after, family, field="count"):
+    """What ``family``'s series took between two snapshots, by the value
+    of its one label."""
+    return {k.split('"')[1]: after[k][field] - (
+        before[k][field] if k in before else 0)
+        for k in after if k.startswith(family + "{")}
+
+
 def _run(model, params, requests, **engine_kw):
     """Serve ``requests`` (submit kwargs) to their end; returns the
     outputs, the loop spans of the run, and the registry before/after."""
@@ -89,8 +101,8 @@ def _run(model, params, requests, **engine_kw):
 def _check_trees(spans):
     """Every iteration is one root whose descendants carry the seven
     child names, nest inside their parents without overlapping their
-    siblings, and whose self times add up to the root exactly. Returns
-    the roots."""
+    siblings, and whose self times add up to the root exactly; a park is
+    a root with nothing under it. Returns the iterations' roots."""
     by_trace = collections.defaultdict(list)
     for s in spans:
         by_trace[s["trace"]].append(s)
@@ -98,6 +110,9 @@ def _check_trees(spans):
     for trace, group in by_trace.items():
         assert trace.startswith("gen-iter:")
         root = [s for s in group if s["parent"] is None]
+        if root[0]["name"] == PARK:
+            assert len(group) == 1 and not root[0]["args"], group
+            continue
         assert len(root) == 1 and root[0]["name"] == ROOT, group
         roots.append(root[0])
         ids = {s["span"]: s for s in group}
@@ -147,11 +162,12 @@ def test_every_iteration_is_one_tree_of_the_eight_names(
         model, params, requests(np.random.RandomState(3)), **engine_kw)
     roots = _check_trees(spans)
     names = {s["name"] for s in spans}
-    assert names <= CHILDREN | {ROOT}
+    assert names <= CHILDREN | {ROOT, PARK}
     assert {"gen.admit", "gen.prefill.dispatch", "gen.decode.dispatch",
             "gen.wait", "gen.deliver"} <= names
     for r in roots:
-        assert set(r["args"]) == {"busy", "running", "waiting", "inflight"}
+        assert set(r["args"]) == {"busy", "running", "waiting", "inflight",
+                                  "chunk", "lanes", "emitted"}
     # the other paths use the same names, with the program in the args
     waited = {s["args"]["program"] for s in spans if s["name"] == "gen.wait"}
     assert programs <= waited
@@ -185,6 +201,191 @@ def test_step_seconds_and_phase_seconds_are_the_same_stamps(model_params):
     # one observation a phase an iteration in which it ran
     admit = 'hvd_tpu_gen_phase_seconds{phase="admit"}'
     assert _count(after, admit) - _count(before, admit) == len(busy)
+
+
+def _mixed(rng):
+    # the short prompt decodes while the long one prefills, chunk by chunk
+    return [dict(prompt=_prompt(rng, 5), max_tokens=24),
+            dict(prompt=_prompt(rng, 30), max_tokens=4)]
+
+
+@pytest.mark.parametrize("requests,engine_kw", [
+    (_plain, {}), (_mixed, {}),
+    (_spec, {"spec_mode": "ngram", "spec_tokens": 4}),
+    (_beam, {"max_beams": 2}),
+], ids=["plain", "mixed", "speculative", "beam"])
+def test_every_token_but_a_requests_first_is_one_itl_observation(
+        model_params, requests, engine_kw):
+    model, params = model_params
+    outs, spans, before, after = _run(
+        model, params, requests(np.random.RandomState(11)), **engine_kw)
+    got = _delta(before, after, ITL)
+    assert set(got) == {"decode", "prefill", "preempt"}
+    assert sum(got.values()) == sum(len(o) - 1 for o in outs) > 0
+    assert got["preempt"] == 0
+    # what the passes put on streams is what the requests received
+    assert sum(r["args"]["emitted"] for r in _check_trees(spans)) == \
+        sum(len(o) for o in outs)
+
+
+def test_a_gap_is_labelled_by_what_the_loop_dispatched_in_it(model_params):
+    model, params = model_params
+    rng = np.random.RandomState(12)
+    # alone, within one chunk: every gap holds decode steps only
+    _, _, before, after = _run(
+        model, params, [dict(prompt=_prompt(rng, 6), max_tokens=12)])
+    assert _delta(before, after, ITL) == {
+        "decode": 11, "prefill": 0, "preempt": 0}
+    # beside a prompt of four chunks: the gaps of the sequence already
+    # decoding carry another sequence's chunks
+    outs, spans, before, after = _run(model, params, _mixed(rng))
+    got = _delta(before, after, ITL)
+    chunks = [s for s in spans if s["name"] == "gen.prefill.dispatch"]
+    assert len(chunks) == 5 and got["preempt"] == 0
+    assert 1 <= got["prefill"] <= len(chunks)
+    assert got["decode"] + got["prefill"] == sum(len(o) - 1 for o in outs)
+
+
+def test_a_gap_across_a_preemption_is_labelled_preempt(model_params):
+    model, params = model_params
+    rng = np.random.RandomState(10)
+    # the pool of test_a_readmission_after_preemption...: the younger of
+    # two sequences is preempted while it decodes, and recomputed
+    outs, _, before, after = _run(
+        model, params,
+        [dict(prompt=_prompt(rng, 6), max_tokens=20) for _ in range(2)],
+        num_blocks=10)
+    key = "hvd_tpu_gen_preemptions_total"
+    preemptions = after[key] - before.get(key, 0)
+    got = _delta(before, after, ITL)
+    assert 1 <= got["preempt"] <= preemptions
+    assert sum(got.values()) == sum(len(o) - 1 for o in outs)
+
+
+def test_iter_seconds_are_step_seconds_by_what_the_pass_carried(
+        model_params):
+    model, params = model_params
+    _, spans, before, after = _run(model, params,
+                                   _mixed(np.random.RandomState(13)))
+    counts = _delta(before, after, ITER)
+    host = _delta(before, after, STEP)["host"]
+    assert set(counts) <= {"decode", "prefill", "both"}
+    assert sum(counts.values()) == host > 0
+    assert sum(_delta(before, after, ITER, "sum").values()) == \
+        pytest.approx(sum(v for k, v in _delta(
+            before, after, STEP, "sum").items() if k != "verify"), rel=1e-9)
+    # the root's record holds what the histogram's label says
+    want = collections.Counter()
+    for r in _check_trees(spans):
+        a = r["args"]
+        if a["busy"]:
+            want["both" if a["chunk"] and a["lanes"] else
+                 "prefill" if a["chunk"] else "decode"] += 1
+    assert {k: v for k, v in counts.items() if v} == dict(want)
+    assert want["both"] >= 1 and want["decode"] >= 1
+
+
+def test_gen_iter_carries_chunk_lanes_and_emitted(model_params):
+    model, params = model_params
+    requests = _plain(np.random.RandomState(14))
+    outs, spans, _, _ = _run(model, params, requests)
+    roots = _check_trees(spans)
+    by_trace = collections.defaultdict(list)
+    for s in spans:
+        by_trace[s["trace"]].append(s)
+    for r in roots:
+        kids = by_trace[r["trace"]]
+        assert r["args"]["chunk"] == sum(
+            s["args"]["chunk"] for s in kids
+            if s["name"] == "gen.prefill.dispatch")
+        assert r["args"]["lanes"] == sum(
+            s["args"]["lanes"] for s in kids
+            if s["name"] == "gen.decode.dispatch")
+    assert sum(r["args"]["chunk"] for r in roots) == \
+        sum(len(kw["prompt"]) for kw in requests)
+    assert sum(r["args"]["emitted"] for r in roots) == \
+        sum(len(o) for o in outs)
+
+
+def test_iter_and_park_records_tile_the_loop_threads_time(model_params):
+    model, params = model_params
+    _, spans, before, after = _run(model, params,
+                                   _plain(np.random.RandomState(15)))
+    roots = sorted((s for s in spans if s["parent"] is None),
+                   key=lambda s: s["start_ns"])
+    assert {s["name"] for s in roots} == {ROOT, PARK}
+    # the engine starts with nothing to do, and ends so
+    assert roots[0]["name"] == PARK
+    for a, b in zip(roots, roots[1:]):
+        assert a["end_ns"] == b["start_ns"], (a, b)
+        assert a["start_ns"] < a["end_ns"]
+    parked = sum(s["end_ns"] - s["start_ns"] for s in roots
+                 if s["name"] == PARK)
+    key = "hvd_tpu_gen_parked_seconds_total"
+    assert after[key] - before.get(key, 0.0) == \
+        pytest.approx(parked * 1e-9, rel=1e-9)
+
+
+@pytest.mark.parametrize("requests,engine_kw", [
+    (_mixed, {}), (_spec, {"spec_mode": "ngram", "spec_tokens": 4}),
+    (_beam, {"max_beams": 2}),
+], ids=["plain", "speculative", "beam"])
+def test_a_dispatch_and_its_wait_share_a_flight(model_params, requests,
+                                                engine_kw):
+    model, params = model_params
+    _, spans, _, _ = _run(model, params,
+                          requests(np.random.RandomState(16)), **engine_kw)
+    dispatches = sorted((s for s in spans if s["name"].endswith(".dispatch")),
+                        key=lambda s: s["start_ns"])
+    flights = [s["args"]["flight"] for s in dispatches]
+    # one counter a batcher, in the order of dispatch
+    assert flights == list(range(flights[0], flights[0] + len(flights)))
+    by_flight = dict(zip(flights, dispatches))
+    waits = [s for s in spans if s["name"] == "gen.wait"]
+    assert waits and len({s["args"]["flight"] for s in waits}) == len(waits)
+    for w in waits:
+        d = by_flight[w["args"]["flight"]]
+        assert d["end_ns"] <= w["start_ns"]
+        assert d["args"].get("program", "prefill") == w["args"]["program"]
+    # a chunk that is not a prompt's last is dispatched and never awaited
+    awaited = {w["args"]["flight"] for w in waits}
+    for d in dispatches:
+        if d["name"] == "gen.prefill.dispatch":
+            last = d["args"]["prefilled"] + d["args"]["chunk"] \
+                == d["args"]["total"]
+            # (a beam request's last chunk leaves its token unread)
+            assert (d["args"]["flight"] in awaited) == (
+                last and "max_beams" not in engine_kw)
+
+
+def _two_humps(rng, n, decode_ms, chunk_ms, chunk_share):
+    """Gaps as an ITL cell shows them: most a decode iteration, a share
+    a decode iteration and a prefill chunk, each hump a few per cent
+    wide."""
+    chunk = rng.random_sample(n) < chunk_share
+    return np.where(chunk, rng.normal(chunk_ms, 0.12 * chunk_ms, n),
+                    rng.normal(decode_ms, 0.04 * decode_ms, n)) * 1e-3
+
+
+@pytest.mark.parametrize("decode_ms,chunk_ms,chunk_share", [
+    (12.6, 24.0, 0.054), (12.6, 24.0, 0.14),
+    (44.0, 80.0, 0.14), (44.0, 80.0, 0.08),
+], ids=["chat", "chat-more-chunks", "longcat", "longcat-fewer-chunks"])
+def test_itl_buckets_hold_the_90th_percentile_within_3_per_cent(
+        decode_ms, chunk_ms, chunk_share):
+    from perfbench.harness import gaps as reader
+
+    gaps = _two_humps(np.random.RandomState(17), 20000, decode_ms,
+                      chunk_ms, chunk_share)
+    hist = M.histogram("hvd_tpu_test_itl_seconds", "a test",
+                       buckets=M.REGISTRY._families[ITL]._buckets)
+    before = M.snapshot()["hvd_tpu_test_itl_seconds"]["buckets"]
+    for g in gaps:
+        hist.observe(g)
+    after = M.snapshot()["hvd_tpu_test_itl_seconds"]["buckets"]
+    got = reader.quantile({le: n - before[le] for le, n in after.items()},
+                          90)
+    assert got == pytest.approx(np.percentile(gaps, 90), rel=0.03)
 
 
 def test_prefill_dispatch_spans_name_the_chunk_and_the_request(model_params):

@@ -60,6 +60,20 @@ class TestRegistry:
         assert total == 4
         assert total_sum == pytest.approx(55.55)
 
+    def test_observe_n_is_n_observations_of_one_value(self):
+        reg = M.Registry()
+        h = reg.histogram("hn_seconds", "", buckets=(0.1, 1.0))
+        one = reg.histogram("h1_seconds", "", buckets=(0.1, 1.0))
+        h.observe(0.05)
+        h._children[()].observe_n(0.5, 3)
+        h._children[()].observe_n(7.0, 0)       # nothing
+        for v in (0.05, 0.5, 0.5, 0.5):
+            one.observe(v)
+        counts, total_sum, total = h._children[()].read()
+        want = one._children[()].read()
+        assert (counts, total) == (want[0], want[2]) == ((1, 3, 0), 4)
+        assert total_sum == pytest.approx(want[1])
+
     def test_histogram_le_boundary_is_inclusive(self):
         """Prometheus le semantics: an observation equal to a bound lands
         in that bound's bucket."""
