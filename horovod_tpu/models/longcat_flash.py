@@ -30,8 +30,22 @@ width; past ``r (dn + dv) / (2 r - dn - dv)`` queries a chunk (171 at
 the published widths) it is the cheaper of the two, by the count of
 multiplications alone.
 
-On the paged path the absorbed form gathers the lane's block table and
-masks a slot by its position. The expanded form gathers nothing: it
+On the paged path neither form gathers a block table where the chip
+can help it. The absorbed form is multi-query attention with one
+key-value head: the ``num_attention_heads x columns`` folded queries
+``[q_lat | q_rope | 0]`` all score the same cached row, and the row's
+first ``kv_lora_rank`` values are the value. That is the grouped layout
+of :mod:`horovod_tpu.ops.paged_attention` with ``kv_heads=1`` and the
+latent pool as K pool and V pool at once, so where that kernel applies
+(:func:`~horovod_tpu.ops.paged_attention.kernel_applies`: a TPU, and
+shapes it takes: a decode or beam step's two columns over the served
+pool of 64-token blocks of 640 bfloat16 values) each live lane's rows
+are read from the pool where they lie, up to what the lane holds, under
+a float32 running softmax; a dead lane reads nothing. Everywhere else (a
+CPU, a float32 pool of 8-token blocks, a verify step or a narrow chunk
+of more than two columns) the absorbed form takes the **gather path**,
+the oracle the kernel is held to: the lane's whole table gathered from
+the pool and a slot masked by its position. The expanded form
 **walks** the lane's blocks, :data:`KEY_BLOCK` slots at a time, from
 block 0 to the one that holds the chunk's last live position
 (``lengths + live - 1``: a dynamic trip count), reads the rows where
@@ -52,18 +66,21 @@ collection ``moe_stats`` (one int32 vector a layer).
 
 Named scopes, under flax's module scopes:
 ``layer_<i>/attn_<j>/{q_proj,kv_write,kv_gather,absorb,attention,out_proj}``
-(``kv_gather`` and ``absorb`` in the absorbed form only),
+(``absorb`` in the absorbed form only, ``kv_gather`` on its gather path
+only),
 ``layer_<i>/mlp_<j>``, ``layer_<i>/moe/{router,sort,experts,identity,combine}``,
 ``head``.
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import paged_attention
 from ..parallel.moe import STATS_COLLECTION, held_experts_mlp, route_topk
 from .blocks import (GatedMlp, normal_init as _init, rms_norm,
                      rotary_interleaved, untied_head)
@@ -132,14 +149,20 @@ class LongcatFlashConfig:
         r, dn, dv = self.kv_lora_rank, self.qk_nope_head_dim, self.v_head_dim
         return chunk * (2 * r - dn - dv) > r * (dn + dv)
 
+    def paged_query_rows(self, chunk: int) -> int:
+        """Query vectors one pass of the paged kernel scores together:
+        the absorbed form has one key-value head, the cached row, which
+        a chunk's columns times all the heads share."""
+        return chunk * self.num_attention_heads
+
     def prefill_keys_walked(self, chunk: int, length: int, live: int,
                             block_size: int, max_blocks: int) -> int:
         """Slots of a lane's table that one attention of a paged chunk
         reads: ``chunk`` columns wide, ``live`` of them live, after
         ``length`` tokens. The expanded form walks to the chunk's last
         live position, rounded up to the key block (nothing for a dead
-        lane); the absorbed form gathers the whole table. Host
-        arithmetic for the scheduler's counter
+        lane); the absorbed form of a narrow chunk gathers the whole
+        table. Host arithmetic for the scheduler's counter
         (``hvd_tpu_gen_prefill_attn_keys_total``), the same rule and the
         same walk as :meth:`LatentAttention._walked`."""
         table = max_blocks * block_size
@@ -151,7 +174,16 @@ class LongcatFlashConfig:
 
 class LatentAttention(nn.Module):
     """MLA. ``layer_cache`` is ``(pool, plane, block_tables, live)`` on
-    the paged path; returns ``(out, pool)`` then, ``out`` otherwise."""
+    the paged path; returns ``(out, pool)`` then, ``out`` otherwise.
+
+    With no cache a chunk attends to its own rows under ``mask``,
+    expanded or absorbed by :meth:`LongcatFlashConfig.expands`. On the
+    paged path a wide chunk walks the lane's blocks expanded
+    (:meth:`_walked`); a narrow one is absorbed and reads the pool
+    through the paged-attention kernel where
+    :func:`~horovod_tpu.ops.paged_attention.kernel_applies` (a TPU and
+    fitting shapes: the rule is the backend's and the shapes', there is
+    no option), else through the gathered table."""
 
     cfg: LongcatFlashConfig
 
@@ -209,25 +241,41 @@ class LatentAttention(nn.Module):
                 pool = pool.at[plane, blocks, positions % block_size].set(
                     new_rows)
         scale = (dn + dr) ** -0.5
-        if layer_cache is None:
-            form = self._expanded if cfg.expands(C) else self._absorbed
-            ctx = form(q_nope, q_rope, new_rows, mask, w_uk, w_uv, scale)
-        elif cfg.expands(C):
-            ctx = self._walked(q_nope, q_rope, pool, plane, block_tables,
-                               positions, live, w_uk, w_uv, scale)
+        if cfg.expands(C):
+            ctx = self._expanded(q_nope, q_rope, new_rows, mask, w_uk, w_uv,
+                                 scale) if layer_cache is None \
+                else self._walked(q_nope, q_rope, pool, plane, block_tables,
+                                  positions, live, w_uk, w_uv, scale)
         else:
-            with jax.named_scope("kv_gather"):
-                # every table slot, from the pool just written:
-                # position t of a sequence lives at index t
-                rows = pool[plane, block_tables].reshape(B, -1, width)
-            ctx = self._absorbed(q_nope, q_rope, rows, mask, w_uk, w_uv,
-                                 scale)
+            if layer_cache is None:
+                attend = functools.partial(_row_attention, rows=new_rows,
+                                           mask=mask, scale=scale)
+            elif paged_attention.kernel_applies(
+                    cfg.paged_query_rows(C), block_size, width, pool.dtype):
+                # one key-value head, and the pool is its keys and its
+                # values: what each live lane holds, read where it lies
+                attend = lambda q_row: paged_attention.paged_attention(  # noqa: E731
+                    q_row, pool, None, plane, block_tables, positions[:, 0],
+                    live, kv_heads=1, scale=scale)
+            else:
+                with jax.named_scope("kv_gather"):
+                    # every table slot, from the pool just written:
+                    # position t of a sequence lives at index t, and a
+                    # query at position p attends to every t <= p
+                    rows = pool[plane, block_tables].reshape(B, -1, width)
+                    seen = (jnp.arange(rows.shape[1])[None, None, None, :]
+                            <= positions[:, None, :, None])
+                attend = functools.partial(_row_attention, rows=rows,
+                                           mask=seen, scale=scale)
+            ctx = self._absorbed(q_nope, q_rope, width, w_uk, w_uv, attend)
         with jax.named_scope("out_proj"):
             out = jnp.einsum("bshd,hde->bse", ctx, w_o)
         return out if layer_cache is None else (out, pool)
 
-    def _absorbed(self, q_nope, q_rope, rows, mask, w_uk, w_uv, scale):
-        """Scores and context against the cached rows themselves."""
+    def _absorbed(self, q_nope, q_rope, width, w_uk, w_uv, attend):
+        """Scores and context against the cached rows themselves:
+        ``attend`` takes the folded queries ``(B, S, H, width)`` to
+        their weighted sums of ``width``-wide rows."""
         cfg = self.cfg
         r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         with jax.named_scope("absorb"):
@@ -236,12 +284,9 @@ class LatentAttention(nn.Module):
             # against [c | kr | 0]
             q_row = jnp.pad(
                 jnp.concatenate([q_lat, q_rope], axis=-1),
-                ((0, 0),) * 3 + ((0, rows.shape[-1] - r - dr),))
+                ((0, 0),) * 3 + ((0, width - r - dr),))
         with jax.named_scope("attention"):
-            scores = jnp.einsum("bshw,btw->bhst", q_row, rows,
-                                preferred_element_type=jnp.float32)
-            probs = _masked_softmax(scores * scale, mask).astype(rows.dtype)
-            ctx = jnp.einsum("bhst,btw->bshw", probs, rows)[..., :r]
+            ctx = attend(q_row)[..., :r]
         with jax.named_scope("absorb"):
             return jnp.einsum("bshr,rhd->bshd", ctx, w_uv)
 
@@ -333,6 +378,17 @@ def _rebuilt_scores(q_nope, q_rope, c, kr, w_uk, w_uv, scale):
               + jnp.einsum("bshd,btd->bhst", q_rope, kr,
                            preferred_element_type=jnp.float32))
     return scores * scale, v
+
+
+def _row_attention(q_row, rows, mask, scale):
+    """The absorbed form in plain XLA: every head's folded queries
+    ``(B, S, H, W)`` against the same ``rows`` ``(B, T, W)``, which are
+    keys and values both; float32 scores and softmax, probabilities in
+    the rows' dtype."""
+    scores = jnp.einsum("bshw,btw->bhst", q_row, rows,
+                        preferred_element_type=jnp.float32)
+    probs = _masked_softmax(scores * scale, mask).astype(rows.dtype)
+    return jnp.einsum("bhst,btw->bshw", probs, rows)
 
 
 def _by_head_groups(attend, q_nope, q_rope, w_uk, w_uv):
@@ -451,16 +507,11 @@ class LongcatFlash(nn.Module):
         else:
             # incremental: the chunk starts at each sequence's cache
             # length; slot t of a lane's table holds absolute position
-            # t, and a query at position p attends to every t <= p. The
-            # expanded form masks a key block by position as it walks;
-            # the absorbed form masks the table it gathers
+            # t, and a query at position p attends to every t <= p:
+            # each paged form masks what it reads by position
             positions = cache.lengths[:, None] + jnp.arange(S)[None, :]
             (pool,) = cache.pools
             mask = None
-            if not cfg.expands(S):
-                t_max = cache.block_tables.shape[1] * pool.shape[2]
-                mask = (jnp.arange(t_max)[None, None, None, :]
-                        <= positions[:, None, :, None])
             valid = jnp.arange(S)[None, :] < cache.live[:, None]
         for i in range(cfg.num_layers):
             layer = DoubleLayer(cfg, name=f"layer_{i}")

@@ -47,6 +47,15 @@ head, 32 FLOP a byte. Which layout runs follows from the shapes
 (``kv_heads < H``), not from an option; a model whose rows hold one head
 a query head keeps the block-diagonal layout.
 
+**One pool for keys and values.** Latent attention in its absorbed
+form is one key-value head whose key is a token's whole row and whose
+value is the same row (:mod:`..models.longcat_flash`): ``v_pool=None``
+says so, and a group is then copied once and serves both matmuls, since
+a second copy of the same rows would double the bytes of a step that is
+bound by them. Its queries are folded through a weight, so their width
+is the row's and not what the scores are scaled by: ``scale`` gives
+that (``None``: ``D ** -0.5``).
+
 **Windows.** With ``window`` a lane's walk starts at the group that
 holds position ``lengths - window + 1`` (a per-lane first group in SMEM
 beside the row count) and a slot is masked by its position
@@ -142,7 +151,7 @@ def first_group(length, window):
 
 def _kernel(layer_ref, rows_ref, first_ref, lengths_ref, next_ref,
             tables_ref, q_ref, *refs, block_size, max_blocks, columns,
-            heads, kv_heads, window, sm_scale):
+            heads, kv_heads, window, sm_scale, one_pool):
     """One lane a grid step. Scalar prefetch: ``layer_ref`` (1,) the
     plane; ``rows_ref`` (B,) rows to read, 0 for a dead lane;
     ``first_ref`` (B,) the group its walk starts at
@@ -162,14 +171,17 @@ def _kernel(layer_ref, rows_ref, first_ref, lengths_ref, next_ref,
     j``), scored against that head's ``D`` columns of a token's row;
     ``o_ref`` the same shape. Scratch: two group buffers each for K and
     V, their DMA semaphores, the float32 accumulator, and which buffer
-    holds the group the next live lane starts from."""
+    holds the group the next live lane starts from. With ``one_pool``
+    there is no V pool and no V buffer: the K pool's rows are the values
+    too, copied once."""
     grouped = kv_heads != heads
-    if grouped:
-        diag_ref = sel_ref = None
-        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, acc_ref, slot_ref = refs
-    else:
-        (diag_ref, sel_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
-         acc_ref, slot_ref) = refs
+    refs = iter(refs)
+    diag_ref, sel_ref = (None, None) if grouped else (next(refs), next(refs))
+    k_hbm = next(refs)
+    v_hbm = None if one_pool else next(refs)
+    o_ref, k_buf = next(refs), next(refs)
+    v_buf = None if one_pool else next(refs)
+    sems, acc_ref, slot_ref = refs
     b = pl.program_id(0)
     lanes = pl.num_programs(0)
     group = GROUP_TOKENS // block_size
@@ -179,10 +191,11 @@ def _kernel(layer_ref, rows_ref, first_ref, lengths_ref, next_ref,
     groups = (rows + (GROUP_TOKENS - 1)) // GROUP_TOKENS - first
 
     def copies(lane, g, slot, lookup=True):
-        """The 2 x group DMA descriptors of a lane's group ``g`` into
-        buffer ``slot``. Waiting needs only the shapes, not the source,
-        so ``lookup=False`` skips the table reads. Entries past the
-        table are clipped to its last: their slots are masked."""
+        """The 2 x group (one pool: group) DMA descriptors of a lane's
+        group ``g`` into buffer ``slot``. Waiting needs only the shapes,
+        not the source, so ``lookup=False`` skips the table reads.
+        Entries past the table are clipped to its last: their slots are
+        masked."""
         out = []
         for j in range(group):
             blk = 0
@@ -192,8 +205,10 @@ def _kernel(layer_ref, rows_ref, first_ref, lengths_ref, next_ref,
             dst = pl.ds(j * block_size, block_size)
             out.append(pltpu.make_async_copy(
                 k_hbm.at[layer, blk], k_buf.at[slot, dst], sems.at[0, slot]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[layer, blk], v_buf.at[slot, dst], sems.at[1, slot]))
+            if not one_pool:
+                out.append(pltpu.make_async_copy(
+                    v_hbm.at[layer, blk], v_buf.at[slot, dst],
+                    sems.at[1, slot]))
         return out
 
     @pl.when(b == 0)
@@ -253,7 +268,8 @@ def _kernel(layer_ref, rows_ref, first_ref, lengths_ref, next_ref,
 
             for c in copies(b, g, slot, lookup=False):
                 c.wait()
-            return score(k_buf[slot], v_buf[slot], g, carry)
+            k = k_buf[slot]
+            return score(k, k if one_pool else v_buf[slot], g, carry)
 
         carry = jax.lax.fori_loop(0, groups, body, carry)
         slot_ref[0] = (slot0 + groups) % 2
@@ -354,10 +370,11 @@ def _fold_masks(C, H, D, R, Cp, row, dtype):
     return jnp.asarray(diag, dtype), jnp.asarray(sel, dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("kv_heads", "window", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("kv_heads", "window", "scale", "interpret"))
 def paged_attention(q, k_pool, v_pool, layer, block_tables, lengths, live,
-                    *, kv_heads=None, window=None, interpret=False):
+                    *, kv_heads=None, window=None, scale=None,
+                    interpret=False):
     """Attention of a chunk's ``q`` ``(B, C, H, D)`` over the paged
     cache: plane ``layer`` of ``k_pool`` / ``v_pool`` ``(planes,
     num_blocks, block_size, row)`` through ``block_tables`` ``(B,
@@ -369,8 +386,10 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, lengths, live,
     anything, the null block too), and a lane with ``live[b] == 0`` is
     dead: nothing of its table is read and its output is zeros.
     ``kv_heads`` (None: ``H``) is how many key-value heads a token's
-    row holds; with fewer than ``H`` the grouped layout runs. Returns
-    ``(B, C, H, D)`` in the pools' dtype.
+    row holds; with fewer than ``H`` the grouped layout runs.
+    ``v_pool=None``: a token's row in ``k_pool`` is its value too, and a
+    group is copied once. ``scale`` multiplies the float32 scores
+    (None: ``D ** -0.5``). Returns ``(B, C, H, D)`` in the pools' dtype.
 
     The shapes have to satisfy :func:`shapes_fit` (asked about the
     query rows one pass brings: ``C * H``, or grouped ``C * H /
@@ -378,7 +397,9 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, lengths, live,
     B, C, H, D = q.shape
     _, _, block_size, row = k_pool.shape
     G = H if kv_heads is None else int(kv_heads)
-    if v_pool.shape != k_pool.shape or v_pool.dtype != k_pool.dtype:
+    one_pool = v_pool is None
+    if not one_pool and (v_pool.shape != k_pool.shape
+                         or v_pool.dtype != k_pool.dtype):
         raise ValueError(
             f"paged_attention: K pool {k_pool.shape} {k_pool.dtype} and V "
             f"pool {v_pool.shape} {v_pool.dtype} differ")
@@ -404,8 +425,8 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, lengths, live,
 
     kernel = functools.partial(
         _kernel, block_size=block_size, max_blocks=max_blocks, columns=C,
-        heads=H, kv_heads=G, window=window,
-        sm_scale=1.0 / float(np.sqrt(D)))
+        heads=H, kv_heads=G, window=window, one_pool=one_pool,
+        sm_scale=1.0 / float(np.sqrt(D)) if scale is None else float(scale))
     whole = lambda b, *_: (0, 0)                      # noqa: E731
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     if grouped:
@@ -418,7 +439,7 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, lengths, live,
                 0, 2, 1, 3, 4).reshape(B, G, C * rep, D),
             ((0, 0), (0, 0), (0, Rg - C * rep), (0, 0))),)
         lane_block = pl.BlockSpec((1, G, Rg, D), lambda b, *_: (b, 0, 0, 0))
-        in_specs = [lane_block, hbm, hbm]
+        in_specs = [lane_block]
         out_shape = (B, G, Rg, D)
         acc_shape = (G, Rg, D)
     else:
@@ -429,19 +450,20 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, lengths, live,
                     *_fold_masks(C, H, D, R, Cp, row, dtype))
         lane_block = pl.BlockSpec((1, Cp, row), lambda b, *_: (b, 0, 0))
         in_specs = [lane_block, pl.BlockSpec((R, row), whole),
-                    pl.BlockSpec((Cp, R), whole), hbm, hbm]
+                    pl.BlockSpec((Cp, R), whole)]
         out_shape = (B, Cp, row)
         acc_shape = (R, row)
+    pools = (k_pool,) if one_pool else (k_pool, v_pool)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(B,),
-            in_specs=in_specs,
+            in_specs=in_specs + [hbm] * len(pools),
             out_specs=lane_block,
             scratch_shapes=[
-                pltpu.VMEM((2, GROUP_TOKENS, row), dtype),
-                pltpu.VMEM((2, GROUP_TOKENS, row), dtype),
+                # a double-buffered group a pool
+                *[pltpu.VMEM((2, GROUP_TOKENS, row), dtype)] * len(pools),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM(acc_shape, jnp.float32),
                 pltpu.SMEM((1,), jnp.int32),
@@ -453,7 +475,7 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, lengths, live,
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), rows, first, lengths, nxt,
       block_tables.astype(jnp.int32).reshape(-1),
-      *operands, k_pool, v_pool)
+      *operands, *pools)
     if grouped:
         return out[:, :, :C * rep].reshape(B, G, C, rep, D).transpose(
             0, 2, 1, 3, 4).reshape(B, C, H, D)

@@ -4,9 +4,10 @@ plain reference (``perfbench/reference/longcat_flash.py``, float32,
 
 (a) full forward against the reference, and departures caught;
 (b) chunked prefill then decode through ``GenerationEngine``;
-(c) absorbed against expanded latent attention on the same cache, and
-    the expanded form's walk of the lane's blocks (ISSUE 34) against the
-    whole table gathered;
+(c) absorbed against expanded latent attention on the same cache, the
+    expanded form's walk of the lane's blocks (ISSUE 34) against the
+    whole table gathered, and the absorbed form through the paged
+    kernel (ISSUE 37, interpreted) against its gather path;
 (d) the shares of a layer's experts add up to the uncut layer;
 (e) dropless under a router that sends most tokens to one expert;
 (f) the routing counters; (g) the latent pool over the disagg wire;
@@ -30,6 +31,7 @@ from horovod_tpu.models import LongcatFlash, LongcatFlashConfig
 from horovod_tpu.models import longcat_flash as lf
 from horovod_tpu.models.longcat_flash import HEAD_GROUP, LatentAttention
 from horovod_tpu.models.transformer import PagedCache
+from horovod_tpu.ops import paged_attention as pa
 from horovod_tpu.parallel.moe import (STATS_FIELDS, TILE, held_experts_mlp,
                                       route_topk)
 from horovod_tpu.serving import GenerationEngine
@@ -451,6 +453,142 @@ def test_a_model_without_the_walk_counts_the_whole_table():
     assert kvc.prefill_keys_walked(program, 512, 4096, 300, 64, 264) == 4608
     assert kvc.prefill_keys_walked(lambda *a: None, 512, 4096, 300, 64,
                                    264) == 16896
+
+
+# -- (c) the absorbed form through the paged kernel ----------------------------
+
+#: one attention at widths whose row fits the kernel: 96 + 16 values pad
+#: to a 128-wide row; 4 heads x 2 columns are 8 query rows. ``CELL`` has
+#: the served row (512 + 64 in 640) and the served 64 heads: 128 query
+#: rows over blocks of 64, the shape the benchmark's decode step brings
+KERNEL = dataclasses.replace(
+    CFG, num_layers=1, kv_lora_rank=96, qk_rope_head_dim=16,
+    dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+KERNEL_F32 = dataclasses.replace(KERNEL, dtype=jnp.float32,
+                                 param_dtype=jnp.float32)
+CELL = dataclasses.replace(
+    KERNEL, num_attention_heads=64, kv_lora_rank=512, qk_rope_head_dim=64,
+    qk_nope_head_dim=128, v_head_dim=128)
+G = pa.GROUP_TOKENS
+#: name -> (configuration, block size, table blocks, lengths, live)
+ABSORBED = {
+    "ragged": (KERNEL, 16, 24, [0, 37, 300, 5], [2, 2, 2, 2]),
+    "dead_lane_between_live": (KERNEL, 16, 24, [40, 200, 70], [2, 0, 2]),
+    "one_short_of_a_group": (KERNEL, 16, 24, [G - 3, 2 * G - 3], [2, 2]),
+    "on_a_groups_edge": (KERNEL, 16, 24, [G - 2, 2 * G - 2], [2, 2]),
+    "one_past_a_groups_edge": (KERNEL, 16, 24, [G - 1, 2 * G - 1], [2, 2]),
+    "table_end": (KERNEL, 16, 20, [20 * 16 - 2, 9], [2, 2]),
+    "first_column_only": (KERNEL, 16, 24, [63, 130, 15], [1, 1, 2]),
+    "float32_blocks_of_8": (KERNEL_F32, 8, 40, [0, 7, 130, 250], [2, 1, 0, 2]),
+    "served_row_640_blocks_of_64": (CELL, 64, 6, [3, 200, 382, 126],
+                                    [2, 0, 2, 1]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ABSORBED))
+def test_absorbed_through_the_kernel_matches_the_gather_path(monkeypatch,
+                                                             name):
+    """One ``LatentAttention`` over a seeded pool, traced as on the
+    chip (the path rule asks for the default backend) with the kernel
+    interpreted, against the same call on this backend's gather path:
+    the live columns of the live lanes, and the rows the step leaves in
+    the pool. The kernel's tables name ``POISON`` (a block of NaN)
+    everywhere a lane's walk does not reach: past a live lane's last
+    group, and in all of a dead lane's table, whose output is zeros;
+    the oracle's tables are clean there, since a gather multiplies
+    every slot in."""
+    cfg, bs, max_blocks, lengths, live = ABSORBED[name]
+    lanes, rng = len(lengths), np.random.RandomState(4)
+    (pool,) = kvc.make_pools(cfg, lanes * max_blocks + 2, bs)
+    assert pa.shapes_fit(cfg.paged_query_rows(2), bs, pool.shape[3],
+                         pool.dtype) and not cfg.expands(2)
+    poison = pool.shape[1] - 1
+    rows = rng.standard_normal(pool.shape)
+    rows[:, poison] = np.nan
+    pool = jnp.asarray(rows, pool.dtype)
+    own = rng.permutation(np.arange(1, poison)).reshape(lanes, max_blocks)
+    clean, reached = np.zeros_like(own), np.full_like(own, poison)
+    for b, (n, alive) in enumerate(zip(lengths, live)):
+        held = -(-(n + 2) // bs)
+        clean[b, :held] = own[b, :held]
+        if alive:
+            walked = pa.blocks_read([n], 2, bs, max_blocks)
+            reached[b, :walked] = clean[b, :walked]
+    attn = LatentAttention(cfg)
+    x = jnp.asarray(rng.standard_normal((lanes, 2, cfg.hidden_size)),
+                    cfg.dtype)
+    positions = jnp.asarray(lengths)[:, None] + jnp.arange(2)[None, :]
+    params = attn.init(jax.random.PRNGKey(3), x, positions, None,
+                       (pool, 0, jnp.asarray(clean), jnp.asarray(live)))
+
+    def step(tables):
+        return jax.jit(lambda pool: attn.apply(
+            params, x, positions, None,
+            (pool, 1, jnp.asarray(tables, jnp.int32),
+             jnp.asarray(live, jnp.int32))))(pool)
+
+    want, want_pool = step(clean)
+    interpreted = pa.paged_attention
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        m.setattr(pa, "paged_attention", lambda *a, **kw: interpreted(
+            *a, **kw, interpret=True))
+        got, got_pool = step(reached)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    # a dead lane: zeros from the kernel, through a projection with no bias
+    assert not got[np.asarray(live) == 0].any()
+    tol = 2e-5 if cfg.dtype == jnp.float32 else 0.02
+    for b, alive in enumerate(live):
+        err = np.linalg.norm(got[b, :alive] - want[b, :alive]) \
+            / max(np.linalg.norm(want[b, :alive]), 1e-30)
+        assert err <= tol, (name, b, err)
+    # the step's own rows went where the table says on both paths
+    np.testing.assert_array_equal(
+        np.asarray(got_pool, np.float32)[:, 1:poison],
+        np.asarray(want_pool, np.float32)[:, 1:poison])
+
+
+#: name -> (backend, block size, pool dtype, kernel?)
+COUNTED = {
+    "cpu_fitting_pool": ("cpu", 16, jnp.bfloat16, False),
+    "tpu_fitting_pool": ("tpu", 16, jnp.bfloat16, True),
+    "tpu_float32_blocks_of_4": ("tpu", 4, jnp.float32, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(COUNTED))
+def test_scheduler_counts_what_the_absorbed_decode_reads(monkeypatch, name):
+    """``hvd_tpu_gen_paged_attn_blocks_total``: the decode program
+    carries the model's ``paged_query_rows`` (all heads x 2 columns: one
+    key-value head), and where the path rule holds for the engine's
+    pool the scheduler adds what the kernel's walk copies
+    (``blocks_read``) to ``kind="read"``; on the gather path, the
+    table."""
+    backend, bs, dtype, kernel = COUNTED[name]
+    cfg = dataclasses.replace(KERNEL, dtype=dtype, param_dtype=dtype,
+                              max_position_embeddings=512)
+    assert cfg.paged_query_rows(2) == 8
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    model = LongcatFlash(cfg)
+    params = model.init(jax.random.PRNGKey(1), jnp.zeros((1, 4), jnp.int32))
+    series = 'hvd_tpu_gen_paged_attn_blocks_total{kind="%s"}'
+    lengths = [5, 300, 130]
+    with _engine(params, cfg, block_size=bs, num_blocks=48,
+                 max_seqs=4) as eng:
+        batcher = eng.batcher
+        assert batcher._decode_prog.query_rows == 8
+        assert ("decode" in batcher._reads_live) is kernel
+        before = M.snapshot()
+        batcher._count_attention_blocks("decode", lengths, 2)
+        after = M.snapshot()
+    read, table = (after[series % k] - before.get(series % k, 0.0)
+                   for k in ("read", "table"))
+    assert table == 4 * (512 // bs)
+    assert read == (pa.blocks_read(lengths, 2, bs, 512 // bs) if kernel
+                    else table)
+    if kernel:
+        assert read == (1 + 3 + 2) * (G // bs) < table
 
 
 # -- (d), (e) the expert layer ------------------------------------------------

@@ -165,6 +165,97 @@ def test_a_lane_reads_only_its_rows():
     assert np.isfinite(np.asarray(out, np.float32)).all()
 
 
+def _plain(q, k_pool, v_pool, layer, tables, lengths, kv_heads, scale):
+    """Attention over the gathered tables in float32, a key-value head
+    shared by ``H / kv_heads`` query heads, scores times ``scale``."""
+    B, C, H, D = q.shape
+    G = kv_heads or H
+    k, v = (pool[layer][tables].reshape(B, -1, pool.shape[3])[..., :G * D]
+            .reshape(B, -1, G, D).astype(jnp.float32)
+            for pool in (k_pool, v_pool))
+    qg = q.astype(jnp.float32).reshape(B, C, G, H // G, D)
+    s = jnp.einsum("bcgrd,btgd->bgrct", qg, k) * scale
+    seen = (jnp.arange(k.shape[1])[None, None, :]
+            <= (lengths[:, None] + jnp.arange(C)[None, :])[:, :, None])
+    p = jax.nn.softmax(jnp.where(seen[:, None, None], s, -1e30), axis=-1)
+    return jnp.einsum("bgrct,btgd->bcgrd", p, v).reshape(B, C, H, D)
+
+
+#: heads, head_dim, kv_heads, one pool for keys and values, scale, dtype
+ARGUMENTS = {
+    # the absorbed form of latent attention: every head's folded query
+    # against a token's whole row, which is its value too
+    "one_pool_one_shared_head": (4, 128, 1, True, 48 ** -0.5, jnp.bfloat16),
+    "one_pool_one_shared_head_float32": (4, 128, 1, True, 48 ** -0.5,
+                                         jnp.float32),
+    "one_pool_default_scale": (4, 128, 2, True, None, jnp.bfloat16),
+    "one_pool_block_diagonal": (2, 64, None, True, None, jnp.bfloat16),
+    "scale_grouped": (4, 128, 2, False, 0.05, jnp.bfloat16),
+    "scale_block_diagonal": (2, 64, None, False, 0.2, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ARGUMENTS))
+def test_one_pool_and_a_given_scale(name):
+    """``v_pool=None`` (a group copied once, keys and values the same
+    rows) and ``scale`` in both layouts: against plain attention over
+    the gathered tables, and, one pool, bit for bit what the kernel
+    makes of the same pool handed in twice. A dead lane between live
+    ones, lanes one short of, on and past a group's edge."""
+    heads, head_dim, kv_heads, one_pool, scale, dtype = ARGUMENTS[name]
+    rng = np.random.RandomState(5)
+    bs = 8 if dtype == jnp.float32 else BS
+    max_blocks = 5 * pa.GROUP_TOKENS // (2 * bs)      # 2.5 groups
+    lengths = jnp.asarray([pa.GROUP_TOKENS - 3, 90, pa.GROUP_TOKENS - 2,
+                           pa.GROUP_TOKENS - 1, max_blocks * bs - 2, 0],
+                          jnp.int32)
+    live = jnp.asarray([1, 0, 1, 1, 1, 1], jnp.int32)
+    row = (kv_heads or heads) * head_dim
+    pool = (2, 6 * max_blocks + 1, bs, row)
+    # rows of unit scale over the whole width, as cached rows are
+    k_pool = jnp.asarray(rng.standard_normal(pool) * row ** -0.5 * 8, dtype)
+    v_pool = None if one_pool else jnp.asarray(rng.standard_normal(pool),
+                                               dtype)
+    q = jnp.asarray(rng.standard_normal((6, 2, heads, head_dim)), dtype)
+    tables = jnp.asarray(rng.permutation(np.arange(1, pool[1])).reshape(
+        6, max_blocks), jnp.int32)
+    got = pa.paged_attention(q, k_pool, v_pool, 1, tables, lengths, live,
+                             kv_heads=kv_heads, scale=scale, interpret=True)
+    assert got.shape == q.shape and got.dtype == dtype
+    values = k_pool if one_pool else v_pool
+    want = _plain(q, k_pool, values, 1, tables, lengths, kv_heads,
+                  head_dim ** -0.5 if scale is None else scale)
+    got32, alive = np.asarray(got, np.float32), np.asarray(live, bool)
+    assert not got32[~alive].any()
+    flat = lambda a: np.asarray(a)[alive].reshape(alive.sum(), -1)  # noqa: E731
+    err = np.linalg.norm(flat(got32) - flat(want), axis=1) \
+        / np.linalg.norm(flat(want), axis=1)
+    tol = F32_TOL if dtype == jnp.float32 else KERNEL_TOL
+    assert (err <= tol).all(), err
+    if one_pool:
+        twice = pa.paged_attention(q, k_pool, k_pool, 1, tables, lengths,
+                                   live, kv_heads=kv_heads, scale=scale,
+                                   interpret=True)
+        np.testing.assert_array_equal(got32, np.asarray(twice, np.float32))
+
+
+def test_one_pool_copies_half_the_blocks():
+    """The traced kernel with ``v_pool=None`` holds one pool operand and
+    one group buffer, and starts one copy a block where two pools start
+    two: the bytes of the step are the rows', once."""
+    q = jnp.zeros((2, 2, 4, 128), jnp.bfloat16)
+    pool = jnp.zeros((1, 9, 16, 128), jnp.bfloat16)
+    args = (0, jnp.zeros((2, 8), jnp.int32), jnp.zeros((2,), jnp.int32),
+            jnp.ones((2,), jnp.int32))
+
+    def starts(v_pool):
+        text = str(jax.make_jaxpr(lambda q, k: pa.paged_attention(
+            q, k, v_pool, *args, kv_heads=1, interpret=True))(q, pool))
+        return text.count("dma_start")
+
+    assert starts(pool) == 2 * starts(None) > 0
+
+
 def test_blocks_read_is_the_kernels_walk():
     # 16-token blocks, groups of 8: rows round up to 128
     assert pa.blocks_read([0], 2, 16, 64) == 8
