@@ -29,11 +29,12 @@ option this module's :class:`Indexer` is) attends to those alone.
 On the paged path the chunk first writes both rows; the indexer walks
 the lane's index rows in key blocks of :data:`INDEX_KEY_BLOCK` up to the
 chunk's last live position, without gathering the table, into a float32
-row of scores a query; :func:`exact_top_k` picks from it by segments of
-:data:`TOP_K_SEGMENT` positions, as far as the lane holds; latent
-attention reads the chosen rows from the pool where they lie, a block
-of queries at a time. Where every live query's prefix is at most
-``index_topk`` the choice is the whole prefix and
+row of scores a query; :func:`exact_top_k` sorts it by segments of
+:data:`TOP_K_SEGMENT` positions into a running top-k, as far as the
+lane holds, each position carrying the pool row its slot maps to
+(:func:`slot_rows`); latent attention reads those rows from the pool
+where they lie, a block of queries at a time. Where every live query's
+prefix is at most ``index_topk`` the choice is the whole prefix and
 :class:`~.longcat_flash.LatentAttention`'s own forms run.
 
 The router (:func:`~horovod_tpu.parallel.moe.route_group_limited_topk`):
@@ -71,9 +72,9 @@ Dtype = Any
 #: logits are queries x index heads x keys x 4 bytes (512 x 64 x 512 x 4
 #: B = 67 MB for a prefill chunk)
 INDEX_KEY_BLOCK = 512
-#: positions :func:`exact_top_k` takes the top-k of at a time before the
-#: segments' winners are ranked together: a TPU's top-k costs more than
-#: linearly in the row's length
+#: positions :func:`exact_top_k` sorts at a time before their first k
+#: join the running top-k: a TPU's sort costs more than linearly in the
+#: row's length
 TOP_K_SEGMENT = 8192
 _NEG_INF = jnp.finfo(jnp.float32).min
 
@@ -152,40 +153,76 @@ class DeepseekV32Config(LatentAttentionConfig):
                 "selected": sum(min(p, k) for p in prefixes)}
 
 
-def exact_top_k(scores, k: int, end=None):
-    """Positions of the ``k`` largest of each row of ``scores`` ``(..., T)``
-    (float32; ties to the lower position), as ``lax.top_k`` gives them,
-    ``(..., k)`` int32. With ``end`` (a traced count) only the first
-    ``end`` positions can win and the segments past it are never
-    ranked: a row is ranked by :data:`TOP_K_SEGMENT` positions at a
-    time, and the segments' ``k`` winners then together. A position of
-    the true top-k wins its own segment, and the segments' winners lie
-    in position order, so the set and the order of ties are those of
-    one ``top_k`` over the row."""
+def _descending_key(scores):
+    """int32 keys whose ascending order is the descending order of the
+    float32 ``scores`` as ``lax.top_k`` ranks them (a total order: 0.0
+    above -0.0)."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return ~jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+def _score_of_key(keys):
+    """The float32 score of :func:`_descending_key`'s ``keys``."""
+    bits = ~keys
+    return jax.lax.bitcast_convert_type(
+        jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits), jnp.float32)
+
+
+def exact_top_k(scores, k: int, payload, end=None):
+    """The ``k`` largest of each row of ``scores`` ``(..., T)`` (float32;
+    ties to the lower position) in ``lax.top_k``'s order, and what each
+    array of ``payload`` (broadcast to ``scores``' shape) holds there:
+    ``(values, *payload)``, each ``(..., k)``. With ``end`` (a traced
+    count) only the first ``end`` positions can win and the segments
+    past it are never ranked.
+
+    A row is ranked :data:`TOP_K_SEGMENT` positions at a time: a
+    segment's scores sorted with its payload alongside, its first ``k``
+    merged into the running top-``k`` by a second sort of ``2k``, the
+    running ones first. Both sorts are stable, so a tie stays in
+    position order: the set and the order are one ``top_k``'s over the
+    row, and the payload (the pool rows) rides along, never looked up
+    afterwards."""
     T = scores.shape[-1]
-    seg = max(TOP_K_SEGMENT, k)
-    if T <= seg:
-        return jax.lax.top_k(scores, k)[1]
+    seg = min(max(TOP_K_SEGMENT, k), T)
     n = -(-T // seg)
-    if n * seg != T:
-        scores = jnp.pad(scores, [(0, 0)] * (scores.ndim - 1)
-                         + [(0, n * seg - T)], constant_values=_NEG_INF)
-    lead = scores.shape[:-1]
-    top = (jnp.full((n,) + lead + (k,), _NEG_INF, jnp.float32),
-           jnp.zeros((n,) + lead + (k,), jnp.int32))
+    pad = [(0, 0)] * (scores.ndim - 1) + [(0, n * seg - T)]
+    scores = jnp.pad(scores, pad, constant_values=_NEG_INF)
+    payload = [jnp.pad(a, pad[-a.ndim:]) for a in payload]
+    shape = scores.shape[:-1] + (seg,)
 
-    def segment(i, top):
-        vals, idx = jax.lax.top_k(
-            jax.lax.dynamic_slice_in_dim(scores, i * seg, seg, axis=-1), k)
-        return (top[0].at[i].set(vals), top[1].at[i].set(idx + i * seg))
+    def first_k(ops):
+        return tuple(a[..., :k] for a in jax.lax.sort(ops, num_keys=1,
+                                                       is_stable=True))
 
-    vals, idx = jax.lax.fori_loop(0, n if end is None else
-                                  (end + seg - 1) // seg, segment, top)
-    # (n, ..., k) -> (..., n k): the segments' winners in position order
-    vals, idx = (jnp.moveaxis(a, 0, -2).reshape(lead + (n * k,))
-                 for a in (vals, idx))
-    _, win = jax.lax.top_k(vals, k)
-    return jnp.take_along_axis(idx, win, axis=-1)
+    def ranked(i):                  # segment i's first k, sorted
+        part = lambda a: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+            a, i * seg, seg, axis=-1)
+        return first_k((_descending_key(part(scores)),) + tuple(
+            jnp.broadcast_to(part(a), shape) for a in payload))
+
+    def merge(i, top):
+        return first_k(tuple(jnp.concatenate(ab, axis=-1)
+                             for ab in zip(top, ranked(i))))
+
+    key, *payload = jax.lax.fori_loop(
+        1, n if end is None else (end + seg - 1) // seg, merge, ranked(0))
+    return (_score_of_key(key), *payload)
+
+
+def slot_rows(tables, plane: int, num_blocks: int, block_size: int,
+              width: int):
+    """The row of the pools' flat view ``(planes * num_blocks *
+    block_size, row)`` that holds slot ``t`` of each lane's table in
+    plane ``plane``, for the ``width`` slots (whole blocks, the table's
+    at the least): ``(B, width)`` int32, the table broadcast over a
+    block's offsets (no gather). Slots past the table map to the plane's
+    null block."""
+    B, nb = tables.shape
+    tables = jnp.pad(tables, ((0, 0), (0, width // block_size - nb)))
+    base = (plane * num_blocks + tables) * block_size
+    return (base[:, :, None] + jnp.arange(block_size, dtype=jnp.int32)
+            ).reshape(B, width)
 
 
 class Indexer(nn.Module):
@@ -194,7 +231,9 @@ class Indexer(nn.Module):
     read. On the paged path (``index_cache`` ``(pool, plane,
     block_tables, live)``): the chunk's index keys written, and
     ``(pool, choose)``, where ``choose()`` gives each query's chosen
-    positions ``(B, C, k)`` and which of them hold a row of its prefix.
+    rows ``(B, C, k)`` of the pools' flat view (:func:`slot_rows`; the
+    latent pool holds the same blocks) and which of them hold a row of
+    its prefix.
     Applied with the collection ``dsa_chosen`` mutable (the benchmark's
     check), it sows those positions as ``positions``, -1 where a prefix
     is shorter, whether or not the dispatch selects."""
@@ -241,18 +280,27 @@ class Indexer(nn.Module):
         pool = write_rows(pool, plane, tables, positions, live, jnp.pad(
             k, ((0, 0), (0, 0), (0, pool.shape[3] - Di))))
 
-        def choose():
+        def choose(sow=False):
             scores, end = _walked_scores(q, w, pool, plane, tables,
                                          positions, live)
+            T = scores.shape[-1]
             with jax.named_scope("select"):
-                idx = exact_top_k(scores, min(cfg.index_topk,
-                                              scores.shape[-1]), end)
-                return idx, idx <= positions[:, :, None]
+                rows = slot_rows(tables, plane, pool.shape[1], pool.shape[2],
+                                 T)[:, None]
+                # the positions ride along only for the check that reads
+                # them: a payload moves no choice
+                top, *chosen = exact_top_k(
+                    scores, min(cfg.index_topk, T), (rows,) + (
+                        (jnp.arange(T, dtype=jnp.int32),) if sow else ()),
+                    end)
+                # the walk leaves _NEG_INF past a query's position and a
+                # finite score at and before it
+                return (chosen[0], top > _NEG_INF, *chosen[1:])
 
         if self.is_mutable_collection("dsa_chosen"):
-            idx, valid = choose()
+            rows, valid, idx = choose(sow=True)
             self.sow("dsa_chosen", "positions", jnp.where(valid, idx, -1))
-            return pool, lambda: (idx, valid)
+            return pool, lambda: (rows, valid)
         return pool, choose
 
 

@@ -213,9 +213,9 @@ class LatentAttention(nn.Module):
     blocks), the call returns ``(out, pool, index_pool)``, and where
     every live query's prefix is at most ``cfg.index_topk`` the forms
     above run unchanged (the choice is the whole prefix); otherwise the
-    indexer's exact top-k positions of each query are read from the
-    pool where they lie and attended in the absorbed form
-    (:func:`_chosen_attention`), a block of queries at a time."""
+    pool rows of each query's exact top-k positions, as the indexer
+    gives them, are read where they lie and attended in the absorbed
+    form (:func:`_chosen_attention`), a block of queries at a time."""
 
     cfg: Any
     indexer: Any = None
@@ -311,10 +311,10 @@ class LatentAttention(nn.Module):
             ctx = dense()
         else:
             def sparse():
-                idx, valid = chosen()
+                rows, valid = chosen()
                 attend = functools.partial(
-                    _chosen_attention, pool=pool, plane=plane,
-                    tables=block_tables, idx=idx, valid=valid, scale=scale)
+                    _chosen_attention, pool=pool, rows=rows, valid=valid,
+                    scale=scale)
                 return self._absorbed(q_nope, q_rope, width, w_uk, w_uv,
                                       attend)
 
@@ -472,24 +472,18 @@ def write_rows(pool, plane, block_tables, positions, live, rows):
 QUERY_BLOCK = 32
 
 
-def _chosen_attention(q_row, pool, plane, tables, idx, valid, scale):
+def _chosen_attention(q_row, pool, rows, valid, scale):
     """The absorbed form over chosen rows: query ``(b, s)`` reads the
-    ``k`` positions ``idx[b, s]`` ``(B, C, k)`` of its lane (those with
-    ``valid``) from plane ``plane`` of ``pool`` where they lie, and
-    every head's folded query ``(B, C, H, W)`` scores and weighs those
-    rows alone (float32 scores and softmax). :data:`QUERY_BLOCK` columns
-    at a time, so a chunk's gathered rows never exist all at once."""
+    ``k`` rows ``rows[b, s]`` ``(B, C, k)`` of ``pool`` seen as one
+    table of rows, ``(planes * num_blocks * block_size, W)``, where they
+    lie (those with ``valid`` weigh), and every head's folded query
+    ``(B, C, H, W)`` scores and weighs those rows alone (float32 scores
+    and softmax). :data:`QUERY_BLOCK` columns at a time, so a chunk's
+    gathered rows never exist all at once."""
     B, C, H, W = q_row.shape
-    num_blocks, block_size = pool.shape[1:3]
     qb = QUERY_BLOCK if C % QUERY_BLOCK == 0 else C
-    # a chosen position's row in the pool seen as one table of rows (a
-    # view of the whole pool: a plane sliced out would be a copy of it);
-    # a position that is not chosen reads position 0's row, weighed 0
+    # a view of the whole pool: a plane sliced out would be a copy of it
     flat = pool.reshape(-1, W)
-    idx = jnp.where(valid, idx, 0)
-    rowid = (plane * num_blocks + jnp.take_along_axis(
-        tables[:, None, :], idx // block_size, axis=2)) * block_size \
-        + idx % block_size
 
     def split(a):                   # (B, C, ...) -> (C / qb, B, qb, ...)
         a = a.reshape((B, C // qb, qb) + a.shape[2:])
@@ -498,14 +492,14 @@ def _chosen_attention(q_row, pool, plane, tables, idx, valid, scale):
     def attend(args):
         q, ids, ok = args
         with jax.named_scope("sparse_rows"):
-            rows = flat[ids]                               # (B, qb, k, W)
-        scores = jnp.einsum("bqhw,bqkw->bqhk", q, rows,
+            read = flat[ids]                               # (B, qb, k, W)
+        scores = jnp.einsum("bqhw,bqkw->bqhk", q, read,
                             preferred_element_type=jnp.float32)
         probs = _masked_softmax(scores * scale,
-                                ok[:, :, None, :]).astype(rows.dtype)
-        return jnp.einsum("bqhk,bqkw->bqhw", probs, rows)
+                                ok[:, :, None, :]).astype(read.dtype)
+        return jnp.einsum("bqhk,bqkw->bqhw", probs, read)
 
-    out = jax.lax.map(attend, (split(q_row), split(rowid), split(valid)))
+    out = jax.lax.map(attend, (split(q_row), split(rows), split(valid)))
     return jnp.moveaxis(out, 0, 1).reshape(B, C, H, W)
 
 

@@ -83,24 +83,34 @@ def _engine(params, cfg=CFG, **kw):
     return GenerationEngine(DeepseekV32(cfg), params=params, **kw)
 
 
-def _paged(params, tokens, chunk, cfg=CFG, num_blocks=40):
+def _paged(params, tokens, chunk, cfg=CFG, num_blocks=40, tables=None,
+           chosen=None):
     """The paged forward over ``tokens`` ``(1, S)`` a chunk at a time:
-    every position's logits."""
+    every position's logits. ``tables`` ``(1, max blocks)``: the lane's
+    table (blocks 1, 2, ... by default). A list given as ``chosen``
+    receives each chunk's sown positions ``(layers, live, k)``."""
     model = DeepseekV32(cfg)
+    mutable = ["moe_stats"] + ([] if chosen is None else ["dsa_chosen"])
     apply = jax.jit(lambda p, t, c: model.apply(p, t, cache=c,
-                                                mutable=["moe_stats"]))
+                                                mutable=mutable))
     pools = kvc.make_pools(cfg, num_blocks, BLOCK)
-    tables = jnp.arange(1, 1 + cfg.max_seq_len // BLOCK,
-                        dtype=jnp.int32)[None]
+    if tables is None:
+        tables = jnp.arange(1, 1 + cfg.max_seq_len // BLOCK,
+                            dtype=jnp.int32)[None]
     outs, at, S = [], 0, tokens.shape[1]
     while at < S:
         live = min(chunk, S - at)
         t = jnp.zeros((1, chunk), jnp.int32).at[0, :live].set(
             tokens[0, at:at + live])
-        (logits, cache), _ = apply(params, t, PagedCache(
+        (logits, cache), sown = apply(params, t, PagedCache(
             pools, tables, jnp.array([at]), jnp.array([live])))
         pools = cache.pools
         outs.append(logits[0, :live])
+        if chosen is not None:
+            chosen.append(np.stack([np.asarray(
+                sown["dsa_chosen"][f"layer_{i}"]["attn"]["indexer"][
+                    "positions"][0])[0, :live]
+                for i in range(cfg.num_hidden_layers)]))
         at += live
     return jnp.concatenate(outs)
 
@@ -141,15 +151,41 @@ def test_full_forward_matches_reference(params, length):
         atol=TOL)
 
 
-@pytest.mark.parametrize("chunk", [8, 16, 48])
-def test_paged_chunks_match_full_forward(params, chunk):
+@pytest.mark.parametrize("chunk,shuffled", [
+    (8, False), (16, False), (48, False), (48, True)],
+    ids=["8", "16", "48", "48-shuffled"])
+def test_paged_chunks_match_full_forward(params, monkeypatch, chunk,
+                                         shuffled):
     """Chunks that cross the 16th position inside, on and past a chunk's
-    edge: the selection on the paged path is the full forward's."""
+    edge: the selection on the paged path is the full forward's. Over a
+    table of shuffled blocks and with the running top-k merging segments
+    of 16, the positions the indexer sows are the full forward's top-k
+    of every layer too."""
     tokens = _tokens(70, 3)
+    model = DeepseekV32(CFG)
+    full, state = jax.jit(lambda p, t: model.apply(
+        p, t, capture_intermediates=lambda m, _: isinstance(m, dsv.Indexer)))(
+            params, tokens)
+    if not shuffled:
+        np.testing.assert_allclose(_paged(params, tokens, chunk), full[0],
+                                   rtol=0, atol=TOL)
+        return
+    monkeypatch.setattr(dsv, "TOP_K_SEGMENT", 16)
+    rng = np.random.default_rng(5)
+    tables = jnp.asarray(rng.permutation(np.arange(1, 40))[
+        :CFG.max_seq_len // BLOCK][None], jnp.int32)
+    chosen = []
     np.testing.assert_allclose(
-        _paged(params, tokens, chunk),
-        jax.jit(DeepseekV32(CFG).apply)(params, tokens)[0], rtol=0,
-        atol=TOL)
+        _paged(params, tokens, chunk, tables=tables, chosen=chosen), full[0],
+        rtol=0, atol=TOL)
+    chosen = np.concatenate(chosen, axis=1)           # (layers, S, k)
+    inter = state["intermediates"]
+    for i, layer in enumerate(chosen):
+        keep = np.asarray(inter[f"layer_{i}"]["attn"]["indexer"][
+            "__call__"][0])[0, 0]                     # (S, S)
+        for s, picked in enumerate(layer):
+            assert set(picked[picked >= 0]) == set(
+                np.flatnonzero(keep[s, :s + 1])), (i, s)
 
 
 def test_a_departure_from_the_equations_is_caught(params):
@@ -275,19 +311,41 @@ def test_scheduler_counts_the_rows_attention_reads(params):
 
 # -- the parts ----------------------------------------------------------------
 
-@pytest.mark.parametrize("end", [None, 37, 100])
-def test_segmented_top_k_is_lax_top_k(monkeypatch, end):
-    """By segments of 16 with ties inside and across segments (and, with
-    ``end``, the scores past it never ranked, as the walk leaves them):
-    the positions of one ``top_k``, in its order."""
+@pytest.mark.parametrize("end,first,last", [
+    (None, 99, 99), (37, 20, 36), (48, 17, 47), (100, 30, 99), (16, 0, 14)],
+    ids=["None", "37", "48", "100", "short"])
+def test_segmented_top_k_is_lax_top_k(monkeypatch, end, first, last):
+    """By segments of 16 over 100 slots, two lanes of five queries whose
+    positions lie in ``first..last`` (the walk's ``_NEG_INF`` past each,
+    ``end`` inside a segment, on its edge, at the row's end, or none;
+    ``short``: prefixes under ``k``), ties inside and across segments and
+    both signed zeros: the values and positions of one ``top_k``, in its
+    order; each position's row the one its lane's table maps it to
+    (tables of shuffled, scattered blocks); valid exactly where the
+    position is in the query's prefix."""
     monkeypatch.setattr(dsv, "TOP_K_SEGMENT", 16)
-    scores = jnp.round(jax.random.normal(jax.random.PRNGKey(2), (3, 5, 100)),
-                       1)
-    if end is not None:
-        scores = jnp.where(jnp.arange(100) < end, scores, dsv._NEG_INF)
+    T, k, bs, nb, plane = 100, 16, 4, 64, 2
+    rng = np.random.default_rng(end or 0)
+    positions = rng.integers(first, last + 1, (2, 5))
+    positions[0, 0] = last
+    tables = rng.permutation(np.arange(1, nb))[:2 * (T // bs)].reshape(2, -1)
+    scores = jnp.round(jax.random.normal(jax.random.PRNGKey(2), (2, 5, T),
+                                         jnp.float32), 1)
+    # top_k ranks 0.0 above -0.0, whichever comes first
+    scores = scores.at[..., 0].set(-0.0).at[..., 1].set(0.0)
+    scores = jnp.where(jnp.arange(T) <= positions[..., None], scores,
+                       dsv._NEG_INF)
+    top, rows, idx = dsv.exact_top_k(
+        scores, k, (dsv.slot_rows(jnp.asarray(tables), plane, nb, bs, T)[
+            :, None], jnp.arange(T)), None if end is None else jnp.int32(end))
+    want_top, want_idx = jax.lax.top_k(scores, k)
+    np.testing.assert_array_equal(idx, want_idx)
+    np.testing.assert_array_equal(top, want_top)
+    blocks = np.take_along_axis(tables[:, None, :], np.asarray(idx) // bs, 2)
     np.testing.assert_array_equal(
-        dsv.exact_top_k(scores, 16, None if end is None else jnp.int32(end)),
-        jax.lax.top_k(scores, 16)[1])
+        rows, (plane * nb + blocks) * bs + np.asarray(idx) % bs)
+    np.testing.assert_array_equal(top > dsv._NEG_INF,
+                                  idx <= positions[..., None])
 
 
 def _route_by_loop(logits, bias, k, n_group, topk_group, scale):
