@@ -628,7 +628,9 @@ GEN_SPEC_MODE = _register(
          "the plain one-token decode loop; 'ngram' drafts by suffix-"
          "matching the sequence's own prompt + emitted tokens (zero "
          "extra model); 'draft' rolls a small draft model forward on "
-         "the host (the engine's draft_model/draft_params arguments). "
+         "the host (the engine's draft_model/draft_params arguments); "
+         "'mtp' drafts on the device with the model's own multi-token "
+         "prediction module (a model without one is refused). "
          "Drafted tokens are verified in one paged forward per step "
          "and the accepted prefix is exactly what the plain decoder "
          "would have produced, so speculative output is bit-identical "

@@ -11,6 +11,7 @@ from .resnet import ResNet, ResNet18, ResNet34, ResNet50, ResNet101, ResNet152  
 from .mlp import MLP  # noqa: F401
 from .command_a_plus import CommandAPlus, CommandAPlusConfig  # noqa: F401
 from .deepseek_v32 import DeepseekV32, DeepseekV32Config  # noqa: F401
+from .glm47_flash import Glm47Flash, Glm47FlashConfig  # noqa: F401
 from .longcat_flash import LongcatFlash, LongcatFlashConfig  # noqa: F401
 from .olmo_hybrid import OlmoHybrid, OlmoHybridConfig  # noqa: F401
 from .transformer import (CacheSpec, PagedCache, PlaneGroup,  # noqa: F401

@@ -395,10 +395,15 @@ class ExpertLayer(nn.Module):
 
 
 class Layer(nn.Module):
-    """One layer (module docstring); ``dense``: a leading dense layer."""
+    """One layer (module docstring); ``dense``: a leading dense layer.
+    ``indexer``: the selection of :class:`LatentAttention` (None: MLA
+    over the whole prefix, as GLM-4.7-Flash's layers,
+    :mod:`.glm47_flash`). On the paged path it returns ``h`` and the
+    pools its attention returns."""
 
-    cfg: DeepseekV32Config
+    cfg: Any
     dense: bool
+    indexer: Any = Indexer
 
     @nn.compact
     def __call__(self, h, positions, mask, valid, layer_cache=None,
@@ -407,13 +412,12 @@ class Layer(nn.Module):
         norm = lambda name, x: rms_norm(  # noqa: E731
             x, self.param(name, nn.initializers.ones, (cfg.hidden_size,),
                           cfg.param_dtype), cfg.rms_norm_eps)
-        attn = LatentAttention(cfg, indexer=Indexer, name="attn")
+        attn = LatentAttention(cfg, indexer=self.indexer, name="attn")
         x = norm("input_layernorm", h)
         if layer_cache is None:
             h = h + attn(x, positions, mask)
         else:
-            a, pool, index_pool = attn(x, positions, mask, layer_cache,
-                                       index_cache)
+            a, *pools = attn(x, positions, mask, layer_cache, index_cache)
             h = h + a
         u = norm("post_attention_layernorm", h)
         if self.dense:
@@ -421,7 +425,7 @@ class Layer(nn.Module):
                              cfg.param_dtype, name="mlp")(u)
         else:
             h = h + ExpertLayer(cfg, name="moe")(u, valid)
-        return h if layer_cache is None else (h, pool, index_pool)
+        return h if layer_cache is None else (h, *pools)
 
 
 class DeepseekV32(nn.Module):

@@ -25,9 +25,11 @@ same admission machinery:
   the shared checkpoint restore + hot-reload lifecycle
   (:class:`~horovod_tpu.serving.engine.ParamsLifecycle`);
 * :mod:`.spec` — speculative decoding proposers
-  (``HVD_TPU_GEN_SPEC_MODE``): n-gram self-drafting or a small draft
-  model, verified k-at-a-time by :func:`build_verify_program` with
-  output bit-identical to plain decode; beam search
+  (``HVD_TPU_GEN_SPEC_MODE``): n-gram self-drafting, a small draft
+  model, or the model's own multi-token prediction module drafting on
+  the device (``mtp``), verified k-at-a-time by
+  :func:`build_verify_program` with output bit-identical to plain
+  decode; beam search
   (``num_beams`` at submit, capped by ``HVD_TPU_GEN_BEAMS``) rides the
   same paged cache via :func:`build_beam_program` with
   copy-on-extend block forking.

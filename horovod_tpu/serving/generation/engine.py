@@ -41,8 +41,12 @@ no traffic can exhaust), and is refused by the same three paths
 (:class:`~.kv_cache.PlaneGroupsError`).
 :class:`~horovod_tpu.models.transformer.Transformer`,
 :class:`~horovod_tpu.models.longcat_flash.LongcatFlash`,
-:class:`~horovod_tpu.models.olmo_hybrid.OlmoHybrid` and
-:class:`~horovod_tpu.models.command_a_plus.CommandAPlus` are the four.
+:class:`~horovod_tpu.models.olmo_hybrid.OlmoHybrid`,
+:class:`~horovod_tpu.models.command_a_plus.CommandAPlus`,
+:class:`~horovod_tpu.models.deepseek_v32.DeepseekV32` and
+:class:`~horovod_tpu.models.glm47_flash.Glm47Flash` are six; the last
+drafts for ``spec_mode='mtp'`` with its own multi-token prediction
+module.
 """
 
 from typing import Any, List, Optional, Sequence
@@ -52,7 +56,8 @@ from ..engine import ParamsLifecycle
 from .kv_cache import (BlockAllocator, build_beam_program,
                        build_decode_program, build_prefill_program,
                        build_verify_program, make_pools, make_state_pools,
-                       refuse_groups, refuse_state, state_bytes)
+                       mtp_depth, refuse_groups, refuse_mtp, refuse_state,
+                       state_bytes)
 from .scheduler import DECODE_WIDTH, ContinuousBatcher, GenSequence
 from .spec import make_proposer
 
@@ -79,11 +84,14 @@ class GenerationEngine:
         is bit-identical to cold decode either way).
       spec_mode: speculative decoding proposer — ``off`` | ``ngram``
         (prompt-lookup self-drafting) | ``draft`` (requires
-        ``draft_model``). None reads ``HVD_TPU_GEN_SPEC_MODE``. Spec
+        ``draft_model``) | ``mtp`` (the model's own multi-token
+        prediction module, on the device; a model without one is
+        refused). None reads ``HVD_TPU_GEN_SPEC_MODE``. Spec
         output is bit-identical to plain decode (greedy AND seeded
         sampling, logprobs included) — the knob only buys throughput.
       spec_tokens: static draft width of the compiled verify program
-        (None reads ``HVD_TPU_GEN_SPEC_TOKENS``).
+        (None reads ``HVD_TPU_GEN_SPEC_TOKENS``; with ``mtp`` the
+        model's MTP depth, the only width it takes).
       max_beams: widest ``num_beams`` this engine accepts; the beam
         step program is compiled for this top-K. 1 disables beam
         search entirely (None reads ``HVD_TPU_GEN_BEAMS``).
@@ -129,8 +137,12 @@ class GenerationEngine:
                          if num_blocks is None else num_blocks)
         spec_mode = str(cfg.get(_config.GEN_SPEC_MODE)
                         if spec_mode is None else spec_mode).strip().lower()
-        spec_tokens = int(cfg.get(_config.GEN_SPEC_TOKENS)
-                          if spec_tokens is None else spec_tokens)
+        mtp = spec_mode == "mtp"
+        if mtp:
+            refuse_mtp(model.cfg)
+        spec_tokens = int(
+            (mtp_depth(model.cfg) if mtp else cfg.get(_config.GEN_SPEC_TOKENS))
+            if spec_tokens is None else spec_tokens)
         max_beams = int(cfg.get(_config.GEN_BEAMS)
                         if max_beams is None else max_beams)
         self.model = model
@@ -181,12 +193,12 @@ class GenerationEngine:
         self._proposer = make_proposer(
             spec_mode, draft_model=draft_model, params=draft_params,
             checkpoint_dir=draft_checkpoint_dir) if not spec_off else None
-        verify_prog = (build_verify_program(model, spec_tokens)
+        verify_prog = (build_verify_program(model, spec_tokens, mtp=mtp)
                        if self._proposer is not None else None)
         beam_prog = (build_beam_program(model, max_beams, DECODE_WIDTH)
                      if max_beams > 1 else None)
         self.batcher = ContinuousBatcher(
-            (build_prefill_program(model),
+            (build_prefill_program(model, mtp=mtp),
              build_decode_program(model, DECODE_WIDTH)),
             lambda: self._lifecycle.snapshot()[0],
             pools, self.allocator,
@@ -330,7 +342,8 @@ class GenerationEngine:
 
     @property
     def spec_mode(self) -> str:
-        """The active speculative-decoding proposer: off|ngram|draft."""
+        """The active speculative-decoding proposer:
+        off|ngram|draft|mtp."""
         return self.batcher.spec_mode if self.batcher.spec else "off"
 
     @property

@@ -184,6 +184,16 @@ def refuse_state(model_cfg, what: str) -> None:
             f"{', '.join(name for name, *_ in state)})")
 
 
+def refuse_mtp(model_cfg) -> None:
+    """Raise ``ValueError`` unless ``model_cfg`` declares an MTP module
+    (:func:`mtp_depth`): ``spec_mode='mtp'`` drafts with it."""
+    if mtp_depth(model_cfg) < 1:
+        raise ValueError(
+            f"spec_mode='mtp' drafts with the model's multi-token "
+            f"prediction module, and {type(model_cfg).__name__} declares "
+            f"none (num_nextn_predict_layers)")
+
+
 class PlaneGroupsError(ValueError):
     """A path that keeps **one block list a sequence** was asked to
     serve a model whose cache declares plane groups
@@ -902,26 +912,51 @@ def scatter_blocks(pools, blocks: Sequence[int], data):
                  for p, d in zip(pools, data))
 
 
-def _paged_apply(model, params, tokens, cache, logits_at=None):
-    """``model.apply`` on the paged path: ``(logits, cache, stats)``.
+def _paged_apply(model, params, tokens, cache, logits_at=None, **kwargs):
+    """``model.apply`` on the paged path: ``(logits, cache, stats)``, or
+    with ``kwargs`` that make the model return more (an MTP model's
+    ``with_hidden``) ``(logits, cache, *more, stats)``; ``kwargs`` also
+    carries ``method`` (:meth:`~horovod_tpu.models.Glm47Flash.mtp_forward`).
     ``stats`` is ``()`` for a model that declares no experts, so its
     programs return what they always did; for one that does
     (``model.cfg.held_experts``) it is ``(counts,)``: the id of the
     first expert the model holds, then the layers' int32 routing counts
     summed. The sampling programs return it beside their tokens, and the
     vector says whose counts they are."""
-    kwargs = {} if logits_at is None else {"logits_at": logits_at}
+    if logits_at is not None:
+        kwargs["logits_at"] = logits_at
     if not getattr(model.cfg, "held_experts", None):
-        logits, cache = model.apply(params, tokens, cache=cache, **kwargs)
-        return logits, cache, ()
+        return (*model.apply(params, tokens, cache=cache, **kwargs), ())
     import jax
 
     from ...parallel.moe import STATS_COLLECTION
-    (logits, cache), sown = model.apply(
+    out, sown = model.apply(
         params, tokens, cache=cache, mutable=[STATS_COLLECTION], **kwargs)
     counts = sum(jax.tree_util.tree_leaves(sown[STATS_COLLECTION]))
     first = jax.numpy.asarray(model.cfg.held_experts[:1], counts.dtype)
-    return logits, cache, (jax.numpy.concatenate([first, counts]),)
+    return (*out, (jax.numpy.concatenate([first, counts]),))
+
+
+def _mtp_apply(model, params, tokens, hidden, cache, logits_at, stats):
+    """The MTP module's pass (:func:`_paged_apply` of the model's
+    ``mtp_forward``) over ``tokens`` (``t_{i+1}`` at the column of
+    position ``i``) and ``hidden``, the main pass's final hidden states:
+    ``(greedy draft (B,) int32, cache, stats)``, its routing counts
+    added into the main pass's ``stats``."""
+    import jax.numpy as jnp
+
+    logits, cache, more = _paged_apply(
+        model, params, tokens, cache, logits_at=logits_at, hidden=hidden,
+        method=type(model).mtp_forward)
+    stats = tuple(a.at[1:].add(b[1:]) for a, b in zip(stats, more))
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache, stats
+
+
+def mtp_depth(model_cfg) -> int:
+    """Multi-token prediction modules the model declares
+    (``num_nextn_predict_layers``): what ``spec_mode='mtp'`` drafts
+    with, a token a module; 0 for a model without."""
+    return int(getattr(model_cfg, "num_nextn_predict_layers", 0) or 0)
 
 
 def _query_rows(model, chunk: int):
@@ -1041,6 +1076,10 @@ class DecodeState:
     remaining: Any
     eos: Any
     sample: SampleParams
+    #: ``(B,)`` int32, the MTP verify step's draft for each lane (the
+    #: token after ``tokens``, from the MTP module's logits of the step
+    #: that emitted ``tokens``); None for every other program
+    draft: Any = None
 
 
 def _register_pytrees():
@@ -1052,7 +1091,7 @@ def _register_pytrees():
     jax.tree_util.register_dataclass(
         DecodeState,
         data_fields=["tokens", "lengths", "live", "remaining", "eos",
-                     "sample"],
+                     "sample", "draft"],
         meta_fields=[])
 
 
@@ -1224,7 +1263,7 @@ def _sample_tokens(logits, sample: SampleParams):
 
 
 @functools.lru_cache(maxsize=8)
-def build_prefill_program(model):
+def build_prefill_program(model, mtp: bool = False):
     """The sampling prefill program:
     ``(params, PagedCache, tokens, SampleParams) ->
     (token (B,), logprob (B,), PagedCache)`` (and, last, the routing
@@ -1234,6 +1273,17 @@ def build_prefill_program(model):
     position's next token is sampled on device — the host never sees
     chunk logits, so intermediate chunks don't even synchronize. The
     cache is donated; ``tokens`` is ``(1, prefill_chunk)``.
+
+    ``mtp`` (``spec_mode='mtp'``, a model with an MTP module): the
+    program takes one more argument, ``lookahead`` ``(B,)`` int32, the
+    token after the chunk's last live one where the host knows it (the
+    prompt's next token, or a recomputed sequence's next input) and -1
+    where it is the token sampled here, and returns ``draft`` ``(B,)``
+    after the cache. The MTP module runs over the chunk's positions
+    with the tokens shifted by one (``lookahead`` at the last live
+    column), writing its plane's row at each of them, and ``draft`` is
+    its greedy token two places past the chunk's last: after the last
+    chunk, the verify step's first draft.
     """
     import jax
     import jax.numpy as jnp
@@ -1245,7 +1295,21 @@ def build_prefill_program(model):
         token, logprob = sample_tokens(logits, sample)
         return (token, logprob, cache, *stats)
 
-    program = jax.jit(_prefill, donate_argnums=(1,))
+    def _prefill_mtp(params, cache, tokens, sample, lookahead):
+        at = jnp.maximum(cache.live - 1, 0).astype(jnp.int32)
+        logits, cache, hidden, stats = _paged_apply(
+            model, params, tokens, cache, logits_at=at, with_hidden=True)
+        token, logprob = sample_tokens(logits, sample)
+        rows = jnp.arange(tokens.shape[0])
+        shifted = jnp.roll(tokens, -1, axis=1).at[rows, at].set(
+            jnp.where(lookahead >= 0, lookahead, token))
+        draft, cache, stats = _mtp_apply(model, params, shifted, hidden,
+                                         cache, at, stats)
+        return (token, logprob, cache, draft, *stats)
+
+    if mtp:
+        refuse_mtp(model.cfg)
+    program = jax.jit(_prefill_mtp if mtp else _prefill, donate_argnums=(1,))
     program.keys_walked = getattr(model.cfg, "prefill_keys_walked", None)
     program.attention_reads = getattr(model.cfg, "attention_reads", None)
     return program
@@ -1305,11 +1369,13 @@ def build_decode_program(model, decode_width: int = 2):
 
 
 @functools.lru_cache(maxsize=8)
-def build_verify_program(model, spec_tokens: int):
+def build_verify_program(model, spec_tokens: int, mtp: bool = False):
     """The speculative-decoding verify step:
     ``(params, pools, tables, DecodeState, draft (B, S),
     draft_len (B,)) -> (pools, DecodeState, pred (B, S+1),
-    logprob (B, S+1), n_emit (B,))``.
+    logprob (B, S+1), n_emit (B,))`` (and, last, the routing counts of
+    a model with experts, drafted positions included: they were
+    computed).
 
     One paged forward scores a lane's current input token plus up to
     ``S = spec_tokens`` drafted continuations in a single chunk of
@@ -1338,6 +1404,21 @@ def build_verify_program(model, spec_tokens: int):
     The pools and the state are donated; ``tables`` is not. The
     per-step transfer is ``(B, S+1)`` tokens + logprobs plus the
     ``(B,)`` accept count — still never logits.
+
+    ``mtp`` (``spec_mode='mtp'``, ``spec_tokens`` the model's MTP depth,
+    1): the draft is the device's own, ``DecodeState.draft``, and the
+    program is ``(params, pools, tables, DecodeState) -> (pools,
+    DecodeState, pred, logprob, n_emit, draft (B,))``: ``draft`` is the
+    new state's draft where a lane was live and -1 elsewhere, an array
+    of its own, so the host may read it after the state is donated to
+    the next step. After the accept rule the MTP
+    module runs over the committed positions ``L .. L + n_emit - 1``,
+    the tokens shifted by one (``pred``: the token each position
+    emitted) beside the verify forward's hidden states there, writing
+    its plane's rows of those positions and nothing else, and the next
+    draft, its greedy token at the last of them, goes into the new
+    state. Rejected positions are rolled back in every plane, the MTP
+    plane included, as above.
     """
     import jax
     import jax.numpy as jnp
@@ -1348,7 +1429,19 @@ def build_verify_program(model, spec_tokens: int):
     S = int(spec_tokens)
     if S < 1:
         raise ValueError(f"spec_tokens={spec_tokens}: must be >= 1")
+    if mtp:
+        refuse_mtp(model.cfg)
+        if S != mtp_depth(model.cfg):
+            raise ValueError(
+                f"spec_tokens={spec_tokens}: the MTP verify step drafts "
+                f"one token a module, and the model has "
+                f"{mtp_depth(model.cfg)}")
     C = S + 1
+
+    def _verify_mtp(params, pools, tables, state):
+        # one draft a live lane, clipped to its budget like any other
+        return _verify(params, pools, tables, state, state.draft[:, None],
+                       state.live)
 
     def _verify(params, pools, tables, state, draft, draft_len):
         block_size = pools[0].shape[2]
@@ -1382,9 +1475,9 @@ def build_verify_program(model, spec_tokens: int):
                       for p, o in zip(pools, orig))
 
         cache = PagedCache(pools, tables, state.lengths, width)
-        # a verify step's routing is not counted: the counters would
-        # hold the rejected drafts' tokens too
-        logits, cache, _ = _paged_apply(model, params, chunk, cache)
+        logits, cache, *hidden, stats = _paged_apply(
+            model, params, chunk, cache, **({"with_hidden": True}
+                                            if mtp else {}))
 
         # per-position resample: position i's draw is the plain
         # decoder's emission `emitted + i` — same ops, same fold_in,
@@ -1416,6 +1509,14 @@ def build_verify_program(model, spec_tokens: int):
                              jnp.minimum(eos_limit, state.remaining))
         n_emit = jnp.where(alive, n_emit, 0).astype(jnp.int32)
 
+        if mtp:
+            # the MTP module at the committed positions: position L + j
+            # reads the token it emitted, pred[:, j]
+            new_draft, cache, stats = _mtp_apply(
+                model, params, pred, hidden[0],
+                dataclasses.replace(cache, live=n_emit),
+                jnp.maximum(n_emit - 1, 0), stats)
+
         # roll back rejected slots: restore originals everywhere except
         # the committed prefix, whose restore writes go to block 0
         committed = jnp.arange(C)[None, :] < n_emit[:, None]
@@ -1434,10 +1535,12 @@ def build_verify_program(model, spec_tokens: int):
             remaining=state.remaining - n_emit,
             eos=state.eos,
             sample=dataclasses.replace(
-                state.sample, emitted=state.sample.emitted + n_emit))
-        return new_pools, new_state, pred, logp, n_emit
+                state.sample, emitted=state.sample.emitted + n_emit),
+            draft=jnp.where(alive, new_draft, state.draft) if mtp else None)
+        drafts = (jnp.where(alive, new_draft, -1),) if mtp else ()
+        return (new_pools, new_state, pred, logp, n_emit, *drafts, *stats)
 
-    program = jax.jit(_verify, donate_argnums=(1, 3))
+    program = jax.jit(_verify_mtp if mtp else _verify, donate_argnums=(1, 3))
     program.query_rows = _query_rows(model, C)
     return program
 

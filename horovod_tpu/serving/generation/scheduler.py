@@ -100,7 +100,17 @@ seeded sampling, logprobs included). The spec loop runs synchronously
 step to overlap — and multi-token emission is what pays: each
 accepted draft saves a whole decode-step weight read. Rejected draft
 positions are rolled back through the null block inside the program;
-the cache is never corrupted by an unaccepted token.
+the cache is never corrupted by an unaccepted token. With
+``spec_mode='mtp'`` (a model with a multi-token prediction module) no
+proposer runs on the host: the prefill and verify programs run the
+model's MTP module themselves and keep the next draft in the device's
+``DecodeState.draft``, so the verify step pipelines exactly as the
+plain decode step does (``HVD_TPU_GEN_ASYNC_DEPTH``): the next step is
+dispatched before the last one's tokens are read. The host keeps a
+mirror of each lane's draft (read in the transfer that brings a step's
+tokens) only to rebuild that state when lanes change, and a prefix hit
+stops one block short, since the MTP plane's row at a hit's last
+position was computed from the token after it, which no hash covers.
 
 **Beam search** (``num_beams > 1`` at submit; greedy only) runs as a
 synchronous sub-loop the moment the request enters decode: width-W
@@ -227,8 +237,10 @@ _M_MOE_TOUCHED = _metrics.counter(
     labels=("phase",))
 _M_MOE_CALLS = _metrics.counter(
     "hvd_tpu_gen_moe_calls_total",
-    "Prefill chunks and decode steps whose routing counts were read "
-    "(verify and beam steps are not counted).",
+    "Prefill chunks, decode steps and verify steps (phase='verify': "
+    "drafted positions and an MTP module's pass included, since they "
+    "are computed) whose routing counts were read (beam steps are not "
+    "counted).",
     labels=("phase",))
 _M_PAGED_BLOCKS = _metrics.counter(
     "hvd_tpu_gen_paged_attn_blocks_total",
@@ -422,16 +434,19 @@ _M_PARKED = _metrics.counter(
 _M_SPEC_DRAFTED = _metrics.counter(
     "hvd_tpu_gen_spec_drafted_total",
     "Tokens proposed by the speculative-decoding drafter "
-    "(HVD_TPU_GEN_SPEC_MODE), summed over lanes and verify steps. "
-    "accepted/drafted is the fleet accept rate — the single number "
-    "that says whether speculation pays on this workload.")
+    "(HVD_TPU_GEN_SPEC_MODE), summed over lanes and verify steps, by "
+    "the proposer (mode='ngram' | 'draft' | 'mtp'). accepted/drafted "
+    "is the fleet accept rate — the single number that says whether "
+    "speculation pays on this workload.",
+    labels=("mode",))
 _M_SPEC_ACCEPTED = _metrics.counter(
     "hvd_tpu_gen_spec_accepted_total",
     "Drafted tokens the verify step accepted (they equalled what the "
     "plain decoder would have produced at their position). Every "
     "accepted token is a decode-step weight read saved; the bonus "
     "token each verify step emits past the accepted prefix is not "
-    "counted here — it is not a draft.")
+    "counted here — it is not a draft. By the proposer (mode).",
+    labels=("mode",))
 _M_SPEC_ACCEPT_LEN = _metrics.histogram(
     "hvd_tpu_gen_spec_accept_length",
     "Accepted drafted tokens per lane per verify step (0 = the draft "
@@ -439,8 +454,8 @@ _M_SPEC_ACCEPT_LEN = _metrics.histogram(
     "token). Mass pinned at HVD_TPU_GEN_SPEC_TOKENS means the draft "
     "width, not the proposer, is the binding constraint — raising it "
     "may pay; mass at 0 means speculation is pure overhead on this "
-    "workload.",
-    buckets=(0, 1, 2, 3, 4, 6, 8, 16))
+    "workload. By the proposer (mode).",
+    labels=("mode",), buckets=(0, 1, 2, 3, 4, 6, 8, 16))
 
 class RequestCancelledError(RuntimeError):
     """The request was cancelled via :meth:`ContinuousBatcher.cancel`
@@ -545,7 +560,7 @@ class GenSequence:
                  "block_hashes", "cache_gen", "request_id", "trace",
                  "num_beams", "first_dispatch_at", "first_token_at",
                  "state_slot", "wblocks", "wreleased", "last_token_ns",
-                 "chunks_seen", "preempted")
+                 "chunks_seen", "preempted", "draft")
 
     def __init__(self, seq_id: int, prompt: List[int], max_tokens: int,
                  eos_id: Optional[int], deadline_s: float,
@@ -602,6 +617,10 @@ class GenSequence:
         #: tokens whose K/V must be in the cache before decoding resumes
         #: (the prompt; after a preemption, prompt + regenerated history)
         self.prefill_tokens: List[int] = list(prompt)
+        #: spec_mode='mtp': the host's mirror of the lane's device draft
+        #: (DecodeState.draft), set by the last prefill chunk and every
+        #: verify step; only read to rebuild the decode state
+        self.draft = 0
         self.prefilled = 0
         #: tokens actually written to the cache so far
         self.cache_len = 0
@@ -787,6 +806,12 @@ class ContinuousBatcher:
             if spec_mode is None else spec_mode).strip().lower()
         self.spec = (self._verify_prog is not None
                      and self._proposer is not None)
+        #: the draft is the device's own (spec_mode='mtp'): the prefill
+        #: program takes a lookahead token and returns the first draft,
+        #: the verify program reads and writes DecodeState.draft, and
+        #: the verify step takes the plain decode step's pipeline
+        self._mtp = self.spec and self.spec_mode == "mtp"
+        self._step_name = "verify_mtp" if self._mtp else "decode"
         #: beam search: the compiled top-k beam step; requests with
         #: num_beams > 1 are rejected at submit when absent
         self._beam_prog = beam_program
@@ -1422,9 +1447,9 @@ class ContinuousBatcher:
                 break
             need_total = self._alloc.blocks_for(len(s.prefill_tokens) + 1)
             matched = matched_cached = 0
-            if self._prefix_cache and s.prefix_hashes:
-                matched, matched_cached = \
-                    self._alloc.match_probe(s.prefix_hashes)
+            hashes = self._hit_hashes(s)
+            if hashes:
+                matched, matched_cached = self._alloc.match_probe(hashes)
             # matched cached blocks leave the evictable pool the moment
             # they attach, so they must not double-count as evictable
             evictable = self._alloc.cached_blocks - matched_cached
@@ -1438,8 +1463,8 @@ class ContinuousBatcher:
             s.block_hashes = []
             s.wblocks, s.wreleased = [], 0
             if self._prefix_cache:
-                s.blocks = self._alloc.match(s.prefix_hashes)
-                s.block_hashes = list(s.prefix_hashes[:len(s.blocks)])
+                s.blocks = self._alloc.match(hashes)
+                s.block_hashes = list(hashes[:len(s.blocks)])
                 s.prefilled = len(s.blocks) * self._alloc.block_size
                 s.cache_len = s.prefilled
                 if self._walloc is not None:
@@ -1467,6 +1492,22 @@ class ContinuousBatcher:
                     s.state_slot, self._alloc.snapshot_of(s.blocks[-1])
                     if s.blocks else 0)
             self._running.append(s)
+
+    def _hit_hashes(self, s: GenSequence) -> List[str]:
+        """The chain hashes ``s`` may match in the prefix cache: none
+        with the cache off; all of them, but with ``spec_mode='mtp'``
+        those of the hit less its last block. The MTP plane's row at a
+        position reads the token after it, so the row at a hit's last
+        position holds the next token of whichever sequence wrote it,
+        which no hash covers; one block earlier, that token lies inside
+        the hit. The cut block is computed again, in a block of ``s``'s
+        own."""
+        if not (self._prefix_cache and s.prefix_hashes):
+            return []
+        if not self._mtp:
+            return s.prefix_hashes
+        matched, _ = self._alloc.match_probe(s.prefix_hashes)
+        return s.prefix_hashes[:max(0, matched - 1)]
 
     # -- prefill -------------------------------------------------------------
 
@@ -1518,8 +1559,8 @@ class ContinuousBatcher:
                 ready = self._prepare_chunk(s)
             if ready is None:
                 return          # s itself was preempted; nothing to run
-        live, total, cache, tokens, sample, cut = ready
-        args = (PagedCache(self._pools, *cache), tokens, sample)
+        live, total, cache, tokens, sample, cut, *lookahead = ready
+        args = (PagedCache(self._pools, *cache), tokens, sample, *lookahead)
         if s.request_id:
             _tracing.note_request(s.request_id)
         flight = next(self._flights)
@@ -1542,14 +1583,15 @@ class ContinuousBatcher:
                 _M_SAMPLE_STEPS.labels(cut=cut).inc()
                 if s.first_dispatch_at is None:
                     self._first_dispatch(s)
-                tok, logp = self._run_prefill(*args)
+                tok, logp, *draft = self._run_prefill(*args)
         except Exception as e:  # noqa: BLE001 — fails only this sequence
             self._deliver_error(s, e)
             return
         self._chunks += 1
         self._carried[0] += live
         with spans.span("gen.deliver"):
-            self._deliver_prefill(s, live, total, tok, logp, now, flight)
+            self._deliver_prefill(s, live, total, tok, logp, now, flight,
+                                  *draft)
 
     def _chunk_blocks_available(self, s: GenSequence) -> bool:
         """Whether ``s``'s next chunk can take its blocks (in every
@@ -1592,8 +1634,26 @@ class ContinuousBatcher:
             temperature=jnp.asarray(temp), top_k=jnp.asarray(top_k),
             top_p=jnp.asarray(top_p), key=jnp.asarray(s.key[None, :]),
             emitted=jnp.asarray([s.sample_offset], jnp.int32))
-        return (live, total, cache, jnp.asarray(tokens), sample,
-                sample_cut(temp, top_k, top_p))
+        ready = (live, total, cache, jnp.asarray(tokens), sample,
+                 sample_cut(temp, top_k, top_p))
+        if self._mtp:
+            ready += (jnp.asarray([self._lookahead(s, s.prefilled + live)],
+                                  jnp.int32),)
+        return ready
+
+    @staticmethod
+    def _lookahead(s: GenSequence, end: int) -> int:
+        """The token after position ``end - 1`` of ``s``'s prefill where
+        the host knows it, for the MTP module's pass over the chunk that
+        ends there: the next prefill token; after the last, the decode
+        input the sequence resumes with (a recomputed sequence's newest
+        token, a beam's held-back prompt token); else -1, the token the
+        chunk samples."""
+        if end < len(s.prefill_tokens):
+            return s.prefill_tokens[end]
+        if s.resume_decode:
+            return s.generated[-1]
+        return s.prompt[-1] if s.num_beams > 1 else -1
 
     def _first_dispatch(self, s: GenSequence) -> None:
         """The request's wait for its first prefill chunk ends here:
@@ -1604,7 +1664,8 @@ class ContinuousBatcher:
                            args={"seq": s.id})
 
     def _deliver_prefill(self, s: GenSequence, live: int, total: int,
-                         tok, logp, now: float, flight: int) -> None:
+                         tok, logp, now: float, flight: int,
+                         draft=None) -> None:
         _M_TOKENS.labels(phase="prefill").inc(live)
         s.prefilled += live
         s.cache_len = s.prefilled
@@ -1627,6 +1688,13 @@ class ContinuousBatcher:
         if s.prefilled == total:
             s.state = "decode"
             self._epoch += 1        # a new lane joins the decode batch
+            if draft is not None and s.resume_decode:
+                # the draft for the decode state the lane rejoins (a
+                # fresh sequence's comes with its first token below; a
+                # beam's is never read)
+                with self._spans.span("gen.wait", program="prefill",
+                                      flight=flight):
+                    s.draft = int(self._readback((draft,))[0][0])
             if s.num_beams > 1:
                 # beam requests held the prompt's last token back from
                 # prefill: it is the beam loop's first input, so the
@@ -1650,7 +1718,10 @@ class ContinuousBatcher:
                 _M_TOKENS.labels(phase="decode").inc()
                 with self._spans.span("gen.wait", program="prefill",
                                       flight=flight):
-                    tok_v, logp_v = self._readback((tok, logp))
+                    tok_v, logp_v, *draft_v = self._readback(
+                        (tok, logp) + (() if draft is None else (draft,)))
+                if draft_v:
+                    s.draft = int(draft_v[0][0])
                 self._delivery_ns = time.perf_counter_ns()
                 logp_v = _corrupt_logprobs(logp_v, [s])
                 if not np.isfinite(logp_v[0]):
@@ -1694,10 +1765,11 @@ class ContinuousBatcher:
             if n:
                 _M_MOE_EXPERT_PICKS.labels(expert=str(e)).inc(int(n))
 
-    def _run_prefill(self, cache: PagedCache, tokens, sample):
+    def _run_prefill(self, cache: PagedCache, tokens, sample, *lookahead):
+        """``(token, logprob)``, and with ``spec_mode='mtp'`` the draft."""
         try:
             tok, logp, cache, *stats = self._prefill_prog(
-                self._params(), cache, tokens, sample)
+                self._params(), cache, tokens, sample, *lookahead)
         except Exception:
             # the pools were donated into the failed call and may be
             # deleted — without recovery every later step would die on
@@ -1707,10 +1779,12 @@ class ContinuousBatcher:
             self._reset_device()
             raise
         self._pools = cache.pools
+        draft = stats[:1] if self._mtp else ()
+        stats = stats[len(draft):]
         self._moe_pending.extend(("prefill", c) for c in stats)
         if len(self._moe_pending) >= 64:
             self._readback(())      # no token is read in this mode
-        return tok, logp
+        return (tok, logp, *draft)
 
     # -- per-sequence state --------------------------------------------------
 
@@ -1757,7 +1831,7 @@ class ContinuousBatcher:
             # beam requests run their whole search synchronously —
             # they never join the lane-batched decode state below
             self._run_beam(s, now)
-        if self.spec:
+        if self.spec and not self._mtp:
             self._spec_decode_step(now)
             return
         if not self._inflight \
@@ -1768,12 +1842,12 @@ class ContinuousBatcher:
         # state was built: drain the pipeline before touching it
         if self._dstate is None or self._state_epoch != self._epoch:
             self._flush_inflight()
-        with self._spans.span("gen.decode.prepare",
-                              program="decode") as span:
+        name = self._step_name
+        with self._spans.span("gen.decode.prepare", program=name) as span:
             batch = self._prepare_decode(span)
         if batch:
             try:
-                _FP_DECODE.fire()
+                (_FP_VERIFY if self._mtp else _FP_DECODE).fire()
             except Exception as e:  # noqa: BLE001 — fails only this batch
                 # the in-flight speculative step is legitimate work:
                 # deliver its tokens, then fail this step's lanes (same
@@ -1786,26 +1860,39 @@ class ContinuousBatcher:
             flight = next(self._flights)
             try:
                 with self._spans.span("gen.decode.dispatch",
-                                      program="decode", lanes=len(batch),
+                                      program=name, lanes=len(batch),
                                       flight=flight):
-                    # the device's lengths run ahead of the host's
-                    # mirror by the steps in flight
-                    ahead = len(self._inflight)
-                    lengths = [x.cache_len + ahead for x in batch]
-                    self._count_attention_blocks("decode", lengths,
-                                                 DECODE_WIDTH)
-                    self._count_reads("decode", self._decode_prog, lengths,
-                                      [1] * len(batch))
+                    if self._mtp:
+                        # what a verify step reads and drafts is counted
+                        # when its tokens come back: the lengths it ran
+                        # at are the host's mirror only then
+                        program = self._verify_prog
+                    else:
+                        # the device's lengths run ahead of the host's
+                        # mirror by the steps in flight
+                        ahead = len(self._inflight)
+                        lengths = [x.cache_len + ahead for x in batch]
+                        self._count_attention_blocks("decode", lengths,
+                                                     DECODE_WIDTH)
+                        self._count_reads("decode", self._decode_prog,
+                                          lengths, [1] * len(batch))
+                        program = self._decode_prog
                     _M_SAMPLE_STEPS.labels(cut=self._dstate_cut).inc()
-                    out = self._decode_prog(self._params(), self._pools,
-                                            self._dtables, self._dstate)
+                    out = program(self._params(), self._pools,
+                                  self._dtables, self._dstate)
             except Exception:  # noqa: BLE001
                 self._reset_device()
                 return
-            self._pools, self._dstate, tok, logp, *stats = out
+            self._pools, self._dstate, *results = out
+            # decode: (token, logprob); verify_mtp: (pred, logprob,
+            # n_emit, draft); then the routing counts
+            n = 4 if self._mtp else 2
+            results, stats = results[:n], results[n:]
             self._carried[1] += len(batch)
-            self._inflight.append((tok, logp, list(self._lanes),
-                                   [("decode", c) for c in stats], flight))
+            self._inflight.append((
+                results, list(self._lanes),
+                [("verify" if self._mtp else "decode", c) for c in stats],
+                flight))
         # consume down to the configured pipeline depth — everything,
         # when nothing was enqueued this iteration
         limit = self.async_depth if batch else 0
@@ -1889,10 +1976,17 @@ class ContinuousBatcher:
             # at worst a few blocks of slack, never a correctness risk
             width = 1 if not self.spec else 1 + max(0, min(
                 self.spec_tokens, s.max_tokens - len(s.generated) - 1))
+            if self._mtp:
+                # a verify step in flight commits up to 1 + spec_tokens
+                # positions, and no step writes past the lane's budget
+                reach = min(pending * (1 + self.spec_tokens) + width,
+                            s.max_tokens - len(s.generated))
+            else:
+                reach = pending + width
             # steps in flight run ahead of the host's length, so the
             # host's is the oldest position any of them still writes
             self._release_window(s, s.cache_len)
-            upto = self._alloc.blocks_for(s.cache_len + pending + width)
+            upto = self._alloc.blocks_for(s.cache_len + reach)
             need = upto - len(s.blocks)
             if need <= 0 and not self._short_of_window(s, upto):
                 continue
@@ -1936,6 +2030,7 @@ class ContinuousBatcher:
         top_p = np.ones((B,), np.float32)
         key = np.zeros((B, 2), np.uint32)
         emitted = np.zeros((B,), np.int32)
+        draft = np.zeros((B,), np.int32)
         for i, s in enumerate(self._lanes):
             if s is None:
                 continue
@@ -1949,6 +2044,7 @@ class ContinuousBatcher:
             top_p[i] = s.top_p
             key[i] = s.key
             emitted[i] = s.sample_offset + len(s.generated)
+            draft[i] = s.draft
         self._dstate = DecodeState(
             tokens=jnp.asarray(tokens), lengths=jnp.asarray(lengths),
             live=jnp.asarray(live), remaining=jnp.asarray(remaining),
@@ -1956,7 +2052,8 @@ class ContinuousBatcher:
             sample=SampleParams(
                 temperature=jnp.asarray(temp), top_k=jnp.asarray(top_k),
                 top_p=jnp.asarray(top_p), key=jnp.asarray(key),
-                emitted=jnp.asarray(emitted)))
+                emitted=jnp.asarray(emitted)),
+            draft=jnp.asarray(draft) if self._mtp else None)
         self._dstate_cut = sample_cut(temp, top_k, top_p)
         self._state_epoch = self._epoch
         self._tables_dirty = True
@@ -2021,16 +2118,23 @@ class ContinuousBatcher:
             self._process_flight(now)
 
     def _process_flight(self, now: float) -> None:
-        tok_d, logp_d, lanes, stats, flight = self._inflight.popleft()
+        results, lanes, stats, flight = self._inflight.popleft()
         try:
-            with self._spans.span("gen.wait", program="decode",
-                                  flight=flight):
-                tok, logp = self._readback((tok_d, logp_d), stats)
+            with self._spans.span("gen.wait", program=self._step_name,
+                                  flight=flight) as wait:
+                results = self._readback(results, stats)
         except Exception:  # noqa: BLE001 — the device step itself died
             self._reset_device()
             return
+        if self._mtp:
+            # the verify step's share of the wait, as the synchronous
+            # speculative loop publishes it
+            _M_STEP.labels(component="verify").observe(wait.dur_ns * 1e-9)
         with self._spans.span("gen.deliver"):
-            self._deliver_flight(tok, logp, lanes, now)
+            if self._mtp:
+                self._deliver_mtp(*results, lanes, now)
+            else:
+                self._deliver_flight(*results, lanes, now)
 
     def _deliver_flight(self, tok, logp, lanes, now: float) -> None:
         self._delivery_ns = time.perf_counter_ns()
@@ -2070,7 +2174,9 @@ class ContinuousBatcher:
         loop — the verify program recomputes the deterministic sample
         at every position — so drafting only ever changes throughput.
         The loop is synchronous (no async pipeline): the proposer needs
-        host-visible history, so every step round-trips anyway."""
+        host-visible history, so every step round-trips anyway. (With
+        ``spec_mode='mtp'`` the draft is the device's, and
+        :meth:`_decode_step` pipelines the verify step instead.)"""
         self._flush_inflight()  # leftover plain-path flights, if any
         if not any(x.state == "decode" and x.num_beams == 1
                    for x in self._running):
@@ -2100,7 +2206,7 @@ class ContinuousBatcher:
                 dlen[i] = len(d)
                 drafted += len(d)
             if drafted:
-                _M_SPEC_DRAFTED.inc(drafted)
+                _M_SPEC_DRAFTED.labels(mode=self.spec_mode).inc(drafted)
             draft_d, dlen_d = jnp.asarray(draft), jnp.asarray(dlen)
         try:
             _FP_VERIFY.fire()
@@ -2123,14 +2229,14 @@ class ContinuousBatcher:
         except Exception:  # noqa: BLE001
             self._reset_device()
             return
-        self._pools, self._dstate, pred_d, logp_d, n_emit_d = out
+        self._pools, self._dstate, pred_d, logp_d, n_emit_d, *stats = out
         self._carried[1] += len(batch)
         try:
             with spans.span("gen.wait", program="verify",
                             flight=flight) as wait:
-                pred = np.asarray(pred_d)
-                logp = np.asarray(logp_d)
-                n_emit = np.asarray(n_emit_d)
+                pred, logp, n_emit = self._readback(
+                    (pred_d, logp_d, n_emit_d),
+                    [("verify", c) for c in stats])
         except Exception:  # noqa: BLE001 — the device step itself died
             self._reset_device()
             return
@@ -2140,18 +2246,41 @@ class ContinuousBatcher:
         # accept-rate tuning
         _M_STEP.labels(component="verify").observe(wait.dur_ns * 1e-9)
         with spans.span("gen.deliver"):
-            self._deliver_verify(pred, logp, n_emit, now)
+            self._deliver_verify(pred, logp, n_emit, list(self._lanes), now)
 
-    def _deliver_verify(self, pred, logp, n_emit, now: float) -> None:
+    def _deliver_mtp(self, pred, logp, n_emit, draft, lanes,
+                     now: float) -> None:
+        """An MTP verify step's delivery: what it drafted and read,
+        counted at the lengths it ran at (the host's mirror, now that
+        the steps before it are delivered), each lane's next draft
+        mirrored, then its tokens."""
+        S = self.spec_tokens
+        alive = [(i, s) for i, s in enumerate(lanes)
+                 if s is not None and s.state == "decode"]
+        # the program drafts for a lane that may still emit two tokens,
+        # as many as it may emit past one
+        drafted = sum(max(0, min(S, s.max_tokens - len(s.generated) - 1))
+                      for _, s in alive)
+        if drafted:
+            _M_SPEC_DRAFTED.labels(mode=self.spec_mode).inc(drafted)
+        self._count_attention_blocks(
+            "verify", [s.cache_len for _, s in alive], S + 1)
+        for i, s in alive:
+            s.draft = int(draft[i])
+        self._deliver_verify(pred, logp, n_emit, lanes, now)
+
+    def _deliver_verify(self, pred, logp, n_emit, lanes,
+                        now: float) -> None:
         self._delivery_ns = time.perf_counter_ns()
-        logp = _corrupt_logprobs(logp, self._lanes)  # serving.logprob
+        logp = _corrupt_logprobs(logp, lanes)   # serving.logprob drill
         emitted = []
-        for i, s in enumerate(list(self._lanes)):
+        for i, s in enumerate(lanes):
             if s is None or s.state != "decode":
                 continue
             n = int(n_emit[i])
-            _M_SPEC_ACCEPTED.inc(max(0, n - 1))
-            _M_SPEC_ACCEPT_LEN.observe(max(0, n - 1))
+            _M_SPEC_ACCEPTED.labels(mode=self.spec_mode).inc(max(0, n - 1))
+            _M_SPEC_ACCEPT_LEN.labels(mode=self.spec_mode).observe(
+                max(0, n - 1))
             for j in range(n):
                 if not np.isfinite(logp[i, j]):
                     # same blast radius as the plain loop: exactly this

@@ -32,9 +32,17 @@ Two proposers ship:
   metrics (``hvd_tpu_gen_spec_accepted_total`` /
   ``_drafted_total``) say whether it pays.
 
+A third mode drafts on the device: ``HVD_TPU_GEN_SPEC_MODE=mtp`` takes
+the model's own multi-token prediction module, which the prefill and
+verify programs run (:func:`~.kv_cache.build_verify_program` with
+``mtp``); its draft never visits the host, so no proposer drafts for
+it (:func:`make_proposer` gives the bare :class:`Proposer`, whose
+:meth:`~Proposer.propose` the scheduler never calls).
+
 Proposers run on the scheduler thread between device steps, see the
 sequence's host-visible history only, and must be fast relative to a
-decode step — the contract is :meth:`Proposer.propose`.
+decode step — the contract is :meth:`Proposer.propose`. The counters
+say which proposer drafted (``mode``).
 """
 
 from typing import List, Optional, Sequence
@@ -152,12 +160,15 @@ def make_proposer(mode: str, draft_model=None, **draft_kwargs) -> \
     """The ``HVD_TPU_GEN_SPEC_MODE`` dispatch: ``'off'`` -> None,
     ``'ngram'`` -> :class:`NGramProposer`, ``'draft'`` ->
     :class:`DraftModelProposer` over ``draft_model`` (required) and
-    ``draft_kwargs`` (its params/checkpoint plumbing)."""
+    ``draft_kwargs`` (its params/checkpoint plumbing), ``'mtp'`` -> the
+    bare :class:`Proposer` (the device drafts)."""
     mode = str(mode).strip().lower()
     if mode in ("", "off", "0", "false", "none"):
         return None
     if mode == "ngram":
         return NGramProposer()
+    if mode == "mtp":
+        return Proposer()
     if mode == "draft":
         if draft_model is None:
             raise ValueError(
@@ -165,4 +176,4 @@ def make_proposer(mode: str, draft_model=None, **draft_kwargs) -> \
                 "draft params or checkpoint) on the GenerationEngine")
         return DraftModelProposer(draft_model, **draft_kwargs)
     raise ValueError(
-        f"HVD_TPU_GEN_SPEC_MODE={mode!r}: must be off|ngram|draft")
+        f"HVD_TPU_GEN_SPEC_MODE={mode!r}: must be off|ngram|draft|mtp")

@@ -31,7 +31,7 @@ from horovod_tpu import serving
 from horovod_tpu.models.transformer import Transformer, TransformerConfig
 from horovod_tpu.serving import fleet
 from horovod_tpu.serving.generation import GenerationEngine, NGramProposer
-from horovod_tpu.serving.generation.spec import make_proposer
+from horovod_tpu.serving.generation.spec import Proposer, make_proposer
 
 SEED = 1234
 
@@ -122,6 +122,8 @@ class TestNGramProposer:
         assert make_proposer("off") is None
         assert make_proposer("") is None
         assert isinstance(make_proposer("ngram"), NGramProposer)
+        # the device drafts for mtp: a proposer that never proposes
+        assert type(make_proposer("mtp")) is Proposer
         with pytest.raises(ValueError):
             make_proposer("draft")          # needs a draft_model
         with pytest.raises(ValueError):
@@ -205,15 +207,47 @@ class TestSpecBitIdentity:
         before = M.snapshot()
         with _spec_engine(model, params) as eng:
             _result(eng, prompt=PROMPT, max_tokens=48)
-        drafted = _delta(before, "hvd_tpu_gen_spec_drafted_total")
-        accepted = _delta(before, "hvd_tpu_gen_spec_accepted_total")
+        drafted = _delta(before, 'hvd_tpu_gen_spec_drafted_total'
+                                 '{mode="ngram"}')
+        accepted = _delta(before, 'hvd_tpu_gen_spec_accepted_total'
+                                  '{mode="ngram"}')
         assert drafted > 0
         assert 0 < accepted <= drafted
-        hist = M.snapshot().get("hvd_tpu_gen_spec_accept_length")
+        hist = M.snapshot().get(
+            'hvd_tpu_gen_spec_accept_length{mode="ngram"}')
         assert hist is not None and hist["count"] > 0
         key = 'hvd_tpu_gen_step_seconds{component="verify"}'
         assert M.snapshot()[key]["count"] > before.get(
             key, {"count": 0})["count"]
+
+
+    def test_each_proposer_counts_under_its_own_mode(self, model_params):
+        """The n-gram proposer's drafts and the draft model's go to the
+        speculative counters under their own ``mode``, and neither moves
+        the other's series or the MTP one."""
+        model, params = model_params
+        small = Transformer(TransformerConfig(
+            vocab_size=64, num_layers=1, d_model=16, num_heads=2,
+            head_dim=8, max_seq_len=64, dtype=jnp.float32))
+        small_params = small.init(jax.random.PRNGKey(3),
+                                  jnp.zeros((1, 4), jnp.int32))
+        series = ("hvd_tpu_gen_spec_drafted_total",
+                  "hvd_tpu_gen_spec_accepted_total")
+        for mode, kw in (("ngram", {}),
+                         ("draft", dict(draft_model=small,
+                                        draft_params=small_params))):
+            before = M.snapshot()
+            with _spec_engine(model, params, spec_mode=mode, **kw) as eng:
+                _result(eng, prompt=PROMPT, max_tokens=24)
+            moved = {m: {k: _delta(before, f'{k}{{mode="{m}"}}')
+                         for k in series}
+                     for m in ("ngram", "draft", "mtp")}
+            assert moved[mode]["hvd_tpu_gen_spec_drafted_total"] > 0
+            hist = f'hvd_tpu_gen_spec_accept_length{{mode="{mode}"}}'
+            assert M.snapshot()[hist]["count"] > before.get(
+                hist, {"count": 0})["count"]
+            for other in {"ngram", "draft", "mtp"} - {mode}:
+                assert not any(moved[other].values()), (mode, other)
 
 
 # ---------------------------------------------------------------------------
