@@ -21,7 +21,9 @@ The reference has no transformer (its benchmarks are CNNs), but the TPU
 build's parallelism strategies (TP/SP/PP/EP/ring attention — SURVEY.md §2.3,
 §7 stage 8) need a first-class transformer to exercise them. Design:
 
-* bfloat16 activations, fp32 params; all projections are einsums with
+* bfloat16 activations, fp32 params (a server holds the matmul weights
+  in ``cfg.dtype``: :meth:`Transformer.serving_params`, cast once where
+  the engine takes the params); all projections are einsums with
   explicit head axes so tensor parallelism is a sharding annotation, not a
   rewrite (heads shard over 'tp', hidden shards over 'tp' in the MLP).
 * flax ``nn.with_logical_partitioning`` names every parameter axis
@@ -89,6 +91,7 @@ hot path (the selected row stays bit-identical to the full projection).
 """
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
@@ -408,8 +411,47 @@ class DecoderLayer(nn.Module):
         return x, kv
 
 
+#: the weights the block multiplies by, by module: what its serving
+#: form holds in ``cfg.dtype`` (LayerNorms keep ``scale`` and ``bias``)
+_MATMUL_WEIGHTS = {"attn": ("wq", "wk", "wv", "wo"), "mlp": ("wi", "wo")}
+
+
+def _is_matmul_weight(path) -> bool:
+    names = [k.key for k in path if isinstance(k, jax.tree_util.DictKey)]
+    return len(names) >= 2 and names[-1] in _MATMUL_WEIGHTS.get(names[-2], ())
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cast_weights(weights, dtype):
+    """One device program for every cast; no input is donated (the
+    caller may still hold its float32 tree)."""
+    return tuple(w.astype(dtype) for w in weights)
+
+
 class Transformer(nn.Module):
     cfg: TransformerConfig
+
+    @nn.nowrap
+    def serving_params(self, params):
+        """The form of ``params`` the paged programs take: every layer's
+        attention and MLP weights in ``cfg.dtype``, everything else (the
+        LayerNorms, ``embedding`` and ``pos_embedding``) the very leaves
+        given. The model multiplies by ``w.astype(cfg.dtype)``, so each
+        program computes what it computes on the float32 masters, bit for
+        bit, without reading and converting them in every call. The
+        masters stay float32 for training, which shares this module.
+        With ``cfg.dtype`` float32 ``params`` comes back as it is."""
+        dt = jnp.dtype(self.cfg.dtype)
+        if dt == jnp.float32:
+            return params
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(params)
+        out = [leaf for _, leaf in leaves]
+        at = [i for i, (path, leaf) in enumerate(leaves)
+              if _is_matmul_weight(path) and jnp.dtype(leaf.dtype) != dt]
+        if at:
+            for i, w in zip(at, _cast_weights(tuple(out[i] for i in at), dt)):
+                out[i] = w
+        return jax.tree_util.tree_unflatten(treedef, out)
 
     @nn.compact
     def __call__(self, tokens, cache=None, logits_at=None):
@@ -433,8 +475,10 @@ class Transformer(nn.Module):
             # host-side against max_seq_len before submission)
             positions = cache.lengths[:, None] + jnp.arange(S)[None, :]
             safe_pos = jnp.clip(positions, 0, cfg.max_seq_len - 1)
-            x = emb.astype(cfg.dtype)[tokens] \
-                + pos.astype(cfg.dtype)[safe_pos]
+            # gather the rows, then cast them: the same values as
+            # casting the tables first, without a whole-table convert
+            x = emb[tokens].astype(cfg.dtype) \
+                + pos[safe_pos].astype(cfg.dtype)
             mask = _table_mask(
                 positions,
                 cache.block_tables.shape[1] * cache.pools[0].shape[2])

@@ -62,6 +62,14 @@ _M_STEP = _metrics.gauge(
     "params lifecycle; -1 = params were supplied directly, not "
     "restored from a checkpoint directory).",
     labels=("plane",))
+_M_PARAMS_BYTES = _metrics.gauge(
+    "hvd_tpu_serving_params_bytes",
+    "Bytes of the params a serving plane holds, by dtype: what its "
+    "lifecycle keeps after the model's serving form (a model that "
+    "declares one, such as the GPT-2 block, holds its matmul weights "
+    "in the dtype its programs multiply in). Set at construction and "
+    "at every hot reload.",
+    labels=("plane", "dtype"))
 
 _FP_FORWARD = _faults.FaultPoint("serving.forward")
 _FP_RELOAD = _faults.FaultPoint("serving.reload", exc=OSError)
@@ -89,7 +97,11 @@ class ParamsLifecycle:
     ``HVD_TPU_SERVING_RELOAD_POLL_SECONDS`` knob; 0 disables the poller
     (:meth:`reload` stays available). ``plane`` labels this lifecycle's
     metric series (one front-end can run an inference and a generation
-    lifecycle side by side).
+    lifecycle side by side). ``prepare``, where given, turns params as
+    restored or supplied into the form the engine's programs take (a
+    model's ``serving_params``); it runs on the initial params and on
+    every reload, before the swap, so a compiled program never sees
+    another dtype. Without it the params are held as they come.
 
     The owning engine must call :meth:`start_poller` as the LAST step
     of its own construction: started any earlier, a failure later in
@@ -101,7 +113,8 @@ class ParamsLifecycle:
                  params: Any = None, sharding=None,
                  step: Optional[int] = None,
                  reload_poll_seconds: Optional[float] = None,
-                 plane: str = "inference"):
+                 plane: str = "inference",
+                 prepare: Optional[Callable[[Any], Any]] = None):
         if (params is None) == (checkpoint_dir is None):
             raise ValueError(
                 "provide exactly one of params= or checkpoint_dir=")
@@ -111,6 +124,8 @@ class ParamsLifecycle:
         self.checkpoint_dir = checkpoint_dir
         self.plane = plane
         self._sharding = sharding
+        self._prepare = prepare or (lambda params: params)
+        self._held_dtypes: set = set()
         self._reload_poll = float(
             cfg.get(_config.SERVING_RELOAD_POLL_SECONDS)
             if reload_poll_seconds is None else reload_poll_seconds)
@@ -136,8 +151,9 @@ class ParamsLifecycle:
                 import jax
                 params = jax.device_put(params, sharding)
             self.step = -1
-        self._params = params
+        self._params = self._prepare(params)
         _M_STEP.labels(plane=self.plane).set(self.step)
+        self._count_bytes(self._params)
 
     def start_poller(self) -> None:
         """Start the background hot-reload poller (idempotent; a no-op
@@ -165,9 +181,9 @@ class ParamsLifecycle:
     def reload(self, step: Optional[int] = None) -> bool:
         """Load ``step`` (default: latest committed) and atomically swap
         it into serving. Returns True when a swap happened. Everything
-        expensive (disk read, checksum verify, device_put) runs before
-        the swap, outside the params lock; the swap itself is one
-        reference assignment. Exceptions propagate — the poll loop (and
+        expensive (disk read, checksum verify, device_put, ``prepare``)
+        runs before the swap, outside the params lock; the swap itself is
+        one reference assignment. Exceptions propagate — the poll loop (and
         any caller that wants old-params-keep-serving semantics) catches
         them."""
         if self._manager is None:
@@ -178,16 +194,30 @@ class ParamsLifecycle:
             if step is None or int(step) == self.step:
                 return False
             _FP_RELOAD.fire(crash=_reload_crash)
-            fresh = self._manager.restore(step=int(step),
-                                          sharding=self._sharding)
+            fresh = self._prepare(self._manager.restore(
+                step=int(step), sharding=self._sharding))
             with self._params_lock:
                 self._params = fresh
                 self.step = int(step)
             _M_STEP.labels(plane=self.plane).set(self.step)
+            self._count_bytes(fresh)
             _M_HOT_SWAPS.labels(plane=self.plane).inc()
             log.info("serving: hot-swapped checkpoint step %d from %s",
                      self.step, self.checkpoint_dir)
             return True
+
+    def _count_bytes(self, params) -> None:
+        import jax
+        held = {}
+        for leaf in jax.tree_util.tree_leaves(params):
+            if hasattr(leaf, "dtype") and hasattr(leaf, "nbytes"):
+                name = str(leaf.dtype)
+                held[name] = held.get(name, 0) + int(leaf.nbytes)
+        for name in self._held_dtypes - held.keys():
+            _M_PARAMS_BYTES.labels(plane=self.plane, dtype=name).set(0)
+        for name, nbytes in held.items():
+            _M_PARAMS_BYTES.labels(plane=self.plane, dtype=name).set(nbytes)
+        self._held_dtypes = set(held)
 
     def _poll_loop(self) -> None:
         while not self._stop.wait(self._reload_poll):

@@ -149,7 +149,8 @@ class GenerationEngine:
         self._lifecycle = ParamsLifecycle(
             checkpoint_dir=checkpoint_dir, params=params, sharding=sharding,
             step=step, reload_poll_seconds=reload_poll_seconds,
-            plane="generation")
+            plane="generation",
+            prepare=getattr(model, "serving_params", None))
         spec_off = spec_mode in ("", "off", "0", "false", "none")
         stateful = bool(model.cfg.cache_spec().state)
         snapshots, state_slots, snapshot_slots = (), 0, 0

@@ -125,7 +125,8 @@ class DraftModelProposer(Proposer):
         self._lifecycle = ParamsLifecycle(
             checkpoint_dir=checkpoint_dir, params=params,
             sharding=sharding, step=step,
-            reload_poll_seconds=reload_poll_seconds, plane="generation")
+            reload_poll_seconds=reload_poll_seconds, plane="draft",
+            prepare=getattr(model, "serving_params", None))
         self._lifecycle.start_poller()
 
     @property
